@@ -73,10 +73,8 @@ def bench_one(gar, n, f, d, reps, key, trials=1):
             return INCOMPATIBLE
     except TypeError:
         pass
-    # Timing that survives tunneled/remote device backends, where
-    # ``block_until_ready`` may return before the device finishes and the
-    # only true synchronization is a host readback that also flushes the
-    # queue at a large constant cost:
+    # Paired-reps timing (utils/profiling.paired_reps): the constant cost
+    # of ending a chain in a host readback cancels in the difference:
     #   - dependency-chain the iterations ((n, d) -> (n, d) by writing the
     #     aggregate back into row 0) so they cannot be overlapped;
     #   - run the chain at ``reps`` and ``2*reps`` with a readback sync each,

@@ -40,8 +40,9 @@ def bench_gather(mesh, d, reps, trials=1):
         )
 
     fn = jax.jit(
-        mesh_lib.shard_map(
-            gather_fold, mesh=mesh, in_specs=P(axis), out_specs=P(axis)
+        jax.shard_map(
+            gather_fold, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
+            check_vma=False,
         )
     )
     x0 = fn(jnp.zeros((k, d), jnp.float32))

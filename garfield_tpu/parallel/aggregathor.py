@@ -1114,7 +1114,7 @@ def make_trainer(
                 )
         return new_state, metrics
 
-    sharded_step = mesh_lib.shard_map(
+    sharded_step = jax.shard_map(
         _local_step,
         mesh=mesh,
         in_specs=(P(), P(axis), P(axis)),

@@ -814,7 +814,7 @@ def make_trainer(
         attack_state=(P() if ps_adaptive_cfg is not None else None),
         defense_state=(P() if defense is not None else None),
     )
-    sharded_step = mesh_lib.shard_map(
+    sharded_step = jax.shard_map(
         _local_step,
         mesh=mesh,
         in_specs=(state_specs, P(axis), P(axis)),

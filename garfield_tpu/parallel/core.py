@@ -205,7 +205,7 @@ def step_donation():
     """``donate_argnums`` for the topology step functions: ``(0,)`` (donate
     the TrainState) on real device backends, ``()`` on XLA:CPU.
 
-    This jaxlib's CPU runtime executes donation unsoundly when host views
+    XLA:CPU executes donation unsoundly when host views
     of the donated buffers are still alive — and on CPU both
     ``np.asarray(jax_array)`` and ``jax.device_put(np_array)`` are
     zero-copy, so checkpoint save/restore and the eval readback all

@@ -19,29 +19,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["make_mesh", "fold", "replicated", "sharded", "shard_map", "P"]
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """Version-tolerant ``jax.shard_map`` for the topology builders.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=)``; older releases
-    only ship ``jax.experimental.shard_map.shard_map(..., check_rep=)``
-    (same semantics, pre-rename). Routing every topology through this
-    shim keeps the whole parallel stack importable and runnable on both,
-    instead of failing at trainer-build time on the older runtime.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
+__all__ = ["make_mesh", "fold", "replicated", "sharded", "P"]
 
 
 def make_mesh(axes, devices=None):

@@ -14,7 +14,8 @@ timing), ``event`` (liveness / exchange waits / wire accounting),
 (host-plane publish/collect cells — the wire-codec A/B record).
 ``validate_record`` / ``validate_jsonl`` are stdlib-only and run in the
 tier-1 suite, so a malformed artifact fails loudly instead of going dark
-(the BENCH_r05 rc=1 post-mortem this subsystem exists for).
+(the round-5 bench capture that died rc=1 with nothing parseable is the
+post-mortem this subsystem exists for).
 """
 
 import json
@@ -38,7 +39,7 @@ SCHEMA = "garfield-telemetry"
 # 10): the ``hier_bench`` kind (hierarchical bucketed-GAR sweep cells —
 # HIERBENCH_r*'s format, with peak-RSS accounting), ``gar_bench`` rows may
 # carry ``peak_rss_bytes``, and bench error records may carry
-# ``backend_outage`` (the BENCH_r05/MULTICHIP_r05 filter). v4 (round 11,
+# ``backend_outage`` (optional; no producer emits it since PR 21). v4 (round 11,
 # the bounded-staleness async plane — DESIGN.md §14): the per-round
 # ``staleness`` EVENT (per-rank staleness + discount weights, validated
 # below), ``summary.staleness`` digest (count/mean/max/hist), and

@@ -21,8 +21,8 @@ Contract (the taps' purity contract, host-side edition):
   tracing-on vs tracing-off trajectory pin in tests/test_trace.py
   asserts the host-side half.
 - **crash-safe**: spans ride the hub's streaming JSONL sink (one
-  flushed line per span), so a run that dies dark — the BENCH_r05
-  post-mortem this plane exists for — keeps every span up to the
+  flushed line per span), so a run that dies dark — the round-5
+  bench post-mortem this plane exists for — keeps every span up to the
   crash.
 - **thread-correct**: spans are emitted from exchange waiter threads
   (wire decode, H2D staging) concurrently with the role's main loop;

@@ -40,7 +40,7 @@ def _use_orbax():
     """Orbax on real device backends; pickle on XLA:CPU (or by env).
 
     orbax's CheckpointManager keeps background commit threads alive past
-    ``wait_until_finished``, and on this jaxlib's XLA:CPU runtime a
+    ``wait_until_finished``, and on the XLA:CPU runtime a
     native thread touching the runtime while the training thread
     dispatches donating steps is unsound — the process dies with a
     native SIGSEGV/SIGABRT, not an exception (same failure class, and

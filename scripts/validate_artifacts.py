@@ -1,6 +1,6 @@
 """Schema-check every committed telemetry JSONL artifact.
 
-The BENCH_r05 post-mortem rule, mechanized: a bench capture that drifts
+The round-5 bench post-mortem rule, mechanized: a bench capture that drifts
 from the telemetry schema must fail LOUDLY at commit time, not parse
 half-way in a later analysis session. This walks the repo root for
 ``*_r*.jsonl`` artifacts (EXCHBENCH_r*, HIERBENCH_r*, ...) plus every

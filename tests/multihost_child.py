@@ -17,11 +17,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 4)
-except AttributeError:  # older jax: XLA_FLAGS (set by the parent) rules
-    pass
+jax.config.update("jax_num_cpu_devices", 4)
 
 
 def main(config_path):
@@ -61,7 +57,7 @@ def main(config_path):
         return gar.unchecked(stack, f=f)
 
     aggr = jax.jit(
-        mesh_lib.shard_map(
+        jax.shard_map(
             step, mesh=mesh, in_specs=P("workers"), out_specs=P(),
             check_vma=False,
         )

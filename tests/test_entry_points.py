@@ -1,0 +1,156 @@
+"""The repo-root entry points and the start-up helper they share.
+
+``chip_smoke.py`` refuses to run off the chip and its legs work at toy size;
+``bench.py`` runs on the backend it was given, names it on its JSON line and
+exits non-zero on any failure; ``__graft_entry__.dryrun_multichip`` raises
+rather than run somewhere else; ``profiling.enable_compile_cache`` can be
+placed from outside and otherwise stays in the checkout.
+"""
+
+import importlib.util
+import json
+import os
+import pathlib
+import re
+import subprocess
+import sys
+
+import jax
+import pytest
+
+from garfield_tpu.utils import profiling
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
+
+
+def _load(name):
+    spec = importlib.util.spec_from_file_location(
+        f"{name}_entry", REPO_ROOT / f"{name}.py"
+    )
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+class TestCompileCache:
+    def test_env_var_set_leaves_config_alone(self, monkeypatch):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+        before = jax.config.jax_compilation_cache_dir
+        assert profiling.enable_compile_cache() == "/placed/from/outside"
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_default_is_fixed_dir_in_checkout(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            got = profiling.enable_compile_cache()
+            assert jax.config.jax_compilation_cache_dir == got
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+        assert got == str(REPO_ROOT / ".jax_cache")
+        # The path is part of the cache key: nothing in it may change from
+        # one process or installation to the next.
+        import tempfile
+
+        assert not got.startswith(tempfile.gettempdir())
+        assert str(os.getpid()) not in got
+        assert not re.search(r"\d+\.\d+", got), got  # no version component
+
+
+def test_chip_smoke_refuses_off_chip():
+    """``python chip_smoke.py`` without a TPU: non-zero, one line on stderr,
+    no result on stdout, no leg started (so nothing compiled)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, str(REPO_ROOT / "chip_smoke.py")],
+        cwd=REPO_ROOT, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode != 0
+    assert proc.stdout == ""
+    assert "needs a TPU" in proc.stderr
+    assert "Traceback" not in proc.stderr
+
+
+def test_dryrun_multichip_raises_on_too_few_devices():
+    entry = _load("__graft_entry__")
+    n = len(jax.devices()) + 1
+    with pytest.raises(RuntimeError, match=f"dryrun_multichip\\({n}\\)"):
+        entry.dryrun_multichip(n)
+
+
+def test_bench_unknown_accelerator_is_an_error():
+    bench = _load("bench")
+
+    class Fake:
+        platform = "tpu"
+        device_kind = "TPU v0 imaginary"
+
+    with pytest.raises(RuntimeError, match="TPU v0 imaginary"):
+        bench.peak_bf16(Fake)
+    assert bench.peak_bf16(jax.devices()[0]) is None  # cpu: no device metric
+
+
+def _run_bench(**knobs):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", GARFIELD_BENCH_JSONL="",
+               GARFIELD_BENCH_STEPS="2", GARFIELD_BENCH_TRIALS="1", **knobs)
+    return subprocess.run(
+        [sys.executable, str(REPO_ROOT / "bench.py")],
+        cwd=REPO_ROOT, env=env, capture_output=True, text=True, timeout=900,
+    )
+
+
+@pytest.mark.slow
+class TestBenchContract:
+    def test_bad_rule_exits_nonzero_without_a_result(self):
+        proc = _run_bench(GARFIELD_BENCH_GAR="no-such-rule")
+        assert proc.returncode != 0
+        assert "no-such-rule" in proc.stderr
+        assert proc.stdout.strip() == ""  # nothing that parses as a result
+
+    def test_workers_that_do_not_fold_exit_nonzero(self):
+        # 8 virtual CPU devices (conftest's JAX_NUM_CPU_DEVICES), 6 workers.
+        proc = _run_bench(GARFIELD_BENCH_WORKERS="6", GARFIELD_BENCH_F="1")
+        assert proc.returncode != 0
+        assert "do not fold" in proc.stderr
+
+    def test_line_names_the_device(self):
+        """Tiny off-default config on the CPU backend: one valid JSON line
+        that says where it ran; no ratchet ratio and no device metric."""
+        proc = _run_bench(
+            GARFIELD_BENCH_WORKERS="8", GARFIELD_BENCH_F="1",
+            GARFIELD_BENCH_GAR="median", GARFIELD_BENCH_ATTACK="lie",
+            GARFIELD_BENCH_BATCH="2",
+        )
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        lines = [l for l in proc.stdout.splitlines() if l.strip()]
+        assert len(lines) == 1, proc.stdout
+        out = json.loads(lines[0])
+        assert out["value"] > 0
+        assert out["unit"] == "steps/s/chip"
+        assert out["metric"].endswith("w8_f1_median_lie")
+        assert out["vs_baseline"] is None  # off-default config
+        assert out["mfu"] is None  # cpu has no peak: no device metric
+        assert out["chunk_steps"] == 1
+        assert (out["platform"], out["n_devices"]) == ("cpu", 8)
+        assert out["device_kind"] == jax.devices()[0].device_kind
+
+
+@pytest.mark.slow
+def test_chip_smoke_legs_at_toy_size():
+    """Every leg of ``chip_smoke.run``, on the CPU: a small convnet, a short
+    stack, kernels in interpret mode. Same code path as the chip run minus
+    the device check and the Mosaic-text assertions."""
+    smoke = _load("chip_smoke")
+    toy = smoke.Size(
+        kernel_d=1031, model="convnet", dataset="mnist", loss="nll",
+        input_shape=(28, 28, 1), batch=4, num_iter=4, acc_freq=2,
+        median_steps=2, interpret=True,
+    )
+    legs = smoke.run(toy)
+    assert list(legs) == ["kernels", "trainer_krum", "trainer_median"]
+    assert all(leg["ok"] for leg in legs.values())
+    assert legs["kernels"]["cases"] == 12
+    assert legs["trainer_krum"]["steps"] == 4
+    assert legs["trainer_krum"]["eval_reports"] == 2
+    assert legs["trainer_median"]["steps"] == 2
+    json.dumps(legs)  # the summary line must serialize

@@ -106,21 +106,7 @@ def test_cpu_lowering_on_tpu_default_process(monkeypatch):
     monkeypatch.setattr(coordinate.jax, "default_backend", lambda: "tpu")
     assert coordinate.use_pallas()  # gate open: dispatch reaches the router
     x = _rand(6, 50, seed=13)
-    try:
-        got = jax.jit(coordinate.coordinate_median)(x)
-    except ValueError as e:
-        if "interpret mode" in str(e):
-            # Old jax lowers EVERY lax.platform_dependent branch behind a
-            # runtime platform-index select instead of pruning to the
-            # lowering platforms, so the Pallas TPU branch poisons CPU
-            # lowering outright. The per-call router this test guards
-            # only exists where pruning does; nothing to regress here.
-            pytest.skip(
-                "this jax has no per-platform pruning in "
-                "lax.platform_dependent; TPU-default router untestable "
-                "on a CPU-only runtime"
-            )
-        raise
+    got = jax.jit(coordinate.coordinate_median)(x)
     np.testing.assert_array_equal(
         np.asarray(got),
         np.asarray(coordinate.coordinate_median_reference(jnp.asarray(x))),

@@ -363,14 +363,17 @@ class TestBenchArtifacts:
         bench._emit_jsonl({
             "metric": "byzsgd_steps_per_sec_per_chip", "value": 51.2,
             "unit": "steps/s/chip", "vs_baseline": 1.01, "mfu": 0.3,
+            "chunk_steps": 1, "platform": "tpu",
+            "device_kind": "TPU v5 lite", "n_devices": 1,
         })
-        bench._emit_jsonl({"error": "RuntimeError: tunnel down"})
-        assert validate_jsonl(path) == 2
+        assert validate_jsonl(path) == 1
         with open(path) as fp:
-            recs = [json.loads(l) for l in fp]
-        assert recs[0]["value"] == 51.2
-        assert recs[1]["metric"] == "error"
-        assert recs[1]["error"].startswith("RuntimeError")
+            rec = json.loads(fp.readline())
+        assert rec["value"] == 51.2
+        # Every bench record names the device it ran on.
+        assert (rec["platform"], rec["device_kind"], rec["n_devices"]) == (
+            "tpu", "TPU v5 lite", 1
+        )
 
     def test_gar_bench_emits_jsonl_twin(self, tmp_path):
         from garfield_tpu.apps.benchmarks import gar_bench
@@ -390,6 +393,8 @@ class TestBenchArtifacts:
             REPO_ROOT.glob("*telemetry*.jsonl")
         )
         for path in dict.fromkeys(found):
+            if path.name == "PERF_LEDGER.jsonl":
+                continue  # the driver's own record, not a telemetry stream
             validate_jsonl(path)  # raises loudly on any malformed line
 
 
