@@ -30,11 +30,14 @@ of them and the compiled step must contain an all-gather.
 The first act is to fail (non-zero, one line, nothing compiled) unless
 ``jax.devices()[0].platform == "tpu"``; the script never sets
 ``jax_platforms``. A failed leg is a traceback and a non-zero exit — there
-is no retry and no fallback. The last stdout line is one JSON object
-(``ok``, ``device``, per-leg facts, ``claim: null``); every wall-clock in it
-is labelled information, not a metric. Tests drive ``run`` with a toy
-``Size`` on the CPU (tests/test_entry_points.py); the default invocation has
-no way around the device check.
+is no retry and no fallback. The last stdout line is the result and nothing
+else: ``{"ok": true, "device": {"platform", "kind", "count"}}``, the device
+as JAX reports it — whoever checks the run parses that line and accepts no
+other key. The line before it, ``[chip_smoke] summary {...}``, carries the
+per-leg facts, versions, cache directory and ``claim: null``; every
+wall-clock in it is labelled information, not a metric. Tests drive ``run``
+and ``report`` with a toy ``Size`` on the CPU (tests/test_entry_points.py);
+the default invocation has no way around the device check.
 """
 
 import contextlib
@@ -345,6 +348,24 @@ def run(size):
     return legs
 
 
+def report(device_facts, details, legs):
+    """The two closing stdout lines: the summary, then the result line —
+    exactly ``ok`` and ``device``, last."""
+    ok = all(leg["ok"] for leg in legs.values())
+    summary = {
+        "ok": ok,
+        "device": device_facts,
+        **details,
+        "legs": legs,
+        "note": "compile_s, cache_hits and *_info fields are information, "
+                "not metrics",
+        "claim": None,
+    }
+    print(f"[chip_smoke] summary {json.dumps(summary)}", flush=True)
+    print(json.dumps({"ok": ok, "device": device_facts}), flush=True)
+    return ok
+
+
 def main():
     device = require_tpu()
 
@@ -357,12 +378,12 @@ def main():
     from garfield_tpu.utils import profiling
 
     bench.peak_bf16(device)  # a device kind without a published peak: error
-    facts = {
-        "device": {
-            "platform": device.platform,
-            "kind": device.device_kind,
-            "count": len(jax.devices()),
-        },
+    device_facts = {
+        "platform": device.platform,
+        "kind": device.device_kind,
+        "count": len(jax.devices()),
+    }
+    details = {
         "versions": {
             "jax": jax.__version__,
             "jaxlib": jaxlib.__version__,
@@ -370,16 +391,11 @@ def main():
         },
         "cache_dir": profiling.enable_compile_cache(),
     }
-    print(f"[chip_smoke] {json.dumps(facts)}", flush=True)
+    print(f"[chip_smoke] {json.dumps({'device': device_facts, **details})}",
+          flush=True)
     legs = run(FULL)
-    print(json.dumps({
-        "ok": all(leg["ok"] for leg in legs.values()),
-        **facts,
-        "legs": legs,
-        "note": "compile_s, cache_hits and *_info fields are information, "
-                "not metrics",
-        "claim": None,
-    }))
+    if not report(device_facts, details, legs):
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -71,6 +71,27 @@ def test_chip_smoke_refuses_off_chip():
     assert "Traceback" not in proc.stderr
 
 
+def test_chip_smoke_last_line_is_exactly_ok_and_device(capsys):
+    """Whoever checks a chip run parses the last stdout line and accepts no
+    key beyond ``ok`` and ``device`` {platform, kind, count}; the per-leg
+    facts and ``claim: null`` ride on the summary line before it."""
+    smoke = _load("chip_smoke")
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    legs = {"kernels": {"ok": True, "compile_s": 1.0}}
+    assert smoke.report(device, {"cache_dir": "/x"}, legs) is True
+    summary, last = capsys.readouterr().out.splitlines()
+    assert json.loads(last) == {"ok": True, "device": device}
+    assert list(json.loads(last)) == ["ok", "device"]
+    tag = "[chip_smoke] summary "
+    assert summary.startswith(tag)
+    full = json.loads(summary[len(tag):])
+    assert full["legs"] == legs and full["device"] == device
+    assert list(full)[-1] == "claim" and full["claim"] is None
+    legs["kernels"]["ok"] = False
+    assert smoke.report(device, {}, legs) is False
+    assert json.loads(capsys.readouterr().out.splitlines()[-1])["ok"] is False
+
+
 def test_dryrun_multichip_raises_on_too_few_devices():
     entry = _load("__graft_entry__")
     n = len(jax.devices()) + 1
@@ -153,4 +174,5 @@ def test_chip_smoke_legs_at_toy_size():
     assert legs["trainer_krum"]["steps"] == 4
     assert legs["trainer_krum"]["eval_reports"] == 2
     assert legs["trainer_median"]["steps"] == 2
-    json.dumps(legs)  # the summary line must serialize
+    device = {"platform": "cpu", "kind": "cpu", "count": len(jax.devices())}
+    assert smoke.report(device, {}, legs)  # the closing lines must serialize
