@@ -244,7 +244,11 @@ def base_parser(description, *, default_model="convnet", default_loss="nll"):
     a("--resume", action="store_true",
       help="Resume from the latest checkpoint in --checkpoint_dir.")
     a("--profile_dir", type=str, default=None,
-      help="Write a jax.profiler trace of the steady-state steps here.")
+      help="Write one jax.profiler trace of a few whole steady-state "
+           "steps here (from the 6th step on, ended by a device sync). "
+           "Turns the host spans of --trace on, as profiler annotations: "
+           "the trace holds them on /host:CPU beside the device's "
+           "operations, which the step's phase scopes name.")
     a("--sync_eval", action="store_true",
       help="Run periodic accuracy inline (blocking) instead of overlapped "
            "with training in a side thread (the reference's accuracy "
@@ -402,8 +406,9 @@ def chunk_length(i, *, chunk, num_iter, acc_freq=0, checkpoint_freq=0,
         of ``checkpoint_freq`` above i;
       - **crash**: a ``--fault_crashes`` event at step s re-jits the step
         with the new Byzantine mask, so no chunk may span s;
-      - **profile**: the profiled step runs as its own single-step
-        dispatch so the trace holds exactly one step program;
+      - **profile**: the ``--profile_dir`` trace starts at a chunk's
+        first step, and that chunk is one step long (the steps traced
+        after it chunk as usual);
       - **end of run**: never past ``num_iter``.
     """
     end = min(i + chunk, num_iter)
@@ -528,8 +533,10 @@ def train(args, *, topology, make_trainer_kwargs, num_slots, tag):
         # Streaming sink (crash-safe): every record — per-step taps AND
         # the trace spans below — drains to the JSONL as it is recorded.
         tele_hub._sink = tele_exp
-        if trace_lib.requested(args):
-            trace_lib.enable(who=tag)
+    if trace_lib.requested(args) or args.profile_dir:
+        # Without a hub (--profile_dir alone) the spans are profiler
+        # annotations only.
+        trace_lib.enable(who=tag)
 
     # Targeted attacks (DESIGN.md §17): resolve the config once — the
     # trainer poisons the cohort's batches with it, and the eval loop
@@ -673,7 +680,8 @@ def train(args, *, topology, make_trainer_kwargs, num_slots, tag):
     # boundary clipping produces a handful of lengths at most. Invalidated
     # whenever the step itself is rebuilt (crash-schedule re-jit).
     crash_steps = sorted(set(sched.crashes.values())) if sched else []
-    profile_step = (start_iter + 5) if args.profile_dir else None
+    steps_trace = profiling.StepsTrace(args.profile_dir, start_iter + 5)
+    profile_step = steps_trace.first if args.profile_dir else None
     chunk_fns = {}
 
     def chunked_for(k):
@@ -705,13 +713,12 @@ def train(args, *, topology, make_trainer_kwargs, num_slots, tag):
             checkpoint_freq=(args.checkpoint_freq if ckpt else 0),
             crash_steps=crash_steps, profile_step=profile_step,
         )
-        profiling_this = profile_step is not None and i == profile_step
+        steps_trace.before(i, metrics)
         # Span semantics without --bench: dispatch is asynchronous, so
         # the span covers ENQUEUE time only (tag blocked=False); with
         # --bench the block_until_ready makes it the honest device time.
-        with profiling.trace(args.profile_dir if profiling_this else None), \
-                trace_lib.span("dispatch", step=i, chunk=k,
-                               blocked=bool(args.bench)):
+        with trace_lib.span("dispatch", step=i, chunk=k,
+                            blocked=bool(args.bench)):
             if k == 1:
                 b = i % num_batches
                 if args.bench:
@@ -979,8 +986,10 @@ def train(args, *, topology, make_trainer_kwargs, num_slots, tag):
         if ckpt and args.checkpoint_freq and end % args.checkpoint_freq == 0:
             with trace_lib.span("checkpoint", step=end - 1):
                 ckpt.save(end, jax.tree.map(np.asarray, state))
+        steps_trace.after(end, metrics)
         i = end
 
+    steps_trace.after(None, metrics)  # a run shorter than the trace
     jax.block_until_ready(state.step)  # drain async dispatch for honest wall
     train_wall = time.time() - t_train
     for t in eval_threads:  # flush overlapped accuracy reports
@@ -1044,10 +1053,10 @@ def train(args, *, topology, make_trainer_kwargs, num_slots, tag):
         **{f"step_{k}": v for k, v in timer.summary().items()},
     }
     print(json.dumps({"tag": tag, **summary}), flush=True)
+    trace_lib.disable()
     if tele_hub is not None:
         from ..telemetry import exporters as tele_fmt, hub as tele_hub_lib
 
-        trace_lib.disable()
         tele_hub._sink = None  # summary is written once, explicitly
         tele_exp.write(tele_hub.summary())
         with open(os.path.join(args.telemetry, "metrics.prom"), "w") as fp:

@@ -181,8 +181,10 @@ def _avgmed_kernel(s, beta, quant_dtype, x_ref, o_ref):
     o_ref[0, :] = (acc / beta).astype(o_ref.dtype)
 
 
-def _column_call(kernel, g, tile, interpret):
-    """Run a (n, TILE) -> (1, TILE) kernel over d-tiles of g."""
+def _column_call(kernel, g, tile, interpret, name):
+    """Run a (n, TILE) -> (1, TILE) kernel over d-tiles of g. ``name`` is
+    the custom call's name in the compiled program, and so its events' name
+    in a device trace (``%coordinate_median.N``)."""
     if tile % _LANES:
         raise ValueError(f"tile must be a multiple of {_LANES}, got {tile}")
     g, d = _pad_cols(g, tile)
@@ -194,6 +196,7 @@ def _column_call(kernel, g, tile, interpret):
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, dp), g.dtype),
         interpret=interpret,
+        name=name,
     )(g)
     return out[0, :d]
 
@@ -271,7 +274,7 @@ def _dispatch(g, kernel, fallback_fn, tile, interpret, n, op):
 
     def run_kernel(a, interp):
         out = _column_call(
-            kernel, a.astype(jnp.float32) if half else a, tile, interp
+            kernel, a.astype(jnp.float32) if half else a, tile, interp, op
         )
         return out.astype(orig) if half else out
 

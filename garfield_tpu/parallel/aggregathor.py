@@ -113,18 +113,20 @@ def _attack_then_aggregate(
     the host-plane PS aggregates: poisoned, quorum-selected, then
     staleness-weighted (utils/rounds.py, DESIGN.md §14)."""
     n = flat_stack.shape[0]
-    stack = apply_gradient_attack(
-        attack, flat_stack, byz_mask, key=atk_key, **attack_params
-    )
-    if subset is not None and subset < n:
-        sel = core.subset_indices(sub_key, n, subset)
-        stack = stack[sel]
+    with core.phase("attack"):
+        stack = apply_gradient_attack(
+            attack, flat_stack, byz_mask, key=atk_key, **attack_params
+        )
+    with core.phase("rule"):
+        if subset is not None and subset < n:
+            sel = core.subset_indices(sub_key, n, subset)
+            stack = stack[sel]
+            if row_weights is not None:
+                row_weights = row_weights[sel]
         if row_weights is not None:
-            row_weights = row_weights[sel]
-    if row_weights is not None:
-        stack = (stack * row_weights[:, None]).astype(stack.dtype)
-    extra = {} if center is None else {"center": center}
-    return gar.unchecked(stack, f=f, key=gar_key, **gar_params, **extra)
+            stack = (stack * row_weights[:, None]).astype(stack.dtype)
+        extra = {} if center is None else {"center": center}
+        return gar.unchecked(stack, f=f, key=gar_key, **gar_params, **extra)
 
 
 def make_trainer(
@@ -620,27 +622,28 @@ def make_trainer(
             # of this (the defense-off bitwise contract).
             byz_local = byz_mask[slot_ids]
             xs_p, ys_p = [], []
-            for k in range(per_shard):
-                xk, yk = targeted_lib.poison_batch(
-                    targeted_cfg, x_local[k], y_local[k], seed=k,
-                    step=state.step,
+            with core.phase("attack"):
+                for k in range(per_shard):
+                    xk, yk = targeted_lib.poison_batch(
+                        targeted_cfg, x_local[k], y_local[k], seed=k,
+                        step=state.step,
+                    )
+                    xs_p.append(xk)
+                    ys_p.append(yk)
+                x_pois = jnp.stack(xs_p)
+                y_pois = jnp.stack(ys_p)
+                x_local = jnp.where(
+                    byz_local.reshape(
+                        (per_shard,) + (1,) * (x_local.ndim - 1)
+                    ),
+                    x_pois, x_local,
                 )
-                xs_p.append(xk)
-                ys_p.append(yk)
-            x_pois = jnp.stack(xs_p)
-            y_pois = jnp.stack(ys_p)
-            x_local = jnp.where(
-                byz_local.reshape(
-                    (per_shard,) + (1,) * (x_local.ndim - 1)
-                ),
-                x_pois, x_local,
-            )
-            y_local = jnp.where(
-                byz_local.reshape(
-                    (per_shard,) + (1,) * (y_local.ndim - 1)
-                ),
-                y_pois, y_local,
-            )
+                y_local = jnp.where(
+                    byz_local.reshape(
+                        (per_shard,) + (1,) * (y_local.ndim - 1)
+                    ),
+                    y_pois, y_local,
+                )
 
         # Unrolled (not vmapped) per-slot gradients: kills the 5-D relayout
         # tax of the logical-worker fold (core.per_slot_grads docstring).
@@ -655,13 +658,16 @@ def make_trainer(
         )
         # Narrow the aggregation pipeline (see make_trainer docstring); the
         # cast fuses into the backward's output writes. No-op when None.
-        grads_local = core.cast_leaves(grads_local, gar_dtype)
+        with core.phase("grads"):
+            grads_local = core.cast_leaves(grads_local, gar_dtype)
 
         # all_gather over the mesh axis == Server.get_gradients (RPC gather).
-        grads = jax.tree.map(
-            lambda l: jax.lax.all_gather(l, axis, tiled=True), grads_local
-        )
-        losses = jax.lax.all_gather(loss_local, axis, tiled=True)
+        with core.phase("exchange"):
+            grads = jax.tree.map(
+                lambda l: jax.lax.all_gather(l, axis, tiled=True),
+                grads_local,
+            )
+            losses = jax.lax.all_gather(loss_local, axis, tiled=True)
         new_ms = core.mean_model_state(ms_local, axis)
 
         # Worker momentum (see make_trainer docstring): every worker submits
@@ -670,9 +676,10 @@ def make_trainer(
         # the honest update is stored, the attack poisons its rows after.
         new_mom = state.worker_mom
         if worker_momentum is not None:
-            grads = core.worker_mom_update(
-                worker_momentum, state.worker_mom, grads
-            )
+            with core.phase("grads"):  # the workers' side of the exchange
+                grads = core.worker_mom_update(
+                    worker_momentum, state.worker_mom, grads
+                )
             new_mom = grads
 
         # Wire-compression emulation (see docstring): encode->decode the
@@ -684,24 +691,26 @@ def make_trainer(
         # fold/row-weight algebra is untouched by construction.
         new_wire = state.wire_state
         if wire_scheme is not None:
-            flat_w = core.flatten_rows(grads).astype(jnp.float32)
-            w_k = (
-                wire_lib.topk_k(flat_w.shape[1], wire_div)
-                if wire_scheme == "topk" else None
-            )
-            if wire_ef:
-                sent_w, resid_w = compress_lib.ef_roundtrip_rows(
-                    flat_w, state.wire_state["resid"], wire_scheme, k=w_k
+            with core.phase("exchange"):
+                flat_w = core.flatten_rows(grads).astype(jnp.float32)
+                w_k = (
+                    wire_lib.topk_k(flat_w.shape[1], wire_div)
+                    if wire_scheme == "topk" else None
                 )
-                new_wire = {"resid": resid_w}
-            else:
-                sent_w = compress_lib.roundtrip_rows(
-                    flat_w, wire_scheme, k=w_k
-                )
-            grads = jax.vmap(
-                lambda r: core.unflatten_like(params, r)
-            )(sent_w)
-            grads = core.cast_leaves(grads, gar_dtype)
+                if wire_ef:
+                    sent_w, resid_w = compress_lib.ef_roundtrip_rows(
+                        flat_w, state.wire_state["resid"], wire_scheme,
+                        k=w_k,
+                    )
+                    new_wire = {"resid": resid_w}
+                else:
+                    sent_w = compress_lib.roundtrip_rows(
+                        flat_w, wire_scheme, k=w_k
+                    )
+                grads = jax.vmap(
+                    lambda r: core.unflatten_like(params, r)
+                )(sent_w)
+                grads = core.cast_leaves(grads, gar_dtype)
 
         honest = (~byz_mask).astype(losses.dtype)
         mean_loss = jnp.sum(losses * honest) / jnp.sum(honest)
@@ -734,29 +743,30 @@ def make_trainer(
         atk_mag = degraded = None
         a_lo = a_hi = None
         if adaptive_cfg is not None:
-            a_lo = state.attack_state["lo"]
-            a_hi = state.attack_state["hi"]
-            atk_mag = adaptive_lib.played_magnitude(a_lo, a_hi)
-            if stale_w is not None:
-                # Quorum-degradation window (emulated): an HONEST rank at
-                # the staleness cutoff's floor weight (or excluded
-                # outright) — the emulation clips taus to the cutoff, so
-                # the floor IS the hard-cut signature a host-plane
-                # straggler/partition produces.
-                floor_w = jnp.float32(
-                    (stale_decay ** stale_ms) * (1.0 + 1e-5)
+            with core.phase("attack"):
+                a_lo = state.attack_state["lo"]
+                a_hi = state.attack_state["hi"]
+                atk_mag = adaptive_lib.played_magnitude(a_lo, a_hi)
+                if stale_w is not None:
+                    # Quorum-degradation window (emulated): an HONEST rank at
+                    # the staleness cutoff's floor weight (or excluded
+                    # outright) — the emulation clips taus to the cutoff, so
+                    # the floor IS the hard-cut signature a host-plane
+                    # straggler/partition produces.
+                    floor_w = jnp.float32(
+                        (stale_decay ** stale_ms) * (1.0 + 1e-5)
+                    )
+                    degraded = jnp.any((stale_w <= floor_w) & ~byz_mask)
+                    atk_mag = jnp.where(
+                        degraded, jnp.float32(adaptive_cfg.burst_mag), atk_mag
+                    )
+                act_mask = adaptive_lib.active_mask_traced(
+                    adaptive_cfg, state.step
                 )
-                degraded = jnp.any((stale_w <= floor_w) & ~byz_mask)
-                atk_mag = jnp.where(
-                    degraded, jnp.float32(adaptive_cfg.burst_mag), atk_mag
-                )
-            act_mask = adaptive_lib.active_mask_traced(
-                adaptive_cfg, state.step
-            )
-            eff_params = dict(attack_params)
-            eff_params[
-                adaptive_lib.magnitude_key(adaptive_cfg.base)
-            ] = atk_mag
+                eff_params = dict(attack_params)
+                eff_params[
+                    adaptive_lib.magnitude_key(adaptive_cfg.base)
+                ] = atk_mag
 
         # Closed-loop defense weights (aggregators/defense.py): suspicion
         # from the carried exclusion EMA, composed into the SAME row-
@@ -792,17 +802,18 @@ def make_trainer(
                     "data-plane defense needs a classifier head (no "
                     "2-D parameter leaf in this model)"
                 )
-            dp_scores, flags_b = dataplane_lib.detect(
-                head_k, head_b, f=max(1, f), tau=dp_tau
-            )
-            dp_flags = flags_b.astype(jnp.float32)
-            dp_susp = state.defense_state["dp_exc"] / jnp.maximum(
-                state.defense_state["dp_obs"], 1e-6
-            )
-            dp_w = defense_lib.suspicion_weights(
-                dp_susp, power=dp_power, floor=dp_floor
-            )
-            grads = dataplane_lib.center_pull_tree(grads, dp_w)
+            with core.phase("rule"):
+                dp_scores, flags_b = dataplane_lib.detect(
+                    head_k, head_b, f=max(1, f), tau=dp_tau
+                )
+                dp_flags = flags_b.astype(jnp.float32)
+                dp_susp = state.defense_state["dp_exc"] / jnp.maximum(
+                    state.defense_state["dp_obs"], 1e-6
+                )
+                dp_w = defense_lib.suspicion_weights(
+                    dp_susp, power=dp_power, floor=dp_floor
+                )
+                grads = dataplane_lib.center_pull_tree(grads, dp_w)
         row_w = stale_w
         if def_w is not None:
             row_w = def_w if row_w is None else row_w * def_w
@@ -841,10 +852,11 @@ def make_trainer(
                 # the adaptive magnitude into the shared fake row
                 # (traced_fold_plan), so the fast path survives both the
                 # async emulation and the adaptive adversary.
-                plan_now = (
-                    adaptive_lib.traced_fold_plan(adaptive_cfg, atk_mag)
-                    if adaptive_fold else fold_plan
-                )
+                with core.phase("attack"):
+                    plan_now = (
+                        adaptive_lib.traced_fold_plan(adaptive_cfg, atk_mag)
+                        if adaptive_fold else fold_plan
+                    )
                 out = fold.folded_tree_aggregate(
                     gar, plan_now, grads, f=f, key=gar_key,
                     gar_params={**gar_params, **center_kw},
@@ -857,18 +869,20 @@ def make_trainer(
                 else:
                     aggr_tree = out
             else:
-                poisoned = apply_gradient_attack_tree(
-                    attack, grads, act_mask, key=atk_key, **eff_params
-                )
+                with core.phase("attack"):
+                    poisoned = apply_gradient_attack_tree(
+                        attack, grads, act_mask, key=atk_key, **eff_params
+                    )
                 if row_w is not None:
                     # Weight the post-attack rows — what the host-plane
                     # PS aggregates (poisoned arrivals, then discounted).
-                    poisoned = jax.tree.map(
-                        lambda l: (l * row_w.reshape(
-                            (num_workers,) + (1,) * (l.ndim - 1)
-                        )).astype(l.dtype),
-                        poisoned,
-                    )
+                    with core.phase("rule"):
+                        poisoned = jax.tree.map(
+                            lambda l: (l * row_w.reshape(
+                                (num_workers,) + (1,) * (l.ndim - 1)
+                            )).astype(l.dtype),
+                            poisoned,
+                        )
                 if sel is not None:
                     # Wait-n-f on the Gram: select on the (q, q) sub-Gram,
                     # scatter the weights back — per-leaf row gathers never
@@ -877,22 +891,25 @@ def make_trainer(
                         tree_gram, tree_weighted_sum,
                     )
 
-                    gram = tree_gram(poisoned)
-                    w_sub = gar.gram_select(
-                        gram[sel][:, sel], f=f, key=gar_key, **gar_params
-                    )
-                    w = jnp.zeros(
-                        (num_workers,), jnp.float32
-                    ).at[sel].set(w_sub)
-                    aggr_tree = tree_weighted_sum(poisoned, w)
+                    with core.phase("rule"):
+                        gram = tree_gram(poisoned)
+                        w_sub = gar.gram_select(
+                            gram[sel][:, sel], f=f, key=gar_key,
+                            **gar_params
+                        )
+                        w = jnp.zeros(
+                            (num_workers,), jnp.float32
+                        ).at[sel].set(w_sub)
+                        aggr_tree = tree_weighted_sum(poisoned, w)
                     if need_sel:
                         sel_w = w
                         quorum_idx = sel
                 else:
-                    aggr_tree = gar.tree_aggregate(
-                        poisoned, f=f, key=gar_key, **gar_params,
-                        **center_kw
-                    )
+                    with core.phase("rule"):
+                        aggr_tree = gar.tree_aggregate(
+                            poisoned, f=f, key=gar_key, **gar_params,
+                            **center_kw
+                        )
         elif granularity == "layer":
             # Garfield_CC per-parameter aggregation: independent GAR (and
             # attack statistics) per tensor, like the reference's per-layer
@@ -919,16 +936,18 @@ def make_trainer(
                 out_leaves.append(aggr.reshape(leaf.shape[1:]))
             aggr_tree = jax.tree.unflatten(treedef, out_leaves)
         else:
-            flat_stack = core.flatten_rows(grads)  # (n_w, d)
-            flat_center = (
-                {"center": ravel_pytree(state.gar_state)[0]}
-                if gar.stateful_center else {}
-            )
+            with core.phase("rule"):
+                flat_stack = core.flatten_rows(grads)  # (n_w, d)
+                flat_center = (
+                    {"center": ravel_pytree(state.gar_state)[0]}
+                    if gar.stateful_center else {}
+                )
             aggr = _attack_then_aggregate(
                 flat_stack, act_mask, atk_key, sub_key, gar_key,
                 **agg_kwargs, **flat_center,
             )
-            aggr_tree = core.unflatten_like(params, aggr)
+            with core.phase("rule"):
+                aggr_tree = core.unflatten_like(params, aggr)
 
         if need_sel and sel_w is None:
             # Feedback fallback: the aggregation path exposed no selection
@@ -937,35 +956,36 @@ def make_trainer(
             # SAME poisoned, weighted rows via the audit-tap machinery
             # (exactly the telemetry recomputation below; XLA CSEs the
             # shared subgraphs). Adaptive/defense-only cost.
-            flat_fb = core.flatten_rows(grads)
-            poisoned_fb = apply_gradient_attack(
-                attack, flat_fb, act_mask, key=atk_key, **eff_params
-            )
-            if row_w is not None:
-                poisoned_fb = (poisoned_fb * row_w[:, None]).astype(
-                    poisoned_fb.dtype
+            with core.phase("rule"):
+                flat_fb = core.flatten_rows(grads)
+                poisoned_fb = apply_gradient_attack(
+                    attack, flat_fb, act_mask, key=atk_key, **eff_params
                 )
-            fb_center = (
-                ravel_pytree(state.gar_state)[0]
-                if gar.stateful_center else None
-            )
-            if subset is not None and subset < num_workers:
-                quorum_idx = core.subset_indices(
-                    sub_key, num_workers, subset
+                if row_w is not None:
+                    poisoned_fb = (poisoned_fb * row_w[:, None]).astype(
+                        poisoned_fb.dtype
+                    )
+                fb_center = (
+                    ravel_pytree(state.gar_state)[0]
+                    if gar.stateful_center else None
                 )
-                bundle = taps_lib.compute_flat(
-                    gar.name, poisoned_fb[quorum_idx], f, key=gar_key,
-                    params=gar_params, center=fb_center,
-                )
-                sel_w = jnp.zeros((num_workers,), jnp.float32).at[
-                    quorum_idx
-                ].set(bundle["selected"])
-            else:
-                bundle = taps_lib.compute_flat(
-                    gar.name, poisoned_fb, f, key=gar_key,
-                    params=gar_params, center=fb_center,
-                )
-                sel_w = bundle["selected"]
+                if subset is not None and subset < num_workers:
+                    quorum_idx = core.subset_indices(
+                        sub_key, num_workers, subset
+                    )
+                    bundle = taps_lib.compute_flat(
+                        gar.name, poisoned_fb[quorum_idx], f, key=gar_key,
+                        params=gar_params, center=fb_center,
+                    )
+                    sel_w = jnp.zeros((num_workers,), jnp.float32).at[
+                        quorum_idx
+                    ].set(bundle["selected"])
+                else:
+                    bundle = taps_lib.compute_flat(
+                        gar.name, poisoned_fb, f, key=gar_key,
+                        params=gar_params, center=fb_center,
+                    )
+                    sel_w = bundle["selected"]
 
         obs_vec = None
         if need_sel:
@@ -983,74 +1003,79 @@ def make_trainer(
             # among the OBSERVED colluders counts as detected; a round
             # that observed none (whole cohort outside the quorum) and a
             # burst round (not the bracket's probe) hold the bracket.
-            act_f = act_mask.astype(jnp.float32) * obs_vec
-            cnt = jnp.sum(act_f)
-            admitted = jnp.sum((sel_w > 0).astype(jnp.float32) * act_f)
-            detected = admitted * 2.0 < cnt
-            upd_lo, upd_hi = adaptive_lib.update_bracket(
-                a_lo, a_hi, detected,
-                mag_min=adaptive_cfg.mag_min,
-                mag_max=adaptive_cfg.mag_max,
-                regrow=adaptive_cfg.regrow,
-            )
-            hold = cnt == 0.0
-            if degraded is not None:
-                hold = hold | degraded
-            new_attack_state = {
-                "lo": jnp.where(hold, a_lo, upd_lo),
-                "hi": jnp.where(hold, a_hi, upd_hi),
-            }
+            with core.phase("attack"):
+                act_f = act_mask.astype(jnp.float32) * obs_vec
+                cnt = jnp.sum(act_f)
+                admitted = jnp.sum((sel_w > 0).astype(jnp.float32) * act_f)
+                detected = admitted * 2.0 < cnt
+                upd_lo, upd_hi = adaptive_lib.update_bracket(
+                    a_lo, a_hi, detected,
+                    mag_min=adaptive_cfg.mag_min,
+                    mag_max=adaptive_cfg.mag_max,
+                    regrow=adaptive_cfg.regrow,
+                )
+                hold = cnt == 0.0
+                if degraded is not None:
+                    hold = hold | degraded
+                new_attack_state = {
+                    "lo": jnp.where(hold, a_lo, upd_lo),
+                    "hi": jnp.where(hold, a_hi, upd_hi),
+                }
 
         new_defense_state = state.defense_state
         if defense is not None:
-            new_defense_state = dict(state.defense_state)
-            if d_weighted:
-                # The hub's exclusion law (observed minus admitted),
-                # carried as an exponentially-decayed EMA — the in-graph
-                # twin of MetricsHub(suspicion_halflife=).
-                ind = (sel_w > 0).astype(jnp.float32) * obs_vec
-                dec = jnp.float32(d_decay)
-                new_defense_state["obs"] = (
-                    state.defense_state["obs"] * dec + obs_vec
-                )
-                new_defense_state["exc"] = (
-                    state.defense_state["exc"] * dec + (obs_vec - ind)
-                )
-            if dp_decay is not None:
-                # Data-plane twins: the detectors observe the FULL
-                # gathered stack every step (the subset emulation applies
-                # at selection, after the gather), so every rank is
-                # observed and a flag is an exclusion.
-                dpdec = jnp.float32(dp_decay)
-                ones = jnp.ones((num_workers,), jnp.float32)
-                new_defense_state["dp_obs"] = (
-                    state.defense_state["dp_obs"] * dpdec + ones
-                )
-                new_defense_state["dp_exc"] = (
-                    state.defense_state["dp_exc"] * dpdec + dp_flags
-                )
+            with core.phase("rule"):
+                new_defense_state = dict(state.defense_state)
+                if d_weighted:
+                    # The hub's exclusion law (observed minus admitted),
+                    # carried as an exponentially-decayed EMA — the in-graph
+                    # twin of MetricsHub(suspicion_halflife=).
+                    ind = (sel_w > 0).astype(jnp.float32) * obs_vec
+                    dec = jnp.float32(d_decay)
+                    new_defense_state["obs"] = (
+                        state.defense_state["obs"] * dec + obs_vec
+                    )
+                    new_defense_state["exc"] = (
+                        state.defense_state["exc"] * dec + (obs_vec - ind)
+                    )
+                if dp_decay is not None:
+                    # Data-plane twins: the detectors observe the FULL
+                    # gathered stack every step (the subset emulation applies
+                    # at selection, after the gather), so every rank is
+                    # observed and a flag is an exclusion.
+                    dpdec = jnp.float32(dp_decay)
+                    ones = jnp.ones((num_workers,), jnp.float32)
+                    new_defense_state["dp_obs"] = (
+                        state.defense_state["dp_obs"] * dpdec + ones
+                    )
+                    new_defense_state["dp_exc"] = (
+                        state.defense_state["dp_exc"] * dpdec + dp_flags
+                    )
 
-        new_gar_state = state.gar_state
-        if gar.stateful_center:
-            # Next step's v_0 = this step's aggregate (f32 — the carried
-            # center should not round through the bf16 pipeline).
-            new_gar_state = jax.tree.map(
-                lambda l: l.astype(jnp.float32), aggr_tree
+        with core.phase("update"):
+            new_gar_state = state.gar_state
+            if gar.stateful_center:
+                # Next step's v_0 = this step's aggregate (f32 — the carried
+                # center should not round through the bf16 pipeline).
+                new_gar_state = jax.tree.map(
+                    lambda l: l.astype(jnp.float32), aggr_tree
+                )
+            aggr_tree = core.cast_like(aggr_tree, params)  # no-op at f32
+            updates, new_opt = optimizer.update(
+                aggr_tree, state.opt_state, params
             )
-        aggr_tree = core.cast_like(aggr_tree, params)  # no-op at f32
-        updates, new_opt = optimizer.update(aggr_tree, state.opt_state, params)
-        new_params = optax.apply_updates(params, updates)
-        new_state = state.replace(
-            step=state.step + 1,
-            params=new_params,
-            model_state=new_ms,
-            opt_state=new_opt,
-            worker_mom=new_mom,
-            gar_state=new_gar_state,
-            attack_state=new_attack_state,
-            defense_state=new_defense_state,
-            wire_state=new_wire,
-        )
+            new_params = optax.apply_updates(params, updates)
+            new_state = state.replace(
+                step=state.step + 1,
+                params=new_params,
+                model_state=new_ms,
+                opt_state=new_opt,
+                worker_mom=new_mom,
+                gar_state=new_gar_state,
+                attack_state=new_attack_state,
+                defense_state=new_defense_state,
+                wire_state=new_wire,
+            )
         metrics = {"loss": mean_loss}
         if wire_ef:
             # Per-rank EF residual L2 norms — the in-graph twin of the
@@ -1083,35 +1108,36 @@ def make_trainer(
             # pass; on the tree/fold paths it is the enabled-only
             # overhead the docstring prices. Nothing here flows into
             # new_state, so the trajectory is untouched.
-            flat_raw = core.flatten_rows(grads)
-            poisoned = apply_gradient_attack(
-                attack, flat_raw, act_mask, key=atk_key, **eff_params
-            )
-            if row_w is not None:
-                # The tap audits the rule's selection over the SAME rows
-                # the rule consumed — staleness- and suspicion-weighted
-                # (and adaptively poisoned) included.
-                poisoned = (poisoned * row_w[:, None]).astype(
-                    poisoned.dtype
+            with core.phase("rule"):  # a tap recomputes the rule's view
+                flat_raw = core.flatten_rows(grads)
+                poisoned = apply_gradient_attack(
+                    attack, flat_raw, act_mask, key=atk_key, **eff_params
                 )
-            tap_center = (
-                ravel_pytree(state.gar_state)[0]
-                if gar.stateful_center else None
-            )
-            if subset is not None and subset < num_workers:
-                tap_sel = core.subset_indices(sub_key, num_workers, subset)
-                bundle = taps_lib.compute_flat(
-                    gar.name, poisoned[tap_sel], f, key=gar_key,
-                    params=gar_params, center=tap_center,
+                if row_w is not None:
+                    # The tap audits the rule's selection over the SAME rows
+                    # the rule consumed — staleness- and suspicion-weighted
+                    # (and adaptively poisoned) included.
+                    poisoned = (poisoned * row_w[:, None]).astype(
+                        poisoned.dtype
+                    )
+                tap_center = (
+                    ravel_pytree(state.gar_state)[0]
+                    if gar.stateful_center else None
                 )
-                metrics["tap"] = taps_lib.scatter(
-                    bundle, tap_sel, num_workers
-                )
-            else:
-                metrics["tap"] = taps_lib.compute_flat(
-                    gar.name, poisoned, f, key=gar_key, params=gar_params,
-                    center=tap_center,
-                )
+                if subset is not None and subset < num_workers:
+                    tap_sel = core.subset_indices(sub_key, num_workers, subset)
+                    bundle = taps_lib.compute_flat(
+                        gar.name, poisoned[tap_sel], f, key=gar_key,
+                        params=gar_params, center=tap_center,
+                    )
+                    metrics["tap"] = taps_lib.scatter(
+                        bundle, tap_sel, num_workers
+                    )
+                else:
+                    metrics["tap"] = taps_lib.compute_flat(
+                        gar.name, poisoned, f, key=gar_key, params=gar_params,
+                        center=tap_center,
+                    )
         return new_state, metrics
 
     sharded_step = jax.shard_map(

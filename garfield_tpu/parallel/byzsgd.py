@@ -367,29 +367,32 @@ def make_trainer(
         gkey = jax.random.fold_in(gar_key, ps_id)
         stack = grads_stack
         n = stack.shape[0]
-        if subset is not None and subset < n:
-            sel = core.subset_indices(
-                jax.random.fold_in(sub_key, ps_id), n, subset
-            )
-            stack = stack[sel]
+        with core.phase("rule"):
+            if subset is not None and subset < n:
+                sel = core.subset_indices(
+                    jax.random.fold_in(sub_key, ps_id), n, subset
+                )
+                stack = stack[sel]
+                if row_weights is not None:
+                    row_weights = row_weights[sel]
             if row_weights is not None:
-                row_weights = row_weights[sel]
-        if row_weights is not None:
-            stack = (stack * row_weights[:, None]).astype(stack.dtype)
-        if granularity == "layer":
-            aggr = core.segmented_aggregate(
-                lambda s, i: gar.unchecked(
-                    s, f=fw, key=jax.random.fold_in(gkey, i), **gar_params
-                ),
-                stack,
-                core.leaf_segments(params),
-            )
-        else:
-            aggr = gar.unchecked(stack, f=fw, key=gkey, **gar_params)
-        aggr_tree = core.unflatten_like(params, aggr)
-        aggr_tree = core.cast_like(aggr_tree, params)  # no-op at f32
-        updates, new_opt = optimizer.update(aggr_tree, opt_state, params)
-        return optax.apply_updates(params, updates), new_opt
+                stack = (stack * row_weights[:, None]).astype(stack.dtype)
+            if granularity == "layer":
+                aggr = core.segmented_aggregate(
+                    lambda s, i: gar.unchecked(
+                        s, f=fw, key=jax.random.fold_in(gkey, i),
+                        **gar_params
+                    ),
+                    stack,
+                    core.leaf_segments(params),
+                )
+            else:
+                aggr = gar.unchecked(stack, f=fw, key=gkey, **gar_params)
+            aggr_tree = core.unflatten_like(params, aggr)
+        with core.phase("update"):
+            aggr_tree = core.cast_like(aggr_tree, params)  # no-op at f32
+            updates, new_opt = optimizer.update(aggr_tree, opt_state, params)
+            return optax.apply_updates(params, updates), new_opt
 
     def _local_step(state, x_local, y_local):
         base = jax.random.fold_in(state.rng, state.step)
@@ -450,15 +453,17 @@ def make_trainer(
                 grad_fn, params, ms, x_local, y_local, keys,
                 fused_fn=slot_fused_fn, force_unroll=force_unroll,
             )
-            g = core.cast_leaves(g, gar_dtype)
-            if tree_ok:
-                gathered = jax.tree.map(
-                    lambda l: jax.lax.all_gather(l, axis, tiled=True), g
-                )  # tree with (n_w, ...) leaves
-                return gathered, loss, ms_out
-            flat = core.flatten_rows(g)  # (per_w, d)
-            stack = jax.lax.all_gather(flat, axis, tiled=True)  # (n_w, d)
-            return stack, loss, ms_out
+            with core.phase("grads"):
+                g = core.cast_leaves(g, gar_dtype)
+            with core.phase("exchange"):
+                if tree_ok:
+                    gathered = jax.tree.map(
+                        lambda l: jax.lax.all_gather(l, axis, tiled=True), g
+                    )  # tree with (n_w, ...) leaves
+                    return gathered, loss, ms_out
+                flat = core.flatten_rows(g)  # (per_w, d)
+                stack = jax.lax.all_gather(flat, axis, tiled=True)  # (n_w, d)
+                return stack, loss, ms_out
 
         # Unrolled over the (small, static) local PS slots: a vmap here would
         # batch conv kernels over the ps axis, which XLA's conv batching
@@ -493,90 +498,102 @@ def make_trainer(
                         gar_params=gar_params,
                     )
                 else:
-                    poisoned = apply_gradient_attack_tree(
-                        attack, outs[k][0], byz_worker_mask, key=atk_key,
-                        **attack_params,
-                    )
-                    aggr_tree = gar.tree_aggregate(
-                        poisoned, f=fw, key=slot_gar_key, **gar_params,
-                    )
-                p_k = jax.tree.map(lambda l: l[k], state.params)
-                o_k = jax.tree.map(lambda l: l[k], state.opt_state)
-                aggr_tree = core.cast_like(aggr_tree, p_k)  # no-op at f32
-                updates, o_k = optimizer.update(aggr_tree, o_k, p_k)
-                new_params_list.append(optax.apply_updates(p_k, updates))
-                new_opt_list.append(o_k)
-            new_params = jax.tree.map(
-                lambda *ls: jnp.stack(ls), *new_params_list
-            )
-            new_opt = jax.tree.map(lambda *ls: jnp.stack(ls), *new_opt_list)
+                    with core.phase("attack"):
+                        poisoned = apply_gradient_attack_tree(
+                            attack, outs[k][0], byz_worker_mask, key=atk_key,
+                            **attack_params,
+                        )
+                    with core.phase("rule"):
+                        aggr_tree = gar.tree_aggregate(
+                            poisoned, f=fw, key=slot_gar_key, **gar_params,
+                        )
+                with core.phase("update"):
+                    p_k = jax.tree.map(lambda l: l[k], state.params)
+                    o_k = jax.tree.map(lambda l: l[k], state.opt_state)
+                    aggr_tree = core.cast_like(aggr_tree, p_k)  # no-op at f32
+                    updates, o_k = optimizer.update(aggr_tree, o_k, p_k)
+                    new_params_list.append(optax.apply_updates(p_k, updates))
+                    new_opt_list.append(o_k)
+            with core.phase("update"):
+                new_params = jax.tree.map(
+                    lambda *ls: jnp.stack(ls), *new_params_list
+                )
+                new_opt = jax.tree.map(
+                    lambda *ls: jnp.stack(ls), *new_opt_list
+                )
             if telemetry:
                 # Per-PS audit taps on the gradient plane (no subsets on
                 # this branch — see tree_ok): each slot's gathered tree
                 # differs (its own replica's gradients), so tap each and
                 # average; pmean folds in the other PS shards.
-                bundles = [
-                    taps_lib.compute_flat(
-                        gar.name,
-                        apply_gradient_attack(
-                            attack, core.flatten_rows(outs[k][0]),
-                            byz_worker_mask, key=atk_key, **attack_params,
-                        ),
-                        fw, key=jax.random.fold_in(gar_key, ps_ids[k]),
-                        params=gar_params,
+                with core.phase("rule"):  # a tap recomputes the rule's view
+                    bundles = [
+                        taps_lib.compute_flat(
+                            gar.name,
+                            apply_gradient_attack(
+                                attack, core.flatten_rows(outs[k][0]),
+                                byz_worker_mask, key=atk_key, **attack_params,
+                            ),
+                            fw, key=jax.random.fold_in(gar_key, ps_ids[k]),
+                            params=gar_params,
+                        )
+                        for k in range(per_ps)
+                    ]
+                    tap = taps_lib.mean_bundles(
+                        jax.tree.map(lambda *ls: jnp.stack(ls), *bundles)
                     )
-                    for k in range(per_ps)
-                ]
-                tap = taps_lib.mean_bundles(
-                    jax.tree.map(lambda *ls: jnp.stack(ls), *bundles)
-                )
         else:
-            stacks = jnp.stack([o[0] for o in outs])  # (per_ps, n_w, d)
-            stacks = jax.vmap(
-                lambda s: apply_gradient_attack(
-                    attack, s, byz_worker_mask, key=atk_key, **attack_params
-                )
-            )(stacks)
+            with core.phase("attack"):
+                stacks = jnp.stack([o[0] for o in outs])  # (per_ps, n_w, d)
+                stacks = jax.vmap(
+                    lambda s: apply_gradient_attack(
+                        attack, s, byz_worker_mask, key=atk_key,
+                        **attack_params
+                    )
+                )(stacks)
 
             new_params, new_opt = jax.vmap(
                 _ps_slot_step, in_axes=(0, 0, 0, 0, None, None)
             )(ps_ids, state.params, state.opt_state, stacks,
               (sub_key, gar_key), def_w)
             if telemetry or defense is not None:
-                def one_tap(ps_id, stack):
-                    # SAME (sel, key, weight) derivation as _ps_slot_step,
-                    # so the tap audits exactly the (suspicion-weighted)
-                    # quorum this PS aggregated — the defense's feedback.
-                    gkey = jax.random.fold_in(gar_key, ps_id)
-                    if subset is not None and subset < num_workers:
-                        sel = core.subset_indices(
-                            jax.random.fold_in(sub_key, ps_id),
-                            num_workers, subset,
-                        )
-                        sub = stack[sel]
-                        if def_w is not None:
-                            sub = (sub * def_w[sel][:, None]).astype(
-                                sub.dtype
+                with core.phase("rule"):  # a tap recomputes the rule's view
+                    def one_tap(ps_id, stack):
+                        # SAME (sel, key, weight) derivation as _ps_slot_step,
+                        # so the tap audits exactly the (suspicion-weighted)
+                        # quorum this PS aggregated — the defense's feedback.
+                        gkey = jax.random.fold_in(gar_key, ps_id)
+                        if subset is not None and subset < num_workers:
+                            sel = core.subset_indices(
+                                jax.random.fold_in(sub_key, ps_id),
+                                num_workers, subset,
                             )
-                        bundle = taps_lib.compute_flat(
-                            gar.name, sub, fw, key=gkey,
-                            params=gar_params,
+                            sub = stack[sel]
+                            if def_w is not None:
+                                sub = (sub * def_w[sel][:, None]).astype(
+                                    sub.dtype
+                                )
+                            bundle = taps_lib.compute_flat(
+                                gar.name, sub, fw, key=gkey,
+                                params=gar_params,
+                            )
+                            return taps_lib.scatter(bundle, sel, num_workers)
+                        sub = stack
+                        if def_w is not None:
+                            sub = (sub * def_w[:, None]).astype(sub.dtype)
+                        return taps_lib.compute_flat(
+                            gar.name, sub, fw, key=gkey, params=gar_params,
                         )
-                        return taps_lib.scatter(bundle, sel, num_workers)
-                    sub = stack
-                    if def_w is not None:
-                        sub = (sub * def_w[:, None]).astype(sub.dtype)
-                    return taps_lib.compute_flat(
-                        gar.name, sub, fw, key=gkey, params=gar_params,
+
+                    tap = taps_lib.mean_bundles(
+                        jax.vmap(one_tap)(ps_ids, stacks)
                     )
 
-                tap = taps_lib.mean_bundles(
-                    jax.vmap(one_tap)(ps_ids, stacks)
-                )
-
         # --- model gather phase (ByzSGD/trainer.py:240-244) ----------------
-        flat_models = core.flatten_rows(new_params)  # (per_ps, d)
-        models = jax.lax.all_gather(flat_models, ps_axis, tiled=True)  # (n_ps, d)
+        with core.phase("model_exchange"):
+            flat_models = core.flatten_rows(new_params)  # (per_ps, d)
+            # (n_ps, d)
+            models = jax.lax.all_gather(flat_models, ps_axis, tiled=True)
         params0 = jax.tree.map(lambda l: l[0], new_params)
         # Model-plane selection feedback (DESIGN.md §17): the rule's
         # verdict over the SAME poisoned, weighted replica stack the
@@ -586,39 +603,40 @@ def make_trainer(
         # view, pmean'd so the carried state stays replicated.
         ps_bundle = None
         if defense is not None or ps_adaptive_cfg is not None:
-            poisoned_m = apply_model_attack_rows(
-                ps_attack, models, act_ps_mask, key=psatk_key,
-                **eff_ps_params,
-            )
-            if ps_def_w is not None:
-                poisoned_m = (poisoned_m * ps_def_w[:, None]).astype(
-                    poisoned_m.dtype
+            with core.phase("model_rule"):  # a tap recomputes the rule's view
+                poisoned_m = apply_model_attack_rows(
+                    ps_attack, models, act_ps_mask, key=psatk_key,
+                    **eff_ps_params,
                 )
-            if model_waiting:
-                def one_mtap(ps_id):
-                    # SAME (sel, key) derivation as the gather below.
-                    sel = core.subset_indices(
-                        jax.random.fold_in(msub_key, ps_id), num_ps,
-                        model_subset,
+                if ps_def_w is not None:
+                    poisoned_m = (poisoned_m * ps_def_w[:, None]).astype(
+                        poisoned_m.dtype
                     )
-                    mkey = jax.random.fold_in(mgar_key, ps_id)
-                    bundle = taps_lib.compute_flat(
-                        model_gar.name, poisoned_m[sel], fps, key=mkey,
+                if model_waiting:
+                    def one_mtap(ps_id):
+                        # SAME (sel, key) derivation as the gather below.
+                        sel = core.subset_indices(
+                            jax.random.fold_in(msub_key, ps_id), num_ps,
+                            model_subset,
+                        )
+                        mkey = jax.random.fold_in(mgar_key, ps_id)
+                        bundle = taps_lib.compute_flat(
+                            model_gar.name, poisoned_m[sel], fps, key=mkey,
+                            params=model_gar_params,
+                        )
+                        return taps_lib.scatter(bundle, sel, num_ps)
+
+                    ps_bundle = taps_lib.mean_bundles(
+                        jax.vmap(one_mtap)(ps_ids)
+                    )
+                    ps_bundle = jax.tree.map(
+                        lambda l: jax.lax.pmean(l, ps_axis), ps_bundle
+                    )
+                else:
+                    ps_bundle = taps_lib.compute_flat(
+                        model_gar.name, poisoned_m, fps, key=mgar_key,
                         params=model_gar_params,
                     )
-                    return taps_lib.scatter(bundle, sel, num_ps)
-
-                ps_bundle = taps_lib.mean_bundles(
-                    jax.vmap(one_mtap)(ps_ids)
-                )
-                ps_bundle = jax.tree.map(
-                    lambda l: jax.lax.pmean(l, ps_axis), ps_bundle
-                )
-            else:
-                ps_bundle = taps_lib.compute_flat(
-                    model_gar.name, poisoned_m, fps, key=mgar_key,
-                    params=model_gar_params,
-                )
         if model_waiting:
             # Reference-faithful wait-n-f on the model plane: each PS
             # aggregates only its own seeded fastest q_m peer models
@@ -630,31 +648,35 @@ def make_trainer(
             # PS slot via (q_m, q_m) sub-Gram selections, with
             # deterministic PS attacks (reverse/crash) folded into the
             # Gram remap instead of poisoning the rows.
-            sels = jax.vmap(
-                lambda i: core.subset_indices(
-                    jax.random.fold_in(msub_key, i), num_ps, model_subset
-                )
-            )(ps_ids)
-            mkeys = jax.vmap(
-                lambda i: jax.random.fold_in(mgar_key, i)
-            )(ps_ids)
+            with core.phase("model_rule"):
+                sels = jax.vmap(
+                    lambda i: core.subset_indices(
+                        jax.random.fold_in(msub_key, i), num_ps, model_subset
+                    )
+                )(ps_ids)
+                mkeys = jax.vmap(
+                    lambda i: jax.random.fold_in(mgar_key, i)
+                )(ps_ids)
             if model_gram_ok:
                 base_models = models
                 if model_fold_plan is None:
-                    base_models = apply_model_attack_rows(
+                    with core.phase("attack"):
+                        base_models = apply_model_attack_rows(
+                            ps_attack, models, act_ps_mask, key=psatk_key,
+                            **eff_ps_params,
+                        )
+                with core.phase("model_rule"):  # claims the fold's own "rule"
+                    aggr_models = fold.folded_tree_aggregate_multi(
+                        model_gar, model_fold_plan, base_models, f=fps,
+                        keys=mkeys, gar_params=model_gar_params,
+                        subset_sels=sels, row_weights=ps_def_w,
+                    )  # (per_ps, d)
+            else:
+                with core.phase("attack"):
+                    poisoned = apply_model_attack_rows(
                         ps_attack, models, act_ps_mask, key=psatk_key,
                         **eff_ps_params,
                     )
-                aggr_models = fold.folded_tree_aggregate_multi(
-                    model_gar, model_fold_plan, base_models, f=fps,
-                    keys=mkeys, gar_params=model_gar_params,
-                    subset_sels=sels, row_weights=ps_def_w,
-                )  # (per_ps, d)
-            else:
-                poisoned = apply_model_attack_rows(
-                    ps_attack, models, act_ps_mask, key=psatk_key,
-                    **eff_ps_params,
-                )
 
                 def one_ps(sel, mkey):
                     sub = poisoned[sel]
@@ -678,39 +700,45 @@ def make_trainer(
                         sub, f=fps, key=mkey, **model_gar_params
                     )
 
-                aggr_models = jax.vmap(one_ps)(sels, mkeys)  # (per_ps, d)
-            new_params = jax.tree.map(
-                lambda *ls: jnp.stack(ls),
-                *[
-                    core.unflatten_like(params0, aggr_models[k])
-                    for k in range(per_ps)
-                ],
-            )
+                with core.phase("model_rule"):
+                    aggr_models = jax.vmap(one_ps)(sels, mkeys)  # (per_ps, d)
+            with core.phase("model_rule"):
+                new_params = jax.tree.map(
+                    lambda *ls: jnp.stack(ls),
+                    *[
+                        core.unflatten_like(params0, aggr_models[k])
+                        for k in range(per_ps)
+                    ],
+                )
         else:
-            models = apply_model_attack_rows(
-                ps_attack, models, act_ps_mask, key=psatk_key,
-                **eff_ps_params,
-            )
-            if ps_def_w is not None:
-                models = (models * ps_def_w[:, None]).astype(models.dtype)
-            if granularity == "layer":
-                aggr_model = core.segmented_aggregate(
-                    lambda s, i: model_gar.unchecked(
-                        s, f=fps, key=jax.random.fold_in(mgar_key, i),
-                        **model_gar_params,
-                    ),
-                    models,
-                    core.leaf_segments(params0),
+            with core.phase("attack"):
+                models = apply_model_attack_rows(
+                    ps_attack, models, act_ps_mask, key=psatk_key,
+                    **eff_ps_params,
                 )
-            else:
-                aggr_model = model_gar.unchecked(
-                    models, f=fps, key=mgar_key, **model_gar_params
+            with core.phase("model_rule"):
+                if ps_def_w is not None:
+                    models = (models * ps_def_w[:, None]).astype(
+                        models.dtype
+                    )
+                if granularity == "layer":
+                    aggr_model = core.segmented_aggregate(
+                        lambda s, i: model_gar.unchecked(
+                            s, f=fps, key=jax.random.fold_in(mgar_key, i),
+                            **model_gar_params,
+                        ),
+                        models,
+                        core.leaf_segments(params0),
+                    )
+                else:
+                    aggr_model = model_gar.unchecked(
+                        models, f=fps, key=mgar_key, **model_gar_params
+                    )
+                written = core.unflatten_like(params0, aggr_model)
+                new_params = jax.tree.map(
+                    lambda l: jnp.broadcast_to(l[None], (per_ps,) + l.shape),
+                    written,
                 )
-            written = core.unflatten_like(params0, aggr_model)
-            new_params = jax.tree.map(
-                lambda l: jnp.broadcast_to(l[None], (per_ps,) + l.shape),
-                written,
-            )
 
         # losses: (per_ps, per_w) — honest-worker mean, then over the mesh.
         honest = (~byz_worker_mask).astype(losses.dtype)
@@ -744,23 +772,24 @@ def make_trainer(
         new_attack_state = state.attack_state
         ps_detected = None
         if ps_adaptive_cfg is not None:
-            act_f = act_ps_mask.astype(jnp.float32) * ps_bundle["observed"]
-            cnt = jnp.sum(act_f)
-            admitted = jnp.sum(
-                (ps_bundle["selected"] > 0).astype(jnp.float32) * act_f
-            )
-            ps_detected = admitted * 2.0 < cnt
-            upd_lo, upd_hi = adaptive_lib.update_bracket(
-                p_lo, p_hi, ps_detected,
-                mag_min=ps_adaptive_cfg.mag_min,
-                mag_max=ps_adaptive_cfg.mag_max,
-                regrow=ps_adaptive_cfg.regrow,
-            )
-            hold = cnt == 0.0
-            new_attack_state = {
-                "lo": jnp.where(hold, p_lo, upd_lo),
-                "hi": jnp.where(hold, p_hi, upd_hi),
-            }
+            with core.phase("attack"):
+                act_f = act_ps_mask.astype(jnp.float32) * ps_bundle["observed"]
+                cnt = jnp.sum(act_f)
+                admitted = jnp.sum(
+                    (ps_bundle["selected"] > 0).astype(jnp.float32) * act_f
+                )
+                ps_detected = admitted * 2.0 < cnt
+                upd_lo, upd_hi = adaptive_lib.update_bracket(
+                    p_lo, p_hi, ps_detected,
+                    mag_min=ps_adaptive_cfg.mag_min,
+                    mag_max=ps_adaptive_cfg.mag_max,
+                    regrow=ps_adaptive_cfg.regrow,
+                )
+                hold = cnt == 0.0
+                new_attack_state = {
+                    "lo": jnp.where(hold, p_lo, upd_lo),
+                    "hi": jnp.where(hold, p_hi, upd_hi),
+                }
 
         new_defense_state = state.defense_state
         if defense is not None:

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.flatten_util import ravel_pytree
 
 __all__ = [
+    "PHASES",
+    "phase",
     "TrainState",
     "make_worker_fns",
     "make_chunked_step",
@@ -36,6 +38,36 @@ __all__ = [
     "mean_model_state",
     "default_byz_mask",
 ]
+
+
+# The phases of one training step, as a device trace can name them: every
+# instruction the compiler makes from code under ``phase(name)`` carries
+# ``phase.<name>`` in its ``op_name`` metadata, fused or not. The metadata is
+# no part of the optimized program, so the scopes are always on.
+#   grads           per-slot forward and backward, the cast to gar_dtype
+#   exchange        every all_gather of gradients, losses, aggregates
+#   attack          row poisoning; a folded attack's fake row and plan
+#   rule            gathered tree -> aggregated tree (Gram, selection, sums,
+#                   coordinate kernels with upcast/pad/slice, audit taps)
+#   update          optimizer.update, apply_updates, the carried state
+#   model_exchange  LEARN gossip / ByzSGD gather of MODELS (all_gather)
+#   model_rule      their aggregation
+# Where scopes nest the OUTERMOST names the phase, so a topology can claim a
+# shared helper's work for its own plane (``model_rule`` around fold.py).
+PHASES = (
+    "grads", "exchange", "attack", "rule", "update",
+    "model_exchange", "model_rule",
+)
+
+
+def phase(name):
+    """``jax.named_scope("phase.<name>")`` for a name of ``PHASES``; usable
+    as a context manager and as a decorator."""
+    if name not in PHASES:
+        raise ValueError(
+            f"unknown step phase {name!r}; the vocabulary is {PHASES}"
+        )
+    return jax.named_scope("phase." + name)
 
 
 @flax.struct.dataclass
@@ -424,14 +456,15 @@ def per_slot_grads(grad_fn, params, ms, x, y, keys, fused_fn=None,
     the unroll 12% worse end-to-end (PERF.md).
     """
     n = x.shape[0]
-    if fused_fn is not None:
-        return fused_fn(params, ms, x, y, keys)
-    if n > UNROLL_MAX_SLOTS and not force_unroll:
-        return jax.vmap(grad_fn, in_axes=(None, None, 0, 0, 0))(
-            params, ms, x, y, keys
-        )
-    outs = [grad_fn(params, ms, x[k], y[k], keys[k]) for k in range(n)]
-    return jax.tree.map(lambda *ls: jnp.stack(ls), *outs)
+    with phase("grads"):
+        if fused_fn is not None:
+            return fused_fn(params, ms, x, y, keys)
+        if n > UNROLL_MAX_SLOTS and not force_unroll:
+            return jax.vmap(grad_fn, in_axes=(None, None, 0, 0, 0))(
+                params, ms, x, y, keys
+            )
+        outs = [grad_fn(params, ms, x[k], y[k], keys[k]) for k in range(n)]
+        return jax.tree.map(lambda *ls: jnp.stack(ls), *outs)
 
 
 def cast_leaves(tree, dtype):

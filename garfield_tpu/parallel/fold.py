@@ -54,6 +54,7 @@ import numpy as np
 
 from ..aggregators._common import tree_gram, tree_weighted_sum
 from ..attacks import plan_gradient_attack_fold, plan_model_attack_fold
+from . import core
 
 __all__ = [
     "plan_for",
@@ -109,6 +110,18 @@ def _sanitize_gram(gram_p, row_scale):
         return gram_p
     zmask = jnp.asarray(zero)
     return jnp.where(zmask[:, None] | zmask[None, :], 0.0, gram_p)
+
+
+def _extended(build_extra, stacked_tree):
+    """The stacked tree plus the plan's fake row, if it has one."""
+    if build_extra is None:
+        return stacked_tree
+    with core.phase("attack"):
+        extra = build_extra(stacked_tree)
+        return jax.tree.map(
+            lambda l, e: jnp.concatenate([l, e[None]], axis=0),
+            stacked_tree, extra,
+        )
 
 
 def folded_tree_aggregate(gar, plan, stacked_tree, *, f, key=None,
@@ -193,19 +206,38 @@ def folded_tree_aggregate(gar, plan, stacked_tree, *, f, key=None,
     # TREE from TrainState.gar_state; only the flat-iteration branch
     # consumes it (as the concatenated vector).
     center_tree = params.pop("center", None)
+    # The attack's share comes first: the shared fake row, appended to the
+    # tree where the rule consumes one. All that follows is the rule's.
+    tree_form = (
+        gar.gram_select is not None or gar.tree_aggregate_ext is not None
+    )
+    ext = extra = None
+    if tree_form:
+        ext = _extended(plan.build_extra, stacked_tree)
+    elif plan.build_extra is not None:
+        with core.phase("attack"):
+            extra = plan.build_extra(stacked_tree)
+    return _folded_rule(
+        gar, plan, leaves, treedef, ext, extra, center_tree, params,
+        f=f, key=key, subset_sel=subset_sel, row_weights=row_weights,
+        return_weights=return_weights,
+    )
+
+
+@core.phase("rule")
+def _folded_rule(gar, plan, leaves, treedef, ext, extra, center_tree, params,
+                 *, f, key, subset_sel, row_weights, return_weights):
+    """``folded_tree_aggregate`` after the attack's share: ``ext`` is the
+    extended tree (tree-form rules), ``extra`` the fake row alone (flat
+    forms), either None where the plan has no fake row."""
+    n = leaves[0].shape[0]
+    tree_form = ext is not None
 
     def sanitize_gram(gram_p):
         """See ``_sanitize_gram`` — closure over this plan's scales."""
         return _sanitize_gram(gram_p, plan.row_scale)
 
-    if gar.gram_select is not None or gar.tree_aggregate_ext is not None:
-        ext = stacked_tree
-        if plan.build_extra is not None:
-            extra = plan.build_extra(stacked_tree)
-            ext = jax.tree.map(
-                lambda l, e: jnp.concatenate([l, e[None]], axis=0),
-                stacked_tree, extra,
-            )
+    if tree_form:
         if gar.gram_select is None:
             # Coordinate-wise rules (median, tmean): per-leaf kernels with
             # the remap applied in-register — no poisoned stack, no
@@ -246,8 +278,7 @@ def folded_tree_aggregate(gar, plan, stacked_tree, *, f, key=None,
         from ..aggregators._common import concat_stack, unflatten_vec
 
         stack, shapes = concat_stack(leaves)
-        if plan.build_extra is not None:
-            extra = plan.build_extra(stacked_tree)
+        if extra is not None:
             a_flat = jnp.concatenate(
                 [l.reshape(-1) for l in jax.tree.leaves(extra)]
             )
@@ -275,8 +306,7 @@ def folded_tree_aggregate(gar, plan, stacked_tree, *, f, key=None,
     acc = jnp.promote_types(stack.dtype, jnp.float32)
     gram = jnp.matmul(stack, stack.T, preferred_element_type=acc)
     a_flat = None
-    if plan.build_extra is not None:
-        extra = plan.build_extra(stacked_tree)
+    if extra is not None:
         a_flat = jnp.concatenate(
             [l.reshape(-1) for l in jax.tree.leaves(extra)]
         )
@@ -365,60 +395,55 @@ def folded_tree_aggregate_multi(gar, plan, stacked_tree, *, f, keys=None,
     else:
         rmap, scale_np = plan.row_map, plan.row_scale
         build_extra, num_extra = plan.build_extra, plan.num_extra
-    ext = stacked_tree
-    if build_extra is not None:
-        extra = build_extra(stacked_tree)
-        ext = jax.tree.map(
-            lambda l, e: jnp.concatenate([l, e[None]], axis=0),
-            stacked_tree, extra,
+    ext = _extended(build_extra, stacked_tree)
+    with core.phase("rule"):
+        scale = jnp.asarray(scale_np)
+        if row_weights is not None:
+            # Staleness composition (DESIGN.md §15): per-row weights are row
+            # scales, so they multiply into the same algebra the attack plan
+            # uses — the remapped Gram below and every observer's weight row
+            # see the composed scale; nothing row-shaped materializes.
+            scale = scale * jnp.asarray(row_weights, scale.dtype)
+        gram = tree_gram(ext)  # (n+k, n+k), ONE build for all observers
+        gram_p = _sanitize_gram(
+            gram[rmap][:, rmap] * (scale[:, None] * scale[None, :]), scale_np
         )
-    scale = jnp.asarray(scale_np)
-    if row_weights is not None:
-        # Staleness composition (DESIGN.md §15): per-row weights are row
-        # scales, so they multiply into the same algebra the attack plan
-        # uses — the remapped Gram below and every observer's weight row
-        # see the composed scale; nothing row-shaped materializes.
-        scale = scale * jnp.asarray(row_weights, scale.dtype)
-    gram = tree_gram(ext)  # (n+k, n+k), ONE build for all observers
-    gram_p = _sanitize_gram(
-        gram[rmap][:, rmap] * (scale[:, None] * scale[None, :]), scale_np
-    )
 
-    def select_one(sel, key):
-        if sel is None:
-            w = gar.gram_select(gram_p, f=f, key=key, **params)
+        def select_one(sel, key):
+            if sel is None:
+                w = gar.gram_select(gram_p, f=f, key=key, **params)
+            else:
+                w_sub = gar.gram_select(
+                    gram_p[sel][:, sel], f=f, key=key, **params
+                )
+                w = jnp.zeros((n,), jnp.float32).at[sel].set(w_sub)
+            return w
+
+        if subset_sels is None:
+            if keys is None:
+                W = select_one(None, None)[None]
+            else:
+                W = jax.vmap(lambda k: select_one(None, k))(keys)
+        elif keys is None:
+            W = jax.vmap(lambda s: select_one(s, None))(subset_sels)
         else:
-            w_sub = gar.gram_select(
-                gram_p[sel][:, sel], f=f, key=key, **params
+            W = jax.vmap(select_one)(subset_sels, keys)
+        m = W.shape[0]
+        W = W.astype(jnp.float32) * scale[None, :]
+        W_ext = jnp.zeros((m, n + num_extra), jnp.float32).at[:, rmap].add(W)
+        used = jnp.any(W_ext != 0, axis=0)
+
+        def one_leaf(leaf):
+            rows = leaf.shape[0]
+            flat = leaf.reshape(rows, -1)
+            out = jnp.matmul(
+                W_ext.astype(leaf.dtype), jnp.where(used[:, None], flat, 0)
             )
-            w = jnp.zeros((n,), jnp.float32).at[sel].set(w_sub)
-        return w
+            return out.reshape((m,) + leaf.shape[1:])
 
-    if subset_sels is None:
-        if keys is None:
-            W = select_one(None, None)[None]
-        else:
-            W = jax.vmap(lambda k: select_one(None, k))(keys)
-    elif keys is None:
-        W = jax.vmap(lambda s: select_one(s, None))(subset_sels)
-    else:
-        W = jax.vmap(select_one)(subset_sels, keys)
-    m = W.shape[0]
-    W = W.astype(jnp.float32) * scale[None, :]
-    W_ext = jnp.zeros((m, n + num_extra), jnp.float32).at[:, rmap].add(W)
-    used = jnp.any(W_ext != 0, axis=0)
-
-    def one_leaf(leaf):
-        rows = leaf.shape[0]
-        flat = leaf.reshape(rows, -1)
-        out = jnp.matmul(
-            W_ext.astype(leaf.dtype), jnp.where(used[:, None], flat, 0)
-        )
-        return out.reshape((m,) + leaf.shape[1:])
-
-    out_tree = jax.tree.map(one_leaf, ext)
-    if subset_sels is None and keys is None:
-        # Full participation, no per-observer keys: ONE selection — return
-        # it without the leading axis (the caller broadcasts).
-        return jax.tree.map(lambda l: l[0], out_tree)
-    return out_tree
+        out_tree = jax.tree.map(one_leaf, ext)
+        if subset_sels is None and keys is None:
+            # Full participation, no per-observer keys: ONE selection —
+            # return it without the leading axis (the caller broadcasts).
+            return jax.tree.map(lambda l: l[0], out_tree)
+        return out_tree

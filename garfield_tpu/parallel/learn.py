@@ -567,12 +567,14 @@ def make_trainer(
             composed into the Gram row-scale algebra (the tree route is
             gated to gram_select rules when weights are active)."""
             if plan is None and attack_name not in (None, "none"):
-                stacked_tree = apply_gradient_attack_tree(
-                    attack_name, stacked_tree, byz_mask, key=akey,
-                    **attack_kw,
-                )
+                with core.phase("attack"):
+                    stacked_tree = apply_gradient_attack_tree(
+                        attack_name, stacked_tree, byz_mask, key=akey,
+                        **attack_kw,
+                    )
             if waiting:
-                sels, gkeys = node_subset_keys(key)
+                with core.phase("rule"):
+                    sels, gkeys = node_subset_keys(key)
                 return fold.folded_tree_aggregate_multi(
                     gar, plan, stacked_tree, f=f, keys=gkeys,
                     gar_params=gar_params, subset_sels=sels,
@@ -589,12 +591,13 @@ def make_trainer(
                     gar, plan, stacked_tree, f=f,
                     gar_params=gar_params, row_weights=row_weights,
                 )
-                return jax.tree.map(
-                    lambda l: jnp.broadcast_to(
-                        l[None], (per_n,) + l.shape
-                    ),
-                    one,
-                )
+                with core.phase("rule"):
+                    return jax.tree.map(
+                        lambda l: jnp.broadcast_to(
+                            l[None], (per_n,) + l.shape
+                        ),
+                        one,
+                    )
             center_kw = {}
             if center_tree is not None:
                 # Full participation: every node's carried center is equal
@@ -608,17 +611,23 @@ def make_trainer(
                     gar_params={**gar_params, **center_kw},
                 )
             else:
-                one = gar.tree_aggregate(
-                    stacked_tree, f=f, key=key, **gar_params, **center_kw
+                with core.phase("rule"):
+                    one = gar.tree_aggregate(
+                        stacked_tree, f=f, key=key, **gar_params,
+                        **center_kw
+                    )
+            with core.phase("rule"):
+                return jax.tree.map(
+                    lambda l: jnp.broadcast_to(l[None], (per_n,) + l.shape),
+                    one,
                 )
-            return jax.tree.map(
-                lambda l: jnp.broadcast_to(l[None], (per_n,) + l.shape), one
-            )
 
         def honest_spread(aggr_rows):
             """Max pairwise L-inf distance between honest nodes' aggregates:
             the disagreement the agreement rounds must shrink."""
-            rows = jax.lax.all_gather(aggr_rows, axis, tiled=True)  # (n, d)
+            with core.phase("exchange"):
+                # (n, d)
+                rows = jax.lax.all_gather(aggr_rows, axis, tiled=True)
             byz = byz_mask[:, None]
             hi = jnp.max(jnp.where(byz, -jnp.inf, rows), axis=0)
             lo = jnp.min(jnp.where(byz, jnp.inf, rows), axis=0)
@@ -638,37 +647,39 @@ def make_trainer(
         # core.per_slot_grads docstring). Above the cap (or when the run
         # length cannot amortize the unroll's compile premium) the per-node
         # gradients vmap with params mapped over the node axis.
-        if unroll_grads:
-            grads, losses_list, ms_list = [], [], []
-            for k in range(per_n):
-                p_k = jax.tree.map(lambda l: l[k], state.params)
-                rng_k = jax.random.fold_in(drop_base, node_ids[k])
-                g, (loss, ms_out) = grad_fn(
-                    p_k, state.model_state, x_local[k], y_local[k], rng_k
-                )
-                grads.append(g)
-                losses_list.append(loss)
-                ms_list.append(ms_out)
-            grads_local = jax.tree.map(lambda *ls: jnp.stack(ls), *grads)
-            losses = jnp.stack(losses_list)
-            ms_stack = jax.tree.map(lambda *ls: jnp.stack(ls), *ms_list)
-        else:
-            rngs = jax.vmap(
-                lambda i: jax.random.fold_in(drop_base, i)
-            )(node_ids)
-            grads_local, (losses, ms_stack) = jax.vmap(
-                grad_fn, in_axes=(0, None, 0, 0, 0)
-            )(state.params, state.model_state, x_local, y_local, rngs)
-        grads_local = core.cast_leaves(grads_local, gar_dtype)
+        with core.phase("grads"):
+            if unroll_grads:
+                grads, losses_list, ms_list = [], [], []
+                for k in range(per_n):
+                    p_k = jax.tree.map(lambda l: l[k], state.params)
+                    rng_k = jax.random.fold_in(drop_base, node_ids[k])
+                    g, (loss, ms_out) = grad_fn(
+                        p_k, state.model_state, x_local[k], y_local[k], rng_k
+                    )
+                    grads.append(g)
+                    losses_list.append(loss)
+                    ms_list.append(ms_out)
+                grads_local = jax.tree.map(lambda *ls: jnp.stack(ls), *grads)
+                losses = jnp.stack(losses_list)
+                ms_stack = jax.tree.map(lambda *ls: jnp.stack(ls), *ms_list)
+            else:
+                rngs = jax.vmap(
+                    lambda i: jax.random.fold_in(drop_base, i)
+                )(node_ids)
+                grads_local, (losses, ms_stack) = jax.vmap(
+                    grad_fn, in_axes=(0, None, 0, 0, 0)
+                )(state.params, state.model_state, x_local, y_local, rngs)
+            grads_local = core.cast_leaves(grads_local, gar_dtype)
 
         # Per-node momentum (see make_trainer docstring): each node
         # publishes its EMA; the honest update is stored (sharded with the
         # node state), Byzantine rows are re-poisoned after the gather.
         new_mom = state.worker_mom
         if worker_momentum is not None:
-            grads_local = core.worker_mom_update(
-                worker_momentum, state.worker_mom, grads_local
-            )
+            with core.phase("grads"):
+                grads_local = core.worker_mom_update(
+                    worker_momentum, state.worker_mom, grads_local
+                )
             new_mom = grads_local
         new_ms = core.mean_model_state(ms_stack, axis)
 
@@ -678,9 +689,11 @@ def make_trainer(
         # aggregate; at step 0 the lax.cond takes the robust-median-init
         # branch instead — the ONLY coordinate-median pass in the whole
         # step program, executed exactly once per run.
-        gathered = jax.tree.map(
-            lambda l: jax.lax.all_gather(l, axis, tiled=True), grads_local
-        )
+        with core.phase("exchange"):
+            gathered = jax.tree.map(
+                lambda l: jax.lax.all_gather(l, axis, tiled=True),
+                grads_local,
+            )
 
         stale_w2 = row_w_for(0)
 
@@ -691,12 +704,17 @@ def make_trainer(
                     attack_params, center_tree=centers_tree,
                     row_weights=stale_w2,
                 )
-            stack0 = core.flatten_rows(gathered)  # (n, d)
-            stack0 = apply_gradient_attack(
-                attack, stack0, byz_mask, key=atk_key, **attack_params
-            )
-            stack0 = weight_rows(stack0, stale_w2)
-            return local_aggregates(stack0, sub_key, centers=centers_rows)
+            with core.phase("rule"):
+                stack0 = core.flatten_rows(gathered)  # (n, d)
+            with core.phase("attack"):
+                stack0 = apply_gradient_attack(
+                    attack, stack0, byz_mask, key=atk_key, **attack_params
+                )
+            with core.phase("rule"):
+                stack0 = weight_rows(stack0, stale_w2)
+                return local_aggregates(
+                    stack0, sub_key, centers=centers_rows
+                )
 
         if gar.stateful_center:
             carried = state.gar_state  # (per_n, ...) local shard
@@ -723,38 +741,39 @@ def make_trainer(
             # carried centers differ across observers (taps.py caveats).
             # With the defense on, this bundle is ALSO the feedback that
             # updates the carried exclusion EMA below.
-            stack0p = apply_gradient_attack(
-                attack, core.flatten_rows(gathered), byz_mask, key=atk_key,
-                **attack_params,
-            )
-            # The tap audits the rows the rule consumed — staleness- and
-            # suspicion-weighted included (the aggregathor convention).
-            stack0p = weight_rows(stack0p, stale_w2)
-            if waiting:
-                def one_tap(nid):
-                    # SAME (sel, key) derivation as node_aggregate /
-                    # node_subset_keys, so the tap audits exactly the
-                    # quorum node ``nid`` aggregated.
-                    sel_key, gkey = jax.random.split(
-                        jax.random.fold_in(sub_key, nid)
-                    )
-                    sel = core.subset_indices(sel_key, num_nodes, subset)
-                    bundle = taps_lib.compute_flat(
-                        gar.name, stack0p[sel], f, key=gkey,
-                        params=gar_params,
-                    )
-                    return taps_lib.scatter(bundle, sel, num_nodes)
+            with core.phase("rule"):  # a tap recomputes the rule's view
+                stack0p = apply_gradient_attack(
+                    attack, core.flatten_rows(gathered), byz_mask, key=atk_key,
+                    **attack_params,
+                )
+                # The tap audits the rows the rule consumed — staleness- and
+                # suspicion-weighted included (the aggregathor convention).
+                stack0p = weight_rows(stack0p, stale_w2)
+                if waiting:
+                    def one_tap(nid):
+                        # SAME (sel, key) derivation as node_aggregate /
+                        # node_subset_keys, so the tap audits exactly the
+                        # quorum node ``nid`` aggregated.
+                        sel_key, gkey = jax.random.split(
+                            jax.random.fold_in(sub_key, nid)
+                        )
+                        sel = core.subset_indices(sel_key, num_nodes, subset)
+                        bundle = taps_lib.compute_flat(
+                            gar.name, stack0p[sel], f, key=gkey,
+                            params=gar_params,
+                        )
+                        return taps_lib.scatter(bundle, sel, num_nodes)
 
-                local_mean = taps_lib.mean_bundles(
-                    jax.vmap(one_tap)(node_ids)
-                )
-                grad_bundle = jax.tree.map(
-                    lambda l: jax.lax.pmean(l, axis), local_mean
-                )
-            else:
-                grad_bundle = taps_lib.compute_flat(
-                    gar.name, stack0p, f, key=sub_key, params=gar_params,
-                )
+                    local_mean = taps_lib.mean_bundles(
+                        jax.vmap(one_tap)(node_ids)
+                    )
+                    grad_bundle = jax.tree.map(
+                        lambda l: jax.lax.pmean(l, axis), local_mean
+                    )
+                else:
+                    grad_bundle = taps_lib.compute_flat(
+                        gar.name, stack0p, f, key=sub_key, params=gar_params,
+                    )
             if telemetry:
                 metrics_extra["tap"] = grad_bundle
         if track_spread:
@@ -776,10 +795,13 @@ def make_trainer(
 
             if grad_tree_ok:
                 def round_body(r, aggr):
-                    served = jax.tree.map(
-                        lambda l: jax.lax.all_gather(l, axis, tiled=True),
-                        aggr,
-                    )  # (n, ...) leaves: every node's own aggregate
+                    with core.phase("exchange"):
+                        served = jax.tree.map(
+                            lambda l: jax.lax.all_gather(
+                                l, axis, tiled=True
+                            ),
+                            aggr,
+                        )  # (n, ...) leaves: every node's own aggregate
                     akey, skey = jax.random.split(
                         jax.random.fold_in(gossip_key, r)
                     )
@@ -788,24 +810,30 @@ def make_trainer(
                         center_tree=aggr if gar.stateful_center else None,
                         row_weights=row_w_for(1 + r),
                     )
-                    return jax.tree.map(
-                        lambda a, b: jnp.where(r < rounds, a, b), new, aggr
-                    )
+                    with core.phase("rule"):
+                        return jax.tree.map(
+                            lambda a, b: jnp.where(r < rounds, a, b),
+                            new, aggr,
+                        )
             else:
                 def round_body(r, aggr):
-                    served = jax.lax.all_gather(aggr, axis, tiled=True)
+                    with core.phase("exchange"):
+                        served = jax.lax.all_gather(aggr, axis, tiled=True)
                     akey, skey = jax.random.split(
                         jax.random.fold_in(gossip_key, r)
                     )
-                    served = apply_gradient_attack(
-                        attack, served, byz_mask, key=akey, **attack_params
-                    )
-                    served = weight_rows(served, row_w_for(1 + r))
-                    new = local_aggregates(
-                        served, skey,
-                        centers=aggr if gar.stateful_center else None,
-                    )
-                    return jnp.where(r < rounds, new, aggr)
+                    with core.phase("attack"):
+                        served = apply_gradient_attack(
+                            attack, served, byz_mask, key=akey,
+                            **attack_params
+                        )
+                    with core.phase("rule"):
+                        served = weight_rows(served, row_w_for(1 + r))
+                        new = local_aggregates(
+                            served, skey,
+                            centers=aggr if gar.stateful_center else None,
+                        )
+                        return jnp.where(r < rounds, new, aggr)
 
             aggr_local = jax.lax.fori_loop(
                 0, max_rounds, round_body, aggr_local
@@ -817,30 +845,34 @@ def make_trainer(
             )
 
         # Phase 4: per-node optimizer step on that node's own aggregate.
-        new_params_list, new_opt_list, aggr_trees = [], [], []
-        for k in range(per_n):
-            p_k = jax.tree.map(lambda l: l[k], state.params)
-            o_k = jax.tree.map(lambda l: l[k], state.opt_state)
-            if grad_tree_ok:
-                aggr_tree = jax.tree.map(lambda l: l[k], aggr_local)
-            else:
-                aggr_tree = core.unflatten_like(p_k, aggr_local[k])
-            aggr_trees.append(aggr_tree)
-            aggr_tree = core.cast_like(aggr_tree, p_k)  # no-op at f32
-            updates, o_k = optimizer.update(aggr_tree, o_k, p_k)
-            new_params_list.append(optax.apply_updates(p_k, updates))
-            new_opt_list.append(o_k)
-        new_params = jax.tree.map(lambda *ls: jnp.stack(ls), *new_params_list)
-        new_opt = jax.tree.map(lambda *ls: jnp.stack(ls), *new_opt_list)
-
-        new_gar_state = state.gar_state
-        if gar.stateful_center:
-            # Next step's per-node v_0 = this step's final aggregate (f32 —
-            # the carried center should not round through the bf16 pipeline).
-            new_gar_state = jax.tree.map(
-                lambda *ls: jnp.stack([l.astype(jnp.float32) for l in ls]),
-                *aggr_trees,
+        with core.phase("update"):
+            new_params_list, new_opt_list, aggr_trees = [], [], []
+            for k in range(per_n):
+                p_k = jax.tree.map(lambda l: l[k], state.params)
+                o_k = jax.tree.map(lambda l: l[k], state.opt_state)
+                if grad_tree_ok:
+                    aggr_tree = jax.tree.map(lambda l: l[k], aggr_local)
+                else:
+                    aggr_tree = core.unflatten_like(p_k, aggr_local[k])
+                aggr_trees.append(aggr_tree)
+                aggr_tree = core.cast_like(aggr_tree, p_k)  # no-op at f32
+                updates, o_k = optimizer.update(aggr_tree, o_k, p_k)
+                new_params_list.append(optax.apply_updates(p_k, updates))
+                new_opt_list.append(o_k)
+            new_params = jax.tree.map(
+                lambda *ls: jnp.stack(ls), *new_params_list
             )
+            new_opt = jax.tree.map(lambda *ls: jnp.stack(ls), *new_opt_list)
+
+            new_gar_state = state.gar_state
+            if gar.stateful_center:
+                # Next step's per-node v_0 = this step's final aggregate
+                # (f32 — the carried center should not round through the
+                # bf16 pipeline).
+                new_gar_state = jax.tree.map(
+                    lambda *ls: jnp.stack([l.astype(jnp.float32) for l in ls]),
+                    *aggr_trees,
+                )
 
         # Phase 5: model gossip (LEARN/trainer.py:255-257, get_models(n-f) —
         # each node GAR-aggregates its own subset of the gossiped models).
@@ -851,101 +883,115 @@ def make_trainer(
         if model_gossip:
             stale_wg = row_w_for(0x5009)
             if gossip_tree_ok:
-                models_tree = jax.tree.map(
-                    lambda l: jax.lax.all_gather(l, axis, tiled=True),
-                    new_params,
-                )
-                new_params = tree_exchange(
-                    models_tree, model_fold_plan, matk_key, msub_key,
-                    None, {},
-                    center_tree=new_params if gar.stateful_center else None,
-                    row_weights=stale_wg,
-                )
+                with core.phase("model_exchange"):
+                    models_tree = jax.tree.map(
+                        lambda l: jax.lax.all_gather(l, axis, tiled=True),
+                        new_params,
+                    )
+                # The outermost scope names the phase: the exchange's own
+                # "rule" inside is the model plane's here.
+                with core.phase("model_rule"):
+                    new_params = tree_exchange(
+                        models_tree, model_fold_plan, matk_key, msub_key,
+                        None, {},
+                        center_tree=(
+                            new_params if gar.stateful_center else None
+                        ),
+                        row_weights=stale_wg,
+                    )
             else:
-                flat_models = core.flatten_rows(new_params)  # (per_n, d)
-                models = jax.lax.all_gather(flat_models, axis, tiled=True)
-                models = apply_model_attack_rows(
-                    model_attack, models, act_mask_m, key=matk_key,
-                    **eff_m_params,
-                )
+                with core.phase("model_exchange"):
+                    flat_models = core.flatten_rows(new_params)  # (per_n, d)
+                    models = jax.lax.all_gather(
+                        flat_models, axis, tiled=True
+                    )
+                with core.phase("attack"):
+                    models = apply_model_attack_rows(
+                        model_attack, models, act_mask_m, key=matk_key,
+                        **eff_m_params,
+                    )
                 # Gossip-plane staleness: a stale model's row is
                 # discounted like a stale gradient's — the robust rule
                 # then treats the down-scaled row as the outlier it is,
                 # and the fresh honest majority keeps its influence
                 # (DESIGN.md §15; the same composition as the PS plane;
                 # the defense's suspicion weight rides the same multiply).
-                models = weight_rows(models, stale_wg)
+                with core.phase("model_rule"):
+                    models = weight_rows(models, stale_wg)
                 if model_adaptive_cfg is not None:
                     # Gossip-plane selection feedback (DESIGN.md §17):
                     # the rule's verdict over the SAME poisoned, weighted
                     # stack the gossip aggregates — majority-excluded
                     # among the observed active nodes means detected; a
                     # round that observed none holds the bracket.
-                    if waiting:
-                        def one_mtap(nid):
-                            # SAME (sel, key) derivation as
-                            # node_aggregate over msub_key.
-                            sel_key, gkey = jax.random.split(
-                                jax.random.fold_in(msub_key, nid)
+                    with core.phase("model_rule"):
+                        if waiting:
+                            def one_mtap(nid):
+                                # SAME (sel, key) derivation as
+                                # node_aggregate over msub_key.
+                                sel_key, gkey = jax.random.split(
+                                    jax.random.fold_in(msub_key, nid)
+                                )
+                                sel = core.subset_indices(
+                                    sel_key, num_nodes, subset
+                                )
+                                bundle = taps_lib.compute_flat(
+                                    gar.name, models[sel], f, key=gkey,
+                                    params=gar_params,
+                                )
+                                return taps_lib.scatter(bundle, sel, num_nodes)
+
+                            gb = taps_lib.mean_bundles(
+                                jax.vmap(one_mtap)(node_ids)
                             )
-                            sel = core.subset_indices(
-                                sel_key, num_nodes, subset
+                            gossip_bundle = jax.tree.map(
+                                lambda l: jax.lax.pmean(l, axis), gb
                             )
-                            bundle = taps_lib.compute_flat(
-                                gar.name, models[sel], f, key=gkey,
+                        else:
+                            gossip_bundle = taps_lib.compute_flat(
+                                gar.name, models, f, key=msub_key,
                                 params=gar_params,
                             )
-                            return taps_lib.scatter(bundle, sel, num_nodes)
-
-                        gb = taps_lib.mean_bundles(
-                            jax.vmap(one_mtap)(node_ids)
+                    with core.phase("attack"):
+                        act_f = act_mask_m.astype(jnp.float32) * gossip_bundle[
+                            "observed"
+                        ]
+                        cnt = jnp.sum(act_f)
+                        admitted = jnp.sum(
+                            (gossip_bundle["selected"] > 0).astype(jnp.float32)
+                            * act_f
                         )
-                        gossip_bundle = jax.tree.map(
-                            lambda l: jax.lax.pmean(l, axis), gb
+                        m_detected = admitted * 2.0 < cnt
+                        upd_lo, upd_hi = adaptive_lib.update_bracket(
+                            m_lo, m_hi, m_detected,
+                            mag_min=model_adaptive_cfg.mag_min,
+                            mag_max=model_adaptive_cfg.mag_max,
+                            regrow=model_adaptive_cfg.regrow,
                         )
-                    else:
-                        gossip_bundle = taps_lib.compute_flat(
-                            gar.name, models, f, key=msub_key,
-                            params=gar_params,
+                        hold = cnt == 0.0
+                        new_attack_state = {
+                            "lo": jnp.where(hold, m_lo, upd_lo),
+                            "hi": jnp.where(hold, m_hi, upd_hi),
+                        }
+                        metrics_extra["model_attack_mag"] = jnp.asarray(
+                            m_mag, jnp.float32
                         )
-                    act_f = act_mask_m.astype(jnp.float32) * gossip_bundle[
-                        "observed"
-                    ]
-                    cnt = jnp.sum(act_f)
-                    admitted = jnp.sum(
-                        (gossip_bundle["selected"] > 0).astype(jnp.float32)
-                        * act_f
+                        metrics_extra["model_attack_detected"] = (
+                            m_detected.astype(jnp.float32)
+                        )
+                with core.phase("model_rule"):
+                    aggr_models = local_aggregates(
+                        models, msub_key,
+                        centers=flat_models if gar.stateful_center else None,
+                    )  # (per_n, d)
+                    template = jax.tree.map(lambda l: l[0], new_params)
+                    new_params = jax.tree.map(
+                        lambda *ls: jnp.stack(ls),
+                        *[
+                            core.unflatten_like(template, aggr_models[k])
+                            for k in range(per_n)
+                        ],
                     )
-                    m_detected = admitted * 2.0 < cnt
-                    upd_lo, upd_hi = adaptive_lib.update_bracket(
-                        m_lo, m_hi, m_detected,
-                        mag_min=model_adaptive_cfg.mag_min,
-                        mag_max=model_adaptive_cfg.mag_max,
-                        regrow=model_adaptive_cfg.regrow,
-                    )
-                    hold = cnt == 0.0
-                    new_attack_state = {
-                        "lo": jnp.where(hold, m_lo, upd_lo),
-                        "hi": jnp.where(hold, m_hi, upd_hi),
-                    }
-                    metrics_extra["model_attack_mag"] = jnp.asarray(
-                        m_mag, jnp.float32
-                    )
-                    metrics_extra["model_attack_detected"] = (
-                        m_detected.astype(jnp.float32)
-                    )
-                aggr_models = local_aggregates(
-                    models, msub_key,
-                    centers=flat_models if gar.stateful_center else None,
-                )  # (per_n, d)
-                template = jax.tree.map(lambda l: l[0], new_params)
-                new_params = jax.tree.map(
-                    lambda *ls: jnp.stack(ls),
-                    *[
-                        core.unflatten_like(template, aggr_models[k])
-                        for k in range(per_n)
-                    ],
-                )
 
         new_defense_state = state.defense_state
         if defense is not None:
@@ -968,9 +1014,10 @@ def make_trainer(
         # Per-node losses for observers (the reference demo renders per-node
         # progress, LEARN/demo.py:401-441 + templates/index.html); a tiny
         # replicated (n,) vector, node-id ordered.
-        metrics_extra["node_losses"] = jax.lax.all_gather(
-            losses, axis, tiled=True
-        )
+        with core.phase("exchange"):
+            metrics_extra["node_losses"] = jax.lax.all_gather(
+                losses, axis, tiled=True
+            )
 
         return (
             state.replace(

@@ -34,6 +34,14 @@ need the JSONL sink) or ``GARFIELD_TRACE=1``. Consume with
 ``python -m garfield_tpu.telemetry.report`` (cross-process merge,
 causal timeline, critical-path attribution — see report.py).
 
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name
+and tags: in any profiler trace taken of the process (``--profile_dir``,
+which turns the spans on by itself, or a trace a cluster role takes) the
+spans lie on ``/host:CPU`` on the device trace's own clock, so an idle gap
+on the device can be read against ``dispatch``, ``eval``, ``checkpoint``,
+``collect``, ``decode``, ... The device side of the same trace is named by
+the step's phase scopes (``parallel/core.py``: ``phase.grads`` ...).
+
 Phase vocabulary (kept small and stable so the report can reason about
 it; producers may add more):
 
@@ -67,7 +75,7 @@ __all__ = ["span", "emit", "enable", "disable", "enabled", "requested",
 # One mutable cell instead of rebindable module globals: ``span`` reads
 # it on every call (the disabled fast path), and a cell read is as cheap
 # as a global read while keeping enable/disable race-free under threads.
-_STATE = {"enabled": False, "who": None}
+_STATE = {"enabled": False, "who": None, "annotate": None}
 
 # Small per-thread track ids for the report's Chrome-trace lanes: the
 # main loop gets 0, waiter/watcher threads get 1, 2, ... in first-use
@@ -98,7 +106,10 @@ def enable(who=None):
     """Turn span recording on; ``who`` tags every span with the role
     (e.g. ``cluster-ps``, ``cluster-worker-2``) so the report can merge
     per-role streams without guessing from filenames."""
+    import jax.profiler
+
     _STATE["who"] = who
+    _STATE["annotate"] = jax.profiler.TraceAnnotation
     _STATE["enabled"] = True
 
 
@@ -146,7 +157,7 @@ class Span:
     outermost spans for attribution and all of them for the timeline.
     """
 
-    __slots__ = ("phase", "tags", "_t_wall", "_t0")
+    __slots__ = ("phase", "tags", "_t_wall", "_t0", "_annotation")
 
     def __init__(self, phase, tags):
         self.phase = phase
@@ -156,15 +167,21 @@ class Span:
         """Attach tags discovered mid-span (arrived counts, byte
         totals); later values win."""
         self.tags.update(tags)
+        self._annotation.set_metadata(**tags)
         return self
 
     def __enter__(self):
+        # The same span on the profiler's clock (free while no profiler
+        # session runs): host activity beside the device's operations.
+        self._annotation = _STATE["annotate"](self.phase, **self.tags)
+        self._annotation.__enter__()
         self._t_wall = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
         tags = self.tags
         if exc_type is not None:
             tags = dict(tags, error=exc_type.__name__)

@@ -146,14 +146,32 @@ class StepTimer:
         }
 
 
-@contextlib.contextmanager
-def trace(log_dir=None):
-    """``jax.profiler`` trace scope; no-op when ``log_dir`` is None."""
-    if log_dir is None:
-        yield
-        return
-    with jax.profiler.trace(str(log_dir)):
-        yield
+class StepsTrace:
+    """``--profile_dir``: one ``jax.profiler`` trace of ``steps`` whole
+    training steps, from step ``first`` on. Dispatch is asynchronous, so
+    the trace starts once what was dispatched before has run, and stops
+    once the last traced step has: ``before(i, pending)`` ahead of step
+    i's dispatch, ``after(end, pending)`` behind each dispatch (``end`` the
+    next step's index; None at the end of the run). ``pending`` is what to
+    wait for. Nothing happens when ``log_dir`` is None."""
+
+    STEPS = 8
+
+    def __init__(self, log_dir, first, steps=STEPS):
+        self.log_dir, self.first, self.last = log_dir, first, first + steps
+        self.tracing = False
+
+    def before(self, i, pending):
+        if self.log_dir is not None and i == self.first:
+            jax.block_until_ready(pending)
+            jax.profiler.start_trace(str(self.log_dir))
+            self.tracing = True
+
+    def after(self, end, pending):
+        if self.tracing and (end is None or end >= self.last):
+            jax.block_until_ready(pending)
+            jax.profiler.stop_trace()
+            self.tracing = False
 
 
 def collective_bytes(topology, *, num_workers, d, num_ps=1, rounds=1,
