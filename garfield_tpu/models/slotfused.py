@@ -75,6 +75,7 @@ folding n workers onto one chip has no reference counterpart.
 import jax
 import jax.numpy as jnp
 
+from ..utils import tools
 from . import slotlayers as sl
 from .slotlayers import SlotCtx, slot_conv  # re-export (back-compat)
 
@@ -168,11 +169,13 @@ def _cifarnet_twin(module):
         h = sl.max_pool(sl.relu(sl.conv(ctx, h, p_st["Conv_1"], 1, 0)), 2)
 
         def dense(h, name, relu=True):
-            y = sl.dense(ctx, h.reshape(ctx.slots * ctx.nb, -1), p_st[name])
+            y = sl.dense(ctx, h, p_st[name])
             return sl.relu(y) if relu else y
 
-        h = dense(h, "Dense_0")
-        h = dense(h, "Dense_1")
+        # A dense layer's (slots, b, O) result goes back to the flat
+        # batch, in the context's order, for the next one.
+        h = ctx.flat(dense(h, "Dense_0"))
+        h = ctx.flat(dense(h, "Dense_1"))
         return dense(h, "Dense_2", relu=False), {}
 
     return forward
@@ -197,7 +200,6 @@ def _vgg_twin(module):
             else:
                 h = _cbr(ctx, h, p_st, stats, new, ci)
                 ci += 1
-        h = h.reshape(h.shape[0], -1)
         return sl.dense(ctx, h, p_st["Dense_0"]), new
 
     return forward
@@ -411,7 +413,7 @@ def _gpt_twin(module):
             # against the SAME stacked table — autodiff accumulates its
             # cotangent into the embedding's per-slot gradient alongside
             # the lookup's scatter-add, exactly like the unrolled path.
-            h3 = h.reshape(ctx.slots, ctx.nb, -1).astype(ctx.dtype)
+            h3 = ctx.slot_view(h).astype(ctx.dtype)
             emb = p_st["Embed_0"]["embedding"].astype(ctx.dtype)
             return jnp.einsum("sbf,svf->sbv", h3, emb), {}
         return sl.dense(ctx, h, p_st["Dense_0"]), {}
@@ -476,8 +478,15 @@ def build_slot_grad_fn(module, loss_fn):
         slots, b = x.shape[0], x.shape[1]
         # Per-trace context: slot geometry + the slot matrix / segment ids
         # built ONCE and shared by every BN layer of the twin.
+        # It also owns the order of the flat batch (slot-major or
+        # slot-minor, ``slotlayers.flat_batch_order``): logged once per
+        # trace, and invisible downstream — grads, losses, logits and
+        # batch_stats leave slot-leading either way.
         ctx = SlotCtx(slots, b, dtype)
-        x_flat = x.reshape((slots * b,) + x.shape[2:])
+        tools.info(
+            f"[slotfused] flat batch order: {ctx.order} ({ctx.order_why})"
+        )
+        x_flat = ctx.flat(x)
         stats = model_state.get("batch_stats", {})
         p_st = jax.tree.map(
             lambda p: jnp.broadcast_to(p[None], (slots,) + p.shape), params

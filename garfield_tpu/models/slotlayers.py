@@ -21,8 +21,8 @@ the ``SLOTFUSED_MODELS`` registry live in ``slotfused.py``):
     resolution — only the affine parameters are worker-resolved.
   - ``dense``           — slot-batched matmul head ('sbf,sfo->sbo').
   - ``seq_dense``       — the sequence-layout sibling: (slots*b, T, F)
-    through a per-slot kernel via the same 'sbf,sfo->sbo' einsum with T
-    folded into the batch rows.
+    through a per-slot kernel via the same slot-batched einsum with T
+    beside the batch rows ('sbtf,sfo->sbto').
   - ``attn_core``       — the multi-head attention core (QK^T -> masked
     softmax -> PV) on per-example arithmetic, SHARED VERBATIM by the
     flax transformer modules and the slot twins (models/transformer.py
@@ -44,9 +44,25 @@ the ``SLOTFUSED_MODELS`` registry live in ``slotfused.py``):
 
 Every primitive takes a ``SlotCtx``: the per-trace context holding the
 slot geometry plus the PRECOMPUTED slot-membership machinery — the
-``(slots, slots*nb)`` one-hot matrix and the sorted segment-id vector are
-built once per trace and shared by all ~20 BN layers of a deep twin,
-instead of re-emitted per layer.
+``(slots, slots*nb)`` one-hot matrix and the segment-id vector are built
+once per trace and shared by all ~20 BN layers of a deep twin, instead of
+re-emitted per layer.
+
+The ORDER of the examples inside the flat batch is a property of the
+context too (``flat_batch_order``, chosen once per trace from ``slots``,
+``nb`` and the compute dtype): slot-major (example ``n = s*nb + b``)
+or slot-minor (``n = b*slots + s``). On the TPU XLA keeps the twin's
+activations with the batch in the sublane dimension (layout
+``{3,0,2,1}``: physically H, W, N, C, one (16, 128) bf16 tile over
+(N, C)), so splitting N into (slots, nb) is a bitcast only where the
+MINOR factor fills the sublane tile. Where ``nb`` misses it and
+``slots`` fills it (16 x 25 in bf16) the slot-major split costs one
+transposing copy of every activation and every cotangent per dw
+(``_slot_conv_bwd``), and slot-minor is free; where ``nb`` fills it
+(8 x 256) it is the other way round. No primitive spells the mapping
+itself: ``SlotCtx.slot_view`` / ``SlotCtx.flat`` (and ``seg_ids`` /
+``slot_matrix``) hold it. What leaves the twin is slot-leading in
+either order.
 
 Two env knobs select the per-slot reduction formulations for on-chip A/B
 (both read at TRACE time — a change needs a fresh trace, i.e. a new jit or
@@ -54,8 +70,9 @@ an unjitted call):
 
   - ``GARFIELD_SLOTFUSED_BN=matmul|segsum`` (default matmul): per-slot BN
     statistics as the one-hot slot matmul ``S @ (spatial reduce)`` (the r5
-    formulation) or as a sorted-segment sum over slot ids
-    (``jax.ops.segment_sum`` with ``indices_are_sorted``). The matmul
+    formulation) or as a segment sum over slot ids
+    (``jax.ops.segment_sum``, ``indices_are_sorted`` where the flat
+    batch is slot-major). The matmul
     keeps everything on the MXU; the segment sum avoids materializing the
     ``(slots, slots*b)`` operand and lowers to an in-order add — which of
     the two schedules better against the backward's grouped dw convs is a
@@ -82,6 +99,7 @@ from jax import lax
 
 __all__ = [
     "SlotCtx",
+    "flat_batch_order",
     "slot_conv",
     "conv",
     "bn_train",
@@ -113,12 +131,74 @@ def dw_mode():
     return os.environ.get("GARFIELD_SLOTFUSED_DW", "grouped")
 
 
+def sublane_rows(dtype):
+    """Rows of the TPU's packed sublane tile for ``dtype``: 8 for 32-bit
+    (and wider) elements, 16 for bf16, 32 for 8-bit."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def flat_batch_order(slots, nb, dtype):
+    """The order of examples in the twin's flat batch: ``(order, why)``.
+
+    One function of what a trace can see, no model and no platform in it.
+    ``"slot-major"`` (example ``n = s*nb + b``) wherever ``nb`` is a
+    multiple of the sublane tile of the compute dtype, and wherever
+    ``slots`` is not; ``"slot-minor"`` (``n = b*slots + s``) where ``nb``
+    misses the tile and ``slots`` fills it. The minor factor of the batch
+    split has to fill the tile for the split to be a bitcast of the
+    batch-in-sublanes layout XLA gives the activations (module
+    docstring); where neither does (8 x 25 in bf16) the compile is mixed
+    and the order stays slot-major (PERF.md section 7).
+    """
+    rows, name = sublane_rows(dtype), jnp.dtype(dtype).name
+    if nb % rows == 0:
+        return "slot-major", f"nb={nb} fills the {rows}-row {name} tile"
+    if slots % rows:
+        return "slot-major", (
+            f"neither nb={nb} nor slots={slots} fills the {rows}-row "
+            f"{name} tile"
+        )
+    return "slot-minor", (
+        f"nb={nb} misses the {rows}-row {name} tile, slots={slots} fills it"
+    )
+
+
+def _slot_view(x, slots, slot_minor):
+    """Flat (slots*nb, ...) -> slot-leading (slots, nb, ...).
+
+    Slot-major: a reshape. Slot-minor: the (nb, slots, ...) reshape with
+    its two leading axes swapped — a transposed VIEW that the consuming
+    contraction takes in its dimension numbers (the dw convolution fuses
+    the swap into its operand), never a (slots, nb)-major copy.
+    """
+    nb = x.shape[0] // slots
+    if slot_minor:
+        return jnp.swapaxes(x.reshape((nb, slots) + x.shape[1:]), 0, 1)
+    return x.reshape((slots, nb) + x.shape[1:])
+
+
+def _flat(x_st, slot_minor):
+    """Slot-leading (slots, nb, ...) -> flat (slots*nb, ...): the inverse
+    of ``_slot_view``."""
+    if slot_minor:
+        x_st = jnp.swapaxes(x_st, 0, 1)
+    return x_st.reshape((-1,) + x_st.shape[2:])
+
+
 class SlotCtx:
     """Per-trace slot geometry + precomputed membership machinery.
 
     Built once per ``slot_grad_fn`` trace (``slotfused.build_slot_grad_fn``)
     and threaded through every primitive, so the slot matrix / segment ids
     exist once in the traced graph no matter how many layers consume them.
+
+    The context also owns the ORDER of the flat batch (``order`` /
+    ``order_why`` from ``flat_batch_order``; ``slot_minor`` as a bool):
+    ``seg_ids`` and ``slot_matrix`` say which slot a flat row belongs to,
+    ``slot_view`` and ``flat`` map between the flat batch and a
+    slot-leading ``(slots, nb, ...)`` array. Every primitive and every
+    twin assembly goes through these four, so the order is decided in one
+    place and nothing downstream of the twin sees it.
     """
 
     def __init__(self, slots, nb, dtype):
@@ -137,9 +217,18 @@ class SlotCtx:
                 f"GARFIELD_SLOTFUSED_DW must be grouped|unroll|segsum, "
                 f"got {self.dw!r}"
             )
-        # Sorted slot-membership ids (example k of the flat batch belongs
-        # to slot k // nb) — a host constant; jnp ops lift it once.
-        self.seg_ids = np.repeat(np.arange(self.slots), self.nb)
+        self.order, self.order_why = flat_batch_order(
+            self.slots, self.nb, dtype
+        )
+        self.slot_minor = self.order == "slot-minor"
+        # Slot-membership ids of the flat batch — a host constant; jnp ops
+        # lift it once. Slot-major: example k belongs to slot k // nb
+        # (sorted); slot-minor: to slot k % slots (not sorted).
+        ids = np.arange(self.slots)
+        self.seg_ids = (
+            np.tile(ids, self.nb) if self.slot_minor
+            else np.repeat(ids, self.nb)
+        )
         self._S = {}
 
     def slot_matrix(self, dtype):
@@ -152,27 +241,40 @@ class SlotCtx:
         transposing copies (traced 1.4 ms/step at ResNet-18 n=8), while
         ``S @ (per-example reduction)`` stays in natural layouts — and its
         autodiff transpose, ``S.T @ _``, is the equally clean per-slot
-        broadcast.
+        broadcast. The columns follow the context's flat-batch order.
         """
         key = jnp.dtype(dtype).name
         if key not in self._S:
-            self._S[key] = jnp.repeat(
-                jnp.eye(self.slots, dtype=dtype), self.nb, axis=1
+            eye = jnp.eye(self.slots, dtype=dtype)
+            self._S[key] = (
+                jnp.tile(eye, (1, self.nb)) if self.slot_minor
+                else jnp.repeat(eye, self.nb, axis=1)
             )
         return self._S[key]
+
+    def slot_view(self, x):
+        """Flat (slots*nb, ...) -> slot-leading (slots, nb, ...) view."""
+        return _slot_view(x, self.slots, self.slot_minor)
+
+    def flat(self, x_st):
+        """Slot-leading (slots, nb, ...) -> flat batch in this context's
+        order (the inverse of ``slot_view``)."""
+        return _flat(x_st, self.slot_minor)
 
 
 def slot_reduce(ctx, e):
     """Per-slot segment reduction: (slots*nb, C) f32 -> (slots, C) f32.
 
-    ``matmul`` mode: ``S @ e`` (MXU). ``segsum`` mode: sorted segment sum
-    over the slot ids (no (slots, slots*nb) operand; in-order adds, so the
+    ``matmul`` mode: ``S @ e`` (MXU). ``segsum`` mode: segment sum over
+    the slot ids (no (slots, slots*nb) operand; in-order adds, so the
     two modes are f32-rounding-equal for equal-length segments summed in
-    index order — equality-pinned in tests/test_slotfused.py).
+    index order — equality-pinned in tests/test_slotfused.py). The ids
+    are sorted only where the flat batch is slot-major.
     """
     if ctx.bn_mode == "segsum":
         return jax.ops.segment_sum(
-            e, ctx.seg_ids, num_segments=ctx.slots, indices_are_sorted=True
+            e, ctx.seg_ids, num_segments=ctx.slots,
+            indices_are_sorted=not ctx.slot_minor,
         )
     return ctx.slot_matrix(e.dtype) @ e
 
@@ -184,13 +286,13 @@ def slot_expand(ctx, v_st, spatial_dims):
     reduction — its autodiff transpose is (spatial reduce -> ``S @ _``),
     the same copy-free route as the forward stats (a broadcast+reshape
     formulation transposes to the 5-D grouped reduce this library avoids).
-    ``segsum`` dw mode: a row gather over the sorted slot ids, whose
-    transpose is a sorted segment-sum scatter-add — the dw-epilogue
+    ``segsum`` dw mode: a row gather over the slot ids, whose
+    transpose is a segment-sum scatter-add — the dw-epilogue
     formulation (module docstring): per-slot bias/BN cotangents leave the
     MXU to the grouped dw convs.
     """
     if ctx.dw == "segsum":
-        flat = v_st[ctx.seg_ids]  # gather; transpose = sorted segment sum
+        flat = v_st[ctx.seg_ids]  # gather; transpose = segment sum
     else:
         flat = ctx.slot_matrix(v_st.dtype).T @ v_st  # (slots*nb, C)
     return flat.reshape(
@@ -209,36 +311,51 @@ def _conv(x, w, stride, padding, groups):
     )
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
-def _slot_conv(x, w_st, stride, padding, slots, groups):
+@partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def _slot_conv(x, w_st, stride, padding, slots, groups, slot_minor):
     return _conv(x, w_st[0], stride, padding, groups)
 
 
-def _slot_conv_fwd(x, w_st, stride, padding, slots, groups):
+def _slot_conv_fwd(x, w_st, stride, padding, slots, groups, slot_minor):
     return _conv(x, w_st[0], stride, padding, groups), (x, w_st[0])
 
 
-def _slot_conv_bwd(stride, padding, slots, groups, res, dy):
+def _slot_conv_bwd(stride, padding, slots, groups, slot_minor, res, dy):
     """dx fused over the flat batch; dw slot-resolved.
 
     dw formulations (``GARFIELD_SLOTFUSED_DW``, read at trace time):
     ``grouped`` (default) and ``segsum`` run ONE batch-grouped conv via
-    the transpose of the slot-vmapped conv — the (slots, nb) reshape is a
-    view of the flat activations, so no per-slot operand copies and the
-    (slots, ...) result needs no stacking DUS (``segsum`` differs only in
-    the epilogue reductions around the convs — see ``slot_expand``).
-    ``unroll`` is the r5 A/B escape hatch: n per-slot convs + stack
-    (traced 3.0 ms/step of operand copies + 1.6 ms of stack DUS at n=8
-    ResNet-18 — the b=25 slot slices misalign with the (8,128) tile).
+    the transpose of the slot-vmapped conv over the slot-leading view of
+    the flat activations and cotangents, so the (slots, ...) result needs
+    no stacking DUS (``segsum`` differs only in the epilogue reductions
+    around the convs — see ``slot_expand``).
+
+    That view is free only in the right flat-batch order
+    (``flat_batch_order``; ``slot_minor`` is its static answer). XLA:TPU
+    keeps these activations batch-in-sublanes — in the compiled ResNet-50
+    gradient pass at 16 x 25 bf16, ``bf16[400,32,32,256]{3,0,2,1:
+    T(8,128)(2,1)}``, physically H, W, N, C with a 16-row tile over N — and
+    the slot-major split N -> (16, 25) cuts tiles apart: the compiled text
+    puts a ``copy`` to ``{3,2,1,0}`` in front of the ``bitcast`` to
+    ``bf16[16,25,32,32,256]`` of every activation and every cotangent
+    (125 top-level copies in that text, 40 slot-minor; 15 ms of an 86 ms
+    step on the chip, PERF.md section 6). Slot-minor splits N -> (25, 16):
+    the slots fill the tile, the split is a bitcast and the (b, s) swap
+    rides in the dw convolution's operand. At 8 x 256 the slot-major
+    split is the bitcast and slot-minor would add the copies.
+
+    ``unroll`` is the r5 A/B escape hatch: n per-slot convs + stack over
+    the same view (traced 3.0 ms/step of operand copies + 1.6 ms of stack
+    DUS at n=8 ResNet-18, slot-major — the b=25 slot slices misalign with
+    the (8,128) tile).
     """
     x, w0 = res
     # dx: one fused transposed conv over the whole n*b batch.
     dx = jax.linear_transpose(
         lambda x_: _conv(x_, w0, stride, padding, groups), x
     )(dy)[0]
-    nb = x.shape[0] // slots
-    xs = x.reshape(slots, nb, *x.shape[1:])
-    dys = dy.reshape(slots, nb, *dy.shape[1:])
+    xs = _slot_view(x, slots, slot_minor)
+    dys = _slot_view(dy, slots, slot_minor)
     if dw_mode() != "unroll":
         def vconv(w_st_):
             return jax.vmap(
@@ -260,7 +377,7 @@ def _slot_conv_bwd(stride, padding, slots, groups, res, dy):
 _slot_conv.defvjp(_slot_conv_fwd, _slot_conv_bwd)
 
 
-def slot_conv(x, w_st, stride, padding, slots, groups=1):
+def slot_conv(x, w_st, stride, padding, slots, groups=1, slot_minor=False):
     """Convolution over the flat (slots*b) batch with a STACKED kernel.
 
     ``w_st`` is (slots, kh, kw, ci/groups, co) with all slot rows equal (a
@@ -269,9 +386,10 @@ def slot_conv(x, w_st, stride, padding, slots, groups=1):
     gradients as ``w_st``'s cotangent — the only place worker-resolved
     arithmetic is actually required. ``groups`` is
     ``lax.conv_general_dilated``'s ``feature_group_count`` (depthwise
-    convs pass ``groups == in_channels``).
+    convs pass ``groups == in_channels``). ``slot_minor`` is the flat
+    batch's order (``SlotCtx.slot_minor``; only the dw rule reads it).
     """
-    return _slot_conv(x, w_st, stride, padding, slots, groups)
+    return _slot_conv(x, w_st, stride, padding, slots, groups, slot_minor)
 
 
 def conv(ctx, x, p_st, stride, padding, groups=1):
@@ -286,7 +404,7 @@ def conv(ctx, x, p_st, stride, padding, groups=1):
         padding = ((padding, padding), (padding, padding))
     y = slot_conv(
         x, p_st["kernel"].astype(ctx.dtype), stride, padding, ctx.slots,
-        groups,
+        groups, ctx.slot_minor,
     )
     if "bias" in p_st:
         y = y + slot_expand(ctx, p_st["bias"].astype(ctx.dtype), x.ndim - 2)
@@ -348,9 +466,10 @@ def bn_train(ctx, x, p_st, stats, momentum=0.9, eps=1e-5):
 # --------------------------------------------------------------------------
 
 def dense(ctx, x2, p_st):
-    """(slots*b, F) @ per-slot kernel -> (slots, b, O) via a slot-batched
-    matmul; autodiff's dk is a slot-batched matmul too (MXU-native)."""
-    x3 = x2.reshape(ctx.slots, ctx.nb, -1).astype(ctx.dtype)
+    """(slots*b, ...) rows, flattened to (slots*b, F), @ per-slot kernel ->
+    (slots, b, O) via a slot-batched matmul; autodiff's dk is a
+    slot-batched matmul too (MXU-native)."""
+    x3 = ctx.slot_view(x2.reshape(x2.shape[0], -1)).astype(ctx.dtype)
     y = jnp.einsum("sbf,sfo->sbo", x3, p_st["kernel"].astype(ctx.dtype))
     if "bias" in p_st:
         y = y + p_st["bias"].astype(ctx.dtype)[:, None, :]
@@ -360,18 +479,19 @@ def dense(ctx, x2, p_st):
 def seq_dense(ctx, x, p_st):
     """Sequence-layout dense: (slots*b, T, F) @ per-slot kernel.
 
-    The T axis folds into the per-slot batch rows, so this is the same
-    MXU-native 'sbf,sfo->sbo' contraction as ``dense`` — flax
+    The same MXU-native slot-batched contraction as ``dense`` with the T
+    axis riding beside the per-slot batch rows ('sbtf,sfo->sbto') — flax
     ``nn.Dense`` on (b, T, F) contracts the last dim identically, so
     the twin-vs-unroll difference is only the slot batching of the
     kernel operand. Returns (slots*b, T, O).
     """
-    T = x.shape[1]
-    x3 = x.reshape(ctx.slots, ctx.nb * T, -1).astype(ctx.dtype)
-    y = jnp.einsum("sbf,sfo->sbo", x3, p_st["kernel"].astype(ctx.dtype))
+    y = jnp.einsum(
+        "sbtf,sfo->sbto", ctx.slot_view(x).astype(ctx.dtype),
+        p_st["kernel"].astype(ctx.dtype),
+    )
     if "bias" in p_st:
-        y = y + p_st["bias"].astype(ctx.dtype)[:, None, :]
-    return y.reshape(ctx.slots * ctx.nb, T, -1)
+        y = y + p_st["bias"].astype(ctx.dtype)[:, None, None, :]
+    return ctx.flat(y)
 
 
 # --------------------------------------------------------------------------
@@ -478,21 +598,19 @@ def embed(ctx, tok, emb_st):
     slot-vmapped gather is a per-slot scatter-add — exactly the
     per-worker embedding gradient, with no custom vjp needed.
     """
-    tok3 = tok.reshape((ctx.slots, ctx.nb) + tok.shape[1:])
     out = jax.vmap(lambda tab, t: jnp.take(tab, t, axis=0))(
-        emb_st.astype(ctx.dtype), tok3
+        emb_st.astype(ctx.dtype), ctx.slot_view(tok)
     )
-    return out.reshape((ctx.slots * ctx.nb,) + out.shape[2:])
+    return ctx.flat(out)
 
 
 def pos_embed(ctx, x, pos_st):
     """Add learned per-slot positional embeddings (slots, T, D) onto the
-    flat (slots*b, T, D) activations. The (slots, nb) view is free; the
+    flat (slots*b, T, D) activations through the slot-leading view; the
     broadcast-add's transpose is a per-slot sum over the nb rows — the
     positional table's per-worker gradient."""
-    xs = x.reshape((ctx.slots, ctx.nb) + x.shape[1:])
-    y = xs + pos_st[:, None].astype(ctx.dtype)
-    return y.reshape(x.shape)
+    y = ctx.slot_view(x) + pos_st[:, None].astype(ctx.dtype)
+    return ctx.flat(y)
 
 
 def bias_add(ctx, x, b_st):

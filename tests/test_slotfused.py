@@ -30,6 +30,12 @@ The twins' two formulation knobs (GARFIELD_SLOTFUSED_BN=matmul|segsum,
 GARFIELD_SLOTFUSED_DW=grouped|unroll|segsum) are equality-pinned against
 each other, and trainer-level fused-vs-unroll trajectory A/B covers
 cifarnet (existing) plus the DenseNet family (new this round).
+
+The ORDER of the flat batch (slotlayers.flat_batch_order: slot-major or
+slot-minor, from slots, nb and the compute dtype) is pinned as a pure
+function, and both tiers plus both knobs run again at a geometry that
+selects slot-minor (8 slots x 3: 8 fills the 32-/64-bit sublane tile, 3
+misses it) against the same unroll reference at the same tolerances.
 """
 
 import jax
@@ -37,12 +43,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from garfield_tpu.models import select_model, slotfused
+from garfield_tpu.models import resnet, select_model, slotfused
+from garfield_tpu.models import slotlayers as sl
 from garfield_tpu.models.densenet import DenseNet
 from garfield_tpu.parallel import core
 from garfield_tpu.utils import selectors
 
 N, B = 3, 2
+#: A geometry whose flat batch is ordered slot-minor in f32 and in f64.
+N_MINOR, B_MINOR = 8, 3
 
 
 @pytest.fixture
@@ -184,6 +193,90 @@ def test_twin_structural_pin_x64_slow(x64, name, shape):
     _x64_family(name, shape)
 
 
+# --- the flat batch's order (slot-major / slot-minor) ----------------------
+
+@pytest.mark.parametrize("slots,nb,dtype,order", [
+    (16, 25, jnp.bfloat16, "slot-minor"),   # r50n16: 25 misses 16, 16 fills
+    (8, 256, jnp.bfloat16, "slot-major"),   # r18n8: nb fills the tile
+    (8, 25, jnp.bfloat16, "slot-major"),    # C0: neither fills 16
+    (16, 32, jnp.bfloat16, "slot-major"),   # both fill: nb decides
+    (4, 3, jnp.float32, "slot-major"),      # neither fills 8
+    (8, 3, jnp.float32, "slot-minor"),      # 3 misses 8, 8 fills
+])
+def test_flat_batch_order(slots, nb, dtype, order):
+    """The selection is one pure function of (slots, nb, dtype): slot-minor
+    only where nb misses the packed sublane tile (16 rows bf16, 8 rows
+    32-bit) and slots fills it."""
+    got, why = sl.flat_batch_order(slots, nb, dtype)
+    assert got == order
+    assert f"nb={nb}" in why and "tile" in why
+    assert sl.SlotCtx(slots, nb, dtype).order == order
+
+
+@pytest.mark.parametrize("slots,nb", [(N, B), (N_MINOR, B_MINOR)],
+                         ids=["slot-major", "slot-minor"])
+def test_slot_ctx_maps_agree(slots, nb):
+    """SlotCtx's four holders of the order agree with each other: flat and
+    slot_view are inverses, and seg_ids / slot_matrix name the slot that
+    slot_view puts each flat row in."""
+    ctx = sl.SlotCtx(slots, nb, jnp.float32)
+    assert ctx.slot_minor == (slots == N_MINOR)
+    x_st = jnp.arange(slots * nb * 5, dtype=jnp.float32).reshape(slots, nb, 5)
+    flat = ctx.flat(x_st)
+    assert flat.shape == (slots * nb, 5)
+    np.testing.assert_array_equal(ctx.slot_view(flat), x_st)
+    # Row k of the flat batch came from slot seg_ids[k] ...
+    slot_of_row = np.asarray(flat[:, 0]).astype(int) // (nb * 5)
+    np.testing.assert_array_equal(slot_of_row, ctx.seg_ids)
+    # ... which is the one-hot column of the slot matrix, so S @ flat is the
+    # per-slot sum in either order.
+    S = ctx.slot_matrix(jnp.float32)
+    np.testing.assert_array_equal(
+        np.argmax(np.asarray(S), axis=0), ctx.seg_ids
+    )
+    np.testing.assert_allclose(S @ flat, x_st.sum(axis=1))
+
+
+def _minor_modules(dtype):
+    """CPU-affordable instances of the families the slot-minor pins run: a
+    BasicBlock and a Bottleneck ResNet (same class and twin path as
+    resnet18 / resnet50, one block per stage) and the dense-headed
+    cifarnet (whose dense layers flatten back into the flat batch)."""
+    return {
+        "basicblock": (resnet.ResNet(resnet.BasicBlock, (1, 1), 10, dtype),
+                       (16, 16, 3)),
+        "bottleneck": (resnet.ResNet(resnet.Bottleneck, (1, 1), 10, dtype),
+                       (16, 16, 3)),
+        "cifarnet": (select_model("cifarnet", "cifar10", dtype=dtype),
+                     (32, 32, 3)),
+    }
+
+
+@pytest.mark.parametrize("family", ["basicblock", "bottleneck", "cifarnet"])
+def test_twin_structural_pin_x64_slot_minor(x64, family):
+    """The f64 structural pin at a slot-minor geometry (8 slots x 3): the
+    twin's flat batch is a permutation of the slot-major one, and grads,
+    losses and batch_stats leave slot-leading and equal to the unroll's
+    per leaf at the same 1e-5 / 1e-7 / 1e-9."""
+    assert sl.SlotCtx(N_MINOR, B_MINOR, jnp.float64).slot_minor
+    module, shape = _minor_modules(jnp.float64)[family]
+    _check_family(
+        module, shape, g_tol=1e-5, ms_tol=1e-7, loss_tol=1e-9,
+        n=N_MINOR, b=B_MINOR, dtype=jnp.float64,
+    )
+
+
+@pytest.mark.parametrize("idx", range(3), ids=["vit", "gpt", "gpt_tied"])
+def test_transformer_twin_structural_pin_x64_slot_minor(x64, idx):
+    """seq_dense, embed, pos_embed and the tied head through the slot-minor
+    views, same f64 pin as the slot-major transformer cases."""
+    _, module, shape, tokens = _trans_modules(jnp.float64)[idx]
+    _check_family(
+        module, shape, g_tol=1e-5, ms_tol=1e-7, loss_tol=1e-9,
+        n=N_MINOR, b=B_MINOR, dtype=jnp.float64, tokens=tokens,
+    )
+
+
 # --- tier 2: pipeline pins (float32, measured-floor tolerances) ----------
 
 @pytest.mark.parametrize("name,shape,g_tol,ms_tol,loss_tol", [
@@ -198,6 +291,21 @@ def test_twin_pipeline_pin_f32(name, shape, g_tol, ms_tol, loss_tol):
 
 def test_twin_pipeline_pin_f32_densenet():
     _check_family(DenseNet((2, 2), growth_rate=8), (16, 16, 3), 1e-3, 1e-3)
+
+
+@pytest.mark.parametrize("family,g_tol,ms_tol,loss_tol", [
+    # The ResNets at resnet18's tolerances, cifarnet at cifarnet's (the
+    # slot-major cases' own, above and below).
+    ("basicblock", 6e-2, 1e-3, 1e-4),
+    ("bottleneck", 6e-2, 1e-3, 1e-4),
+    ("cifarnet", 1e-5, 1e-5, 1e-5),
+])
+def test_twin_pipeline_pin_f32_slot_minor(family, g_tol, ms_tol, loss_tol):
+    """The f32 pipeline pin at a slot-minor geometry (8 slots x 3)."""
+    assert sl.SlotCtx(N_MINOR, B_MINOR, jnp.float32).slot_minor
+    module, shape = _minor_modules(jnp.float32)[family]
+    _check_family(module, shape, g_tol, ms_tol, n=N_MINOR, b=B_MINOR,
+                  loss_tol=loss_tol)
 
 
 @pytest.mark.slow
@@ -339,12 +447,21 @@ def test_resolve_slot_grad_fn_gates():
     ) is None
 
 
-def test_bn_stats_modes_agree(monkeypatch):
+GEOMETRIES = pytest.mark.parametrize(
+    "n,b", [(N, B), (N_MINOR, B_MINOR)], ids=["slot-major", "slot-minor"]
+)
+
+
+@GEOMETRIES
+def test_bn_stats_modes_agree(monkeypatch, n, b):
     """GARFIELD_SLOTFUSED_BN=matmul|segsum are the same per-slot sums
     (equal-length segments added in index order on both routes) — pinned
-    tightly, grads AND batch_stats."""
+    tightly, grads AND batch_stats; slot-minor runs the segment sum over
+    unsorted ids."""
     module = DenseNet((2, 2), growth_rate=8)
-    loss_fn, grad_fn, params, ms, x, y, keys = _setup(module, (16, 16, 3))
+    loss_fn, grad_fn, params, ms, x, y, keys = _setup(
+        module, (16, 16, 3), n, b
+    )
     slot_fn = slotfused.build_slot_grad_fn(module, loss_fn)
     monkeypatch.setenv("GARFIELD_SLOTFUSED_BN", "matmul")
     g_a, (_, ms_a) = slot_fn(params, ms, x, y, keys)
@@ -354,8 +471,8 @@ def test_bn_stats_modes_agree(monkeypatch):
     _assert_per_leaf(ms_a, ms_b, 1e-5, what="batch_stats")
 
 
-def _dw_mode_check(module, shape, mode, monkeypatch, tol=1e-4):
-    loss_fn, grad_fn, params, ms, x, y, keys = _setup(module, shape)
+def _dw_mode_check(module, shape, mode, monkeypatch, tol=1e-4, n=N, b=B):
+    loss_fn, grad_fn, params, ms, x, y, keys = _setup(module, shape, n, b)
     slot_fn = slotfused.build_slot_grad_fn(module, loss_fn)
     monkeypatch.delenv("GARFIELD_SLOTFUSED_DW", raising=False)
     g_grouped, _ = slot_fn(params, ms, x, y, keys)
@@ -364,13 +481,15 @@ def _dw_mode_check(module, shape, mode, monkeypatch, tol=1e-4):
     _assert_per_leaf(g_grouped, g_mode, tol)
 
 
+@GEOMETRIES
 @pytest.mark.parametrize("mode", ["unroll", "segsum"])
-def test_dw_modes_agree(monkeypatch, mode):
+def test_dw_modes_agree(monkeypatch, mode, n, b):
     """grouped (default) / unroll / segsum dw formulations are the same
-    math on a plain-conv BN model. (Env is read at trace time; the
-    unjitted calls retrace.)"""
+    math on a plain-conv BN model, in either order of the flat batch
+    (``unroll`` indexes the same slot view the grouped conv is vmapped
+    over). (Env is read at trace time; the unjitted calls retrace.)"""
     _dw_mode_check(DenseNet((2, 2), growth_rate=8), (16, 16, 3), mode,
-                   monkeypatch)
+                   monkeypatch, n=n, b=b)
 
 
 @pytest.mark.slow
@@ -407,6 +526,27 @@ def test_per_slot_grads_routes_fused():
         ),
         g_f, g_u,
     )
+
+
+@pytest.mark.parametrize("n,b,order,why", [
+    (N, B, "slot-major",
+     "neither nb=2 nor slots=3 fills the 8-row float32 tile"),
+    (N_MINOR, B_MINOR, "slot-minor",
+     "nb=3 misses the 8-row float32 tile, slots=8 fills it"),
+])
+def test_flat_batch_order_logged_once_per_trace(capsys, n, b, order, why):
+    """The engagement counter: the twin says which order it chose and why
+    on standard error (tools.info), once per trace and not per call."""
+    module = select_model("cifarnet", "cifar10")
+    loss_fn, _, params, ms, x, y, keys = _setup(module, (32, 32, 3), n, b)
+    slot_fn = jax.jit(slotfused.build_slot_grad_fn(module, loss_fn))
+    capsys.readouterr()
+    for _ in range(2):
+        jax.block_until_ready(slot_fn(params, ms, x, y, keys))
+    lines = [l for l in capsys.readouterr().err.splitlines()
+             if "flat batch order:" in l]
+    assert len(lines) == 1, lines
+    assert f"flat batch order: {order} ({why})" in lines[0], lines
 
 
 def _trainer_final_params(module, x, y, disable, monkeypatch, gar="median"):
