@@ -3,8 +3,16 @@
 A configuration is the file its entry names; a traffic mix is
 ``traffic/<traffic>.json``; a cell's limits for `correct` are
 ``limits/<cell>.json``; a per-layer metric's reader is
-``layer_metrics/<name>.py``. Adding a cell, a configuration or a metric is
-adding files and entries — no file that is there changes.
+``layer_metrics/<name>.py``. What a configuration names is a file too: its
+``model.family`` the plain reference ``references/<family>.py`` (which may
+state how its leaves are made, ``leaf_rules``), that family's ``INPUT`` the
+kind of input ``inputs/<kind>.py``, its ``topology`` and ``optimizer.name``
+the adapters ``harness/topologies/<name>.py`` and
+``harness/optimizers/<name>.py``, its ``loss``, its optimizer and the traffic
+mix's ``rule`` and ``attack`` the plain ``references/{losses,optimizers,
+rules,attacks}/<name>.py``. Adding a cell, a configuration, a model family, a
+kind of input or a metric is adding files and entries — no file that is
+there changes (``tests/toy.py`` adds a token model that way).
 """
 
 import importlib

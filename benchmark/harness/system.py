@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import jaxlib
 
+import inputs
 import references
 
 from . import spec, weights
@@ -54,17 +55,23 @@ def _norms(tree):
         lambda v: jnp.linalg.norm(v.astype(jnp.float32)), tree)
 
 
+def _delete(tree):
+    for leaf in jax.tree.leaves(tree):
+        if hasattr(leaf, "delete") and not leaf.is_deleted():
+            leaf.delete()
+
+
 class StepCache:
     """The compiled step program, kept whole beside the compile cache
     (``<cache dir>/bench_steps/<key>``), so that a run after a cell's first
     neither traces the program (for r50n16, 161 Pallas kernels are lowered
     again on every ``lower()``) nor asks XLA for it. The key is everything
-    the program is made from: the program's and the harness's sources, the
-    configuration, the traffic mix, JAX's versions, the devices and the
-    program's environment switches. Anything that goes wrong here is said on
-    standard error and the step is compiled the usual way. Not used on
-    XLA:CPU, whose loader loses functions of an executable it is handed back
-    (a rehearsal's runs then fail at their first step)."""
+    the program is made from: the program's, the harness's and the input
+    kinds' sources, the configuration, the traffic mix, JAX's versions, the
+    devices and the program's environment switches. Anything that goes wrong
+    here is said on standard error and the step is compiled the usual way.
+    Not used on XLA:CPU, whose loader loses functions of an executable it is
+    handed back (a rehearsal's runs then fail at their first step)."""
 
     @classmethod
     def of(cls, cache_dir, config, traffic):
@@ -75,7 +82,8 @@ class StepCache:
 
     def __init__(self, cache_dir, config, traffic):
         h = hashlib.sha256()
-        for root in (spec.ROOT / "garfield_tpu", spec.BENCH_DIR / "harness"):
+        for root in (spec.ROOT / "garfield_tpu", spec.BENCH_DIR / "harness",
+                     spec.BENCH_DIR / "inputs"):
             for path in sorted(root.rglob("*.py")):
                 h.update(str(path.relative_to(spec.ROOT)).encode())
                 h.update(path.read_bytes())
@@ -165,21 +173,23 @@ class System:
 
         key = weights.seed_key(seed)
         n, batch = config["num_workers"], config["batch_per_worker"]
-        example = jnp.zeros(model["image"], jnp.float32)[None]
-        scales = references.family(model["family"]).init_scales(
-            model, config.get("init"))
+        family = references.family(model["family"])
+        kind = inputs.kind(family.INPUT)
+        example = kind.example(model)
+        scales, rules = weights.stated(family, model, config.get("init"))
 
         def make_state(k):
             # The program's own init for everything but the weights, which
             # are the harness's: one trace of ``init_fn``.
             state = init_fn(k, example)
             shapes = {p: v.shape for p, v in flat_paths(state.params).items()}
-            made = weights.make_params(k, shapes, scales)
+            made = weights.make_params(k, shapes, scales, rules)
             return state.replace(params=jax.tree.unflatten(
                 jax.tree.structure(state.params), [made[p] for p in shapes]))
 
         self.state = jax.jit(make_state)(key)
-        # A copy of the start that donation cannot reach.
+        # A copy of the start that donation cannot reach; `forget_start`
+        # drops it once the first steps have been read.
         self.start = jax.jit(
             lambda tree: jax.tree.map(jnp.copy, tree))(self.state.params)
         phase("state_s", self.state, self.start)
@@ -188,8 +198,7 @@ class System:
         num = weights.NUM_BATCHES
 
         def make_batches(k):
-            xs, ys = weights.make_batches(
-                k, n, batch, model["image"], model["num_classes"], num)
+            xs, ys = kind.batches(k, model, n, batch, num)
             return tuple((xs[b], ys[b]) for b in range(num))
 
         self.batches = jax.jit(
@@ -228,11 +237,14 @@ class System:
         """``{path: norm}`` of the parameters' change since the start."""
         return flat_paths(self._change_norms(state.params, self.start))
 
+    def forget_start(self):
+        """Drop the harness's copy of the start: after the first steps
+        nothing reads it, and the window holds the program's bytes alone."""
+        _delete(self.start)
+        self.start = None
+
     def free(self, state):
         """Drop every device buffer the program's run holds; ``state`` is
         the last one the window returned."""
-        for tree in (state, self.state, self.start, self.batches):
-            for leaf in jax.tree.leaves(tree):
-                if hasattr(leaf, "delete") and not leaf.is_deleted():
-                    leaf.delete()
+        _delete((state, self.state, self.start, self.batches))
         self.state = self.start = self.batches = self.compiled = None

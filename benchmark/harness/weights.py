@@ -1,9 +1,10 @@
-"""Weights and input batches, made on the device from ``--seed``.
+"""Weights, made on the device from ``--seed``.
 
 The harness makes them (not the program), so the timed program and the plain
 reference start from the same values while the reference takes nothing the
-program has made. Both are pure functions of (seed, shapes): the same seed
-gives the same arrays in any process.
+program has made. A pure function of (seed, shapes, what the family states):
+the same seed gives the same arrays in any process. The input batches are
+an input kind's (`inputs/<kind>.py`), from the same key.
 """
 
 import math
@@ -12,10 +13,8 @@ import zlib
 import jax
 import jax.numpy as jnp
 
+# Distinct global batches a run cycles through.
 NUM_BATCHES = 8
-# Strength of the per-class template added to the noise images: the labels
-# are learnable, so the loss of a sound run falls instead of blowing up.
-CLASS_SIGNAL = 0.5
 
 
 def seed_key(seed):
@@ -27,41 +26,57 @@ def _leaf_key(key, path):
     return jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
 
 
-def make_params(key, shapes, scales=None):
+def _made(key, path, shape, rule):
+    """One leaf by its rule: ``("normal", fan_in)`` (variance 2 / fan_in),
+    ``("ones",)`` or ``("zeros",)``."""
+    kind = rule[0]
+    if kind == "normal":
+        return jax.random.normal(
+            _leaf_key(key, path), shape, jnp.float32
+        ) * math.sqrt(2.0 / rule[1])
+    if kind == "ones":
+        return jnp.ones(shape, jnp.float32)
+    if kind == "zeros":
+        return jnp.zeros(shape, jnp.float32)
+    raise ValueError(f"no way to make a {kind!r} leaf ({path!r})")
+
+
+def _default_rule(path, shape):
+    leaf = path.rsplit("/", 1)[-1]
+    if leaf == "kernel":
+        return ("normal", math.prod(shape[:-1]))
+    if leaf == "scale":
+        return ("ones",)
+    if leaf == "bias":
+        return ("zeros",)
+    raise ValueError(f"no rule to make the weight leaf {path!r}")
+
+
+def make_params(key, shapes, scales=None, rules=None):
     """``{path: f32 array}`` for ``{path: shape}``: He-normal kernels
-    (variance 2 / fan_in), unit scales, zero biases — the usual start of a
-    BatchNorm ResNet — each leaf times ``scales.get(path, 1)``."""
-    scales = scales or {}
-    unknown = set(scales) - set(shapes)
+    (variance 2 / fan_in, fan_in the product of all axes but the last), unit
+    scales, zero biases — the usual start of a BatchNorm ResNet — unless
+    ``rules`` states the leaf's own (``{path: ("normal", fan_in) | ("ones",)
+    | ("zeros",)}``: an embedding, a stack of expert kernels whose leading
+    axis is no fan-in, a leaf by another name); each leaf times
+    ``scales.get(path, 1)``. A leaf with neither raises."""
+    scales, rules = scales or {}, rules or {}
+    unknown = (set(scales) | set(rules)) - set(shapes)
     if unknown:
-        raise ValueError(f"scales for leaves that do not exist: {unknown}")
+        raise ValueError(
+            f"scales or rules for leaves that do not exist: {unknown}")
     params = {}
     for path in sorted(shapes):
         shape = tuple(shapes[path])
-        leaf = path.rsplit("/", 1)[-1]
-        if leaf == "kernel":
-            fan_in = math.prod(shape[:-1])
-            made = jax.random.normal(
-                _leaf_key(key, path), shape, jnp.float32
-            ) * math.sqrt(2.0 / fan_in)
-        elif leaf == "scale":
-            made = jnp.ones(shape, jnp.float32)
-        elif leaf == "bias":
-            made = jnp.zeros(shape, jnp.float32)
-        else:
-            raise ValueError(f"no rule to make the weight leaf {path!r}")
-        params[path] = made * scales.get(path, 1.0)
+        rule = rules[path] if path in rules else _default_rule(path, shape)
+        params[path] = _made(key, path, shape, tuple(rule)) * scales.get(
+            path, 1.0)
     return params
 
 
-def make_batches(key, n, batch, image, num_classes, num_batches=NUM_BATCHES):
-    """``(xs, ys)``: ``num_batches`` distinct global batches,
-    xs (num_batches, n, batch, H, W, C) f32 and ys (num_batches, n, batch)
-    int32. Every row differs: unit noise plus a per-class template."""
-    kt, kx, ky = jax.random.split(jax.random.fold_in(key, 0xDA7A), 3)
-    templates = jax.random.normal(kt, (num_classes, *image), jnp.float32)
-    ys = jax.random.randint(ky, (num_batches, n, batch), 0, num_classes,
-                            jnp.int32)
-    noise = jax.random.normal(kx, (num_batches, n, batch, *image),
-                              jnp.float32)
-    return noise + CLASS_SIGNAL * templates[ys], ys
+def stated(family, model, init=None):
+    """``(scales, rules)`` as a reference family states them for
+    `make_params`: its ``init_scales(model, init)`` and, where it has one,
+    its ``leaf_rules(model)``."""
+    rules = family.leaf_rules(model) if hasattr(family, "leaf_rules") else None
+    return family.init_scales(model, init), rules

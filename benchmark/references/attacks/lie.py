@@ -5,6 +5,9 @@ deviation of the colluding cohort's own honest gradients, z = 1.035."""
 
 import jax.numpy as jnp
 
+# A leaf's result needs that leaf's rows alone (`harness/reference.py`).
+LEAFWISE = True
+
 Z = 1.035
 
 

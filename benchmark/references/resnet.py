@@ -16,6 +16,8 @@ group may hold ``"head_scale"`` and ``"residual_bn_scale"`` (`init_scales`).
 import jax
 import jax.numpy as jnp
 
+# The kind of input this family reads (`inputs/images.py`).
+INPUT = "images"
 BN_EPS = 1e-5
 
 _BLOCKS = {"basic": ("BasicBlock", 1), "bottleneck": ("Bottleneck", 4)}

@@ -3,6 +3,9 @@ even number of rows, the smaller of the two middle values."""
 
 import jax.numpy as jnp
 
+# A leaf's result needs that leaf's rows alone (`harness/reference.py`).
+LEAFWISE = True
+
 
 def aggregate(stack, f):
     out = {}

@@ -96,7 +96,8 @@ def first_steps(sut):
     """The warm-up steps, through the window's own call and feed; returns the
     state they leave and what the check compares with the reference once the
     window has closed: each step's loss, the first aggregated gradient's
-    norm per leaf, the parameters' change per leaf."""
+    norm per leaf, the parameters' change per leaf. The harness's copy of
+    the start goes once they are read."""
     state, losses = sut.state, []
     for i in range(reference.STEPS):
         state, loss = sut.step(state, *sut.batches[i % len(sut.batches)])
@@ -105,6 +106,7 @@ def first_steps(sut):
             grad1 = sut.first_gradient_norms(state)
     dparam = sut.change_norms(state)
     losses, grad1, dparam = jax.device_get((losses, grad1, dparam))
+    sut.forget_start()
     return state, {
         "loss": [float(l) for l in losses],
         "grad1": {p: float(v) for p, v in grad1.items()},

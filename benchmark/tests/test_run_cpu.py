@@ -1,11 +1,14 @@
 """``run.py`` end to end at a toy size on the CPU, in a temporary copy of the
-benchmark that gains a toy cell by files and entries alone (`toy.py`).
+benchmark that gains toy cells by files and entries alone (`toy.py`): ResNet
+cells of the family the benchmark has, and a token cell whose kind of input
+and reference family arrive with it.
 
 Each run is a process of its own (the device count is fixed at start-up).
 The chip check is switched off here and nowhere else: ``run.run_cell(...,
 check_device=False)``. Slow by CPU compiles: about a minute per run cold.
 """
 
+import filecmp
 import json
 import os
 import pathlib
@@ -93,7 +96,42 @@ def checkout(tmp_path_factory):
             ("krum", "lie", 1, None), ("median", "lie", 4, loose),
             ("average", "none", 1, None)]
     }
+    cells["token"] = toy.make_token_cell(root)
     return root, cells
+
+
+def _changed(ours, theirs):
+    """Files of the tree ``ours`` that ``theirs`` lacks or holds changed."""
+    found = filecmp.dircmp(ours, theirs, ignore=["__pycache__"])
+    out = [*found.left_only, *found.diff_files, *found.funny_files]
+    for name in found.common_dirs:
+        out += [f"{name}/{p}" for p in _changed(ours / name, theirs / name)]
+    return out
+
+
+def test_the_copy_differs_from_the_tree_by_added_files_and_entries(checkout):
+    root, _ = checkout
+    assert _changed(toy.REPO / "benchmark", root / "benchmark") == []
+    ours = json.loads((toy.REPO / "BENCHMARK.json").read_text())
+    theirs = json.loads((root / "BENCHMARK.json").read_text())
+    assert sorted(ours) == sorted(theirs)
+    for key, value in ours.items():
+        if isinstance(value, list) and value and isinstance(value[0], dict):
+            assert theirs[key][:len(value)] == value
+        else:
+            assert theirs[key] == value
+
+
+def test_a_token_cell_arrives_as_files_and_comes_out_correct(checkout):
+    """The program's ``gpt_tiny`` through ``run_cell``: int32 ids in, leaves
+    (`embedding`, `pos_embedding`) the harness's defaults have no rule for,
+    a reference family of its own; no file of the copy was edited."""
+    root, cells = checkout
+    result, err = _drive(root, cells["token"])
+    assert result["correct"] is True and result["failed"] == 0
+    assert all(row["value"] <= row["limit"]
+               for row in result["check"].values())
+    assert "the stack stays on the device" in err
 
 
 def test_the_committed_benchmark_json_has_exactly_the_contracts_keys():
@@ -186,6 +224,13 @@ def test_four_device_cell_is_data_and_reads_every_device(checkout):
 def test_a_broken_timed_path_comes_out_not_correct(checkout, fault):
     root, cells = checkout
     result, _ = _drive(root, cells["krum", "lie", 1], fault=fault)
+    assert result["correct"] is False
+
+
+@pytest.mark.parametrize("fault", ["unchanged", "half_batch"])
+def test_a_broken_token_cell_comes_out_not_correct(checkout, fault):
+    root, cells = checkout
+    result, _ = _drive(root, cells["token"], fault=fault)
     assert result["correct"] is False
 
 
