@@ -184,10 +184,12 @@ def _head_index(named, kernel_ndim):
         raise ValueError(
             "data-plane defense cannot fingerprint an embedding-tied "
             "head: the params carry an nn.Embed table but no top-level "
-            "Dense head (GPT(tied=True) layout) — the output head IS "
-            "the embedding gradient, which every token in the batch "
-            "touches, so no per-class head block exists. Use an untied "
-            "head (tied=False) to run the data-plane defense."
+            "Dense head (the GPT(tied=True) layout, and the lfm2 family "
+            "of models/lfm2.py, whose logits are always its embedding's) "
+            "— the output head IS the embedding gradient, which every "
+            "token in the batch touches, so no per-class head block "
+            "exists. Use an untied head (GPT tied=False) to run the "
+            "data-plane defense; the lfm2 family runs with it off."
         )
     if last_kernel is not None:
         return last_kernel

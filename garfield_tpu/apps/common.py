@@ -659,6 +659,7 @@ def train(args, *, topology, make_trainer_kwargs, num_slots, tag):
     ys = jax.device_put(ys_np, step_fn.batch_sharding)
     key = jax.random.PRNGKey(args.seed)
     state = init_fn(key, xs_np[0, 0])
+    counter_keys = parallel.core.counter_names(state.model_state)
 
     if ckpt is not None and start_iter:
         state = jax.device_put(
@@ -786,6 +787,12 @@ def train(args, *, topology, make_trainer_kwargs, num_slots, tag):
                     loss=float(m_j["loss"]),
                     tap=m_j.get("tap"),
                     step_time_s=timer.last() if args.bench else None,
+                    # The model's counters (core.step_counters), one
+                    # number per module that writes one, beside the loss.
+                    extra={
+                        key: np.asarray(m_j[key], np.float64).tolist()
+                        for key in counter_keys if key in m_j
+                    },
                 )
                 if "attack_mag" in m_j:
                     # Adaptive-controller observability (schema v7): the

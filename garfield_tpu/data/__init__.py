@@ -47,8 +47,12 @@ __all__ = [
 # Reference list (datasets.py:47) + cifar100 (tensorflow_impl tfds names,
 # tensorflow_impl/libs/dataset.py:41-87 accepts any tfds dataset) +
 # copytask (the synthetic token-sequence task the transformer family
-# trains on — no reference counterpart, synthetic BY CONSTRUCTION).
-datasets_list = ["mnist", "cifar10", "cifar100", "pima", "copytask"]
+# trains on — no reference counterpart, synthetic BY CONSTRUCTION) +
+# synthtokens (seeded token sequences labelled with their next token, for
+# the language models of models/lfm2.py).
+datasets_list = [
+    "mnist", "cifar10", "cifar100", "pima", "copytask", "synthtokens",
+]
 
 # Reference normalization constants.
 _MNIST_MEAN, _MNIST_STD = 0.1307, 0.3081  # datasets.py:186-187
@@ -306,6 +310,39 @@ def load_copytask(train_size=None):
     return (tx, ty), make(2048, 4321, False)
 
 
+SYNTHTOKENS_VOCAB = 16384
+SYNTHTOKENS_SEQ = 2048
+
+
+def load_synthtokens(train_size=None):
+    """Seeded token sequences labelled with the next token at every
+    position (the ``next-token`` loss; models/lfm2.py).
+
+    x is (N, ``SYNTHTOKENS_SEQ``) int32 below ``SYNTHTOKENS_VOCAB`` (the
+    vocabulary slice of ``models.num_classes_dict["synthtokens"]``; the
+    length is the one ``lfm2_8b_a1b_ep4`` is benchmarked at), y is x moved
+    one place on. The law is `tokens.sequences`'s — a token repeats the one
+    two places back or is a fresh Zipf-Mandelbrot draw — and the benchmark's
+    cell draws from the same (benchmark/inputs/next_tokens.py). Synthetic
+    by construction, like copytask: no file is read, no warning.
+    """
+    import jax
+
+    from . import tokens
+
+    def make(n, seed):
+        made = np.asarray(tokens.sequences(
+            jax.random.PRNGKey(seed), (n,), SYNTHTOKENS_SEQ + 2,
+            SYNTHTOKENS_VOCAB,
+        ))
+        return made[:, :SYNTHTOKENS_SEQ], made[:, 1:SYNTHTOKENS_SEQ + 1]
+
+    tx, ty = make(1024, 1234)
+    if train_size is not None:
+        tx, ty = tx[:train_size], ty[:train_size]
+    return (tx, ty), make(128, 4321)
+
+
 def load_dataset(name, train_size=None):
     if name == "mnist":
         return load_mnist()
@@ -315,6 +352,8 @@ def load_dataset(name, train_size=None):
         return load_pima(train_size)
     if name == "copytask":
         return load_copytask(train_size)
+    if name == "synthtokens":
+        return load_synthtokens(train_size)
     raise ValueError(f"Existing datasets are: {datasets_list}")
 
 

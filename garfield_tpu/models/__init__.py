@@ -1,10 +1,16 @@
-"""CNN model zoo (counterpart of pytorch_impl/libs/garfieldpp/models/ and the
-torchvision entries in garfieldpp/tools.py:59-105).
+"""Model zoo: the CNNs of pytorch_impl/libs/garfieldpp/models/ and the
+torchvision entries in garfieldpp/tools.py:59-105, the small transformers
+(`transformer.py`) and the LFM2-MoE language models (`lfm2.py`).
 
 All models are flax.linen modules with the signature
-``model(x_nhwc, train: bool)`` and constructor kwargs ``num_classes`` and
+``model(x, train: bool)`` and constructor kwargs ``num_classes`` and
 ``dtype`` (compute dtype; pass jnp.bfloat16 to route convs/matmuls to the
-MXU in bf16 while parameters stay float32).
+MXU in bf16 while parameters stay float32). ``x`` is an NHWC image batch
+for the CNNs and ``vit_tiny``, an int token batch (batch, time) for
+``gpt_tiny`` (one label per sequence) and for the ``lfm2_*`` presets, whose
+logits are (batch, time, vocabulary) and whose loss is ``next-token``
+(``utils.selectors.select_loss``); for them ``num_classes`` is the slice of
+the vocabulary held.
 
 ``select_model(name, dataset)`` mirrors the reference selector: the model
 table (tools.py:66-88) and the dataset->num_classes map (tools.py:89).
@@ -19,6 +25,7 @@ from .dpn import DPN26, DPN92
 from .efficientnet import EfficientNetB0
 from .googlenet import GoogLeNet
 from .lenet import LeNet
+from .lfm2 import lfm2_8b_a1b_ep4, lfm2_moe_tiny
 from .mobilenet import MobileNet
 from .mobilenetv2 import MobileNetV2
 from .nets import CNNet, Cifarnet, Net
@@ -87,9 +94,17 @@ models = {
     # (the copytask sequence dataset).
     "vit_tiny": ViT,
     "gpt_tiny": GPT,
+    # LFM2-MoE language models (models/lfm2.py): int token batches in,
+    # next-token logits out. lfm2_8b_a1b_ep4 is one chip's share of
+    # LFM2-8B-A1B under 4-way expert parallelism (5 of 24 layers, experts 0-7 of
+    # 32, published widths); lfm2_moe_tiny is the CPU tests' size.
+    "lfm2_8b_a1b_ep4": lfm2_8b_a1b_ep4,
+    "lfm2_moe_tiny": lfm2_moe_tiny,
 }
 
-# tools.py:89 (+ the synthetic copytask sequence dataset, data/__init__.py)
+# tools.py:89 (+ the synthetic sequence datasets of data/__init__.py:
+# copytask, one label per sequence; synthtokens, whose "classes" are the
+# slice of the vocabulary its next-token labels are drawn from)
 num_classes_dict = {
     "cifar10": 10,
     "cifar100": 100,
@@ -97,6 +112,7 @@ num_classes_dict = {
     "imagenet": 1000,
     "pima": 1,
     "copytask": 10,
+    "synthtokens": 16384,
 }
 
 

@@ -1,0 +1,405 @@
+"""LFM2-MoE language models (``model_type`` ``lfm2_moe``: LiquidAI's
+LFM2-8B-A1B and its siblings): gated short-convolution and grouped-query
+attention blocks, a dense SwiGLU MLP in the leading layers, a mixture of
+experts in the others, a tied embedding.
+
+The family differs from the CNN zoo in its signature: ``model(tokens, train)``
+takes int token ids (batch, time) and returns logits (batch, time,
+vocabulary) for the ``next-token`` loss (``utils.selectors.select_loss``).
+
+**One chip's share of a layer.** The expert layer is told how many experts
+the model has (``num_experts``: the router's width), which of them this
+chip holds (``experts_held``) and how many a token chooses
+(``experts_per_token``). It routes over all experts and computes the part of
+the result its own experts give; what the absent experts would add is left
+out and the partial result goes on to the next layer. On one chip the layer
+runs without its exchange: nothing here stands in for the absent chips.
+``vocab`` is likewise the slice of the vocabulary held here.
+
+**Dropless.** Every (token, expert) pair whose expert is held is computed,
+whatever the imbalance: the pairs are sorted by expert, those of absent
+experts last, and three ``jax.lax.ragged_dot`` run over the sorted rows with
+the held experts' counts as group sizes. XLA:TPU compiles a ragged dot to a
+grouped matmul that visits only the row tiles its groups cover, so the
+matmul work follows the pairs held (a quarter of 4 x tokens when 8 of 32
+experts are held), not the worst case; the sort, the two permutations and
+the buffers they fill are the static worst case of 4 x tokens rows.
+
+**Precision.** Parameters are float32; ``dtype`` is the compute dtype of
+the matmuls and the residual stream. Norm statistics, the rotary embedding,
+the attention softmax, the router (scores, selection, weights) and the
+logits' consumer (the loss) are float32 whatever ``dtype`` is.
+
+**Scopes** (``jax.named_scope``, always on, metadata only; they nest inside
+``phase.grads`` of `parallel.core`): the vocabulary is ``SCOPES`` below, one
+``model.<name>`` each. **Counters**: an expert layer writes ``moe_pairs_held``
+(pairs computed here) and ``moe_pairs_total`` (experts_per_token x tokens)
+into the collection ``counters_sum`` and ``moe_max_expert_load`` (the
+fullest held expert's pairs) into ``counters_max`` — `parallel.core`'s
+convention for any model's counters, by name as BatchNorm writes
+``batch_stats`` — so they ride in ``model_state``, and a trainer that calls
+``core.step_counters`` (`aggregathor.make_trainer` does) has them in the
+step's ``metrics`` under those names, one entry per expert layer.
+"""
+
+import dataclasses
+import functools
+from typing import Any, Sequence
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+__all__ = [
+    "SCOPES", "COUNTER_SUMS", "COUNTER_MAXES", "scope", "RMSNorm", "ShortConv",
+    "Attention", "SwiGLU", "ExpertLayer", "Sizes", "Block", "Lfm2Moe",
+    "lfm2_8b_a1b_ep4", "lfm2_moe_tiny",
+]
+
+SCOPES = (
+    "embed", "conv_mixer", "attention", "dense_mlp", "moe_router",
+    "moe_dispatch", "moe_experts", "moe_combine", "head_loss",
+)
+# parallel.core's two collections for a model's counters, by name.
+COUNTER_SUMS, COUNTER_MAXES = "counters_sum", "counters_max"
+# Added to the sum of a token's selected scores before it divides them.
+WEIGHT_EPS = 1e-6
+
+_normal = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
+
+
+def scope(name):
+    """``jax.named_scope("model.<name>")`` for a name of ``SCOPES``."""
+    if name not in SCOPES:
+        raise ValueError(f"unknown model scope {name!r}; have {SCOPES}")
+    return jax.named_scope("model." + name)
+
+
+class RMSNorm(nn.Module):
+    """x * scale / sqrt(mean(x^2) + eps), statistics in float32."""
+
+    eps: float = 1e-5
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+        return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(self.dtype)
+
+
+def rotary(x, theta):
+    """Rotary embedding of ``x`` (batch, time, heads, head_dim) in float32,
+    half-rotation convention."""
+    t, d = x.shape[1], x.shape[-1]
+    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None]
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[None, :, None]
+    sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[None, :, None]
+    x = x.astype(jnp.float32)
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+
+def _dense(features, dtype, name):
+    return nn.Dense(features, use_bias=False, dtype=dtype, kernel_init=_normal,
+                    name=name)
+
+
+class ShortConv(nn.Module):
+    """Gated depthwise causal convolution: [B, C, X] = u W_in; z = B * X;
+    y_t = sum_j k_j * z_{t-L+1+j}; out = (C * y) W_out. No bias."""
+
+    length: int = 3
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        hidden = u.shape[-1]
+        b, c, x = jnp.split(
+            _dense(3 * hidden, self.dtype, "in_proj")(u), 3, axis=-1)
+        taps = self.param("conv_kernel", _normal, (self.length, hidden))
+        z = b * x
+        padded = jnp.pad(z, ((0, 0), (self.length - 1, 0), (0, 0)))
+        y = sum(taps[j].astype(self.dtype) * padded[:, j:j + z.shape[1]]
+                for j in range(self.length))
+        return _dense(hidden, self.dtype, "out_proj")(c * y)
+
+
+class Attention(nn.Module):
+    """Grouped-query causal attention with a per-head RMSNorm on q and k and
+    a rotary embedding; each KV head serves heads / kv_heads query heads.
+    Softmax in float32."""
+
+    heads: int
+    kv_heads: int
+    head_dim: int
+    rope_theta: float = 1e6
+    eps: float = 1e-5
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        n, t, hidden = u.shape
+        heads, kv, hd = self.heads, self.kv_heads, self.head_dim
+        q = _dense(heads * hd, self.dtype, "q_proj")(u).reshape(n, t, heads, hd)
+        k = _dense(kv * hd, self.dtype, "k_proj")(u).reshape(n, t, kv, hd)
+        v = _dense(kv * hd, self.dtype, "v_proj")(u).reshape(n, t, kv, hd)
+        q = rotary(RMSNorm(self.eps, jnp.float32, name="q_norm")(q),
+                   self.rope_theta).astype(self.dtype)
+        k = rotary(RMSNorm(self.eps, jnp.float32, name="k_norm")(k),
+                   self.rope_theta).astype(self.dtype)
+        q = q.reshape(n, t, kv, heads // kv, hd)
+        scores = jnp.einsum("nqkgd,nskd->nkgqs", q, k,
+                            preferred_element_type=jnp.float32)
+        scores = scores / jnp.sqrt(jnp.float32(hd))
+        causal = jnp.tril(jnp.ones((t, t), bool))
+        probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+        mixed = jnp.einsum("nkgqs,nskd->nqkgd", probs.astype(self.dtype), v)
+        return _dense(hidden, self.dtype, "o_proj")(
+            mixed.reshape(n, t, heads * hd))
+
+
+class SwiGLU(nn.Module):
+    """W_2 (silu(u W_1) * (u W_3)). No bias."""
+
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        gate = nn.silu(_dense(self.width, self.dtype, "w1")(u))
+        return _dense(u.shape[-1], self.dtype, "w2")(
+            gate * _dense(self.width, self.dtype, "w3")(u))
+
+
+@jax.custom_vjp
+def _permute(x, order, inverse):
+    """``x[order]`` for a permutation ``order`` of the rows whose inverse is
+    ``inverse``: the cotangent goes back by a gather too, not a scatter."""
+    del inverse
+    return x[order]
+
+
+def _permute_fwd(x, order, inverse):
+    return x[order], (order, inverse)
+
+
+def _permute_bwd(res, ct):
+    _, inverse = res
+    return ct[inverse], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+class ExpertLayer(nn.Module):
+    """The part of a mixture-of-experts feed-forward that the experts held
+    here give (module docstring). Scores are sigmoids over all
+    ``num_experts``; the top ``experts_per_token`` of score + bias are
+    chosen (the bias is a constant leaf under ``stop_gradient``: it enters
+    the selection only); a token's weights are its chosen scores over their
+    sum, held here or not, times ``scaling``."""
+
+    num_experts: int
+    experts_held: Sequence[int]
+    experts_per_token: int
+    width: int
+    scaling: float = 1.0
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        hidden, k = u.shape[-1], self.experts_per_token
+        held = len(self.experts_held)
+        x = u.reshape(-1, hidden)
+        tokens = x.shape[0]
+        with scope("moe_router"):
+            kernel = self.param(
+                "router_kernel", _normal, (hidden, self.num_experts))
+            bias = self.param(
+                "expert_bias", nn.initializers.zeros, (self.num_experts,))
+            scores = jax.nn.sigmoid(jnp.matmul(
+                x.astype(jnp.float32), kernel,
+                precision=jax.lax.Precision.HIGHEST))
+            _, chosen = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(bias), k)
+            picked = jnp.take_along_axis(scores, chosen, axis=-1)
+            weights = picked / (
+                jnp.sum(picked, -1, keepdims=True) + WEIGHT_EPS) * self.scaling
+        with scope("moe_dispatch"):
+            # The slot of each chosen expert among those held, ``held`` for
+            # an absent one; pairs sorted by slot, absent pairs last.
+            slot_of = jnp.full((self.num_experts,), held, jnp.int32).at[
+                jnp.asarray(self.experts_held)].set(jnp.arange(held))
+            slots = slot_of[chosen].reshape(-1)
+            order = jnp.argsort(slots, stable=True)
+            inverse = jnp.argsort(order)
+            sizes = jnp.sum(
+                slots[:, None] == jnp.arange(held)[None], axis=0,
+                dtype=jnp.int32)
+            here = (jnp.arange(tokens * k) < jnp.sum(sizes))[:, None]
+            rows = jnp.broadcast_to(
+                x[:, None], (tokens, k, hidden)).reshape(-1, hidden)
+            rows = jnp.where(here, _permute(rows, order, inverse), 0)
+        with scope("moe_experts"):
+            w1 = self.param("w1", _stack_init, (held, hidden, self.width))
+            w3 = self.param("w3", _stack_init, (held, hidden, self.width))
+            w2 = self.param("w2", _stack_init, (held, self.width, hidden))
+            dot = functools.partial(
+                jax.lax.ragged_dot, group_sizes=sizes,
+                preferred_element_type=self.dtype)
+            gate = nn.silu(dot(rows, w1.astype(self.dtype)))
+            out = dot(gate * dot(rows, w3.astype(self.dtype)),
+                      w2.astype(self.dtype))
+        with scope("moe_combine"):
+            # Rows past the last group belong to absent experts: the grouped
+            # matmul leaves them unvisited, so they are set to zero here (and
+            # their cotangent above) and not multiplied by a zero weight.
+            out = _permute(jnp.where(here, out, 0), inverse, order)
+            out = jnp.sum(
+                out.reshape(tokens, k, hidden)
+                * weights[..., None].astype(self.dtype), axis=1)
+        for collection, name, value in (
+                (COUNTER_SUMS, "moe_pairs_held", jnp.sum(sizes)),
+                (COUNTER_SUMS, "moe_pairs_total", tokens * k),
+                (COUNTER_MAXES, "moe_max_expert_load", jnp.max(sizes))):
+            if self.is_mutable_collection(collection):
+                self.variable(
+                    collection, name, lambda: jnp.zeros((), jnp.float32)
+                ).value = jnp.asarray(value, jnp.float32)
+        return out.reshape(u.shape)
+
+
+def _stack_init(key, shape, dtype=jnp.float32):
+    """Expert stacks: the leading axis counts experts and is no fan-in."""
+    return jax.random.normal(key, shape, dtype) / jnp.sqrt(shape[1])
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """What every block of one model shares (hashable: a static field)."""
+
+    heads: int
+    kv_heads: int
+    head_dim: int
+    conv_length: int
+    dense_width: int
+    expert_width: int
+    num_experts: int
+    experts_held: tuple
+    experts_per_token: int
+    scaling: float
+    eps: float
+    rope_theta: float
+
+
+class Block(nn.Module):
+    """h += Op(RMSNorm(h)); h += FF(RMSNorm(h)): ``kind`` names the operator
+    (``conv`` or ``full_attention``), ``moe`` the feed-forward (the expert
+    layer, or the dense MLP)."""
+
+    kind: str
+    cfg: Sizes
+    moe: bool
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.cfg
+        u = RMSNorm(cfg.eps, self.dtype, name="operator_norm")(h)
+        if self.kind == "conv":
+            with scope("conv_mixer"):
+                h = h + ShortConv(cfg.conv_length, self.dtype, name="conv")(u)
+        elif self.kind == "full_attention":
+            with scope("attention"):
+                h = h + Attention(
+                    cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.rope_theta,
+                    cfg.eps, self.dtype, name="attn")(u)
+        else:
+            raise ValueError(f"unknown layer type {self.kind!r}")
+        u = RMSNorm(cfg.eps, self.dtype, name="ffn_norm")(h)
+        if self.moe:
+            return h + ExpertLayer(
+                cfg.num_experts, cfg.experts_held,
+                cfg.experts_per_token, cfg.expert_width, cfg.scaling,
+                self.dtype, name="moe")(u)
+        with scope("dense_mlp"):
+            return h + SwiGLU(cfg.dense_width, self.dtype, name="mlp")(u)
+
+
+class Lfm2Moe(nn.Module):
+    """The model: tied embedding, ``layer_types`` blocks of which the first
+    ``num_dense_layers`` have a dense MLP and the others an expert layer, a
+    final RMSNorm, logits over the ``vocab`` rows held (float32).
+
+    ``num_classes`` is the vocabulary slice (``models.select_model`` passes
+    the dataset's). ``remat`` recomputes each block in the backward pass."""
+
+    num_classes: int = 16384
+    dtype: Any = jnp.float32
+    hidden: int = 2048
+    layer_types: Sequence[str] = ("conv", "full_attention", "conv", "conv",
+                                  "conv")
+    num_dense_layers: int = 1
+    heads: int = 32
+    kv_heads: int = 8
+    head_dim: int = 64
+    conv_length: int = 3
+    dense_width: int = 7168
+    expert_width: int = 1792
+    num_experts: int = 32
+    experts_held: Sequence[int] = tuple(range(8))
+    experts_per_token: int = 4
+    scaling: float = 1.0
+    eps: float = 1e-5
+    rope_theta: float = 1e6
+    remat: bool = False
+
+    def sizes(self):
+        """What the blocks share, as their static field."""
+        return Sizes(**{
+            f.name: getattr(self, f.name) for f in dataclasses.fields(Sizes)
+        } | {"experts_held": tuple(self.experts_held)})
+
+    @nn.compact
+    def __call__(self, tokens, train=False):
+        del train  # no dropout, no batch statistics
+        with scope("embed"):
+            table = nn.Embed(
+                self.num_classes, self.hidden, dtype=self.dtype,
+                embedding_init=nn.initializers.normal(self.hidden ** -0.5),
+                name="embed")
+            h = table(tokens)
+        block = nn.remat(Block) if self.remat else Block
+        for i, kind in enumerate(self.layer_types):
+            h = block(kind, self.sizes(), i >= self.num_dense_layers,
+                      self.dtype, name=f"layer_{i}")(h)
+        with scope("head_loss"):
+            h = RMSNorm(self.eps, self.dtype, name="final_norm")(h)
+            return table.attend(h).astype(jnp.float32)
+
+
+def lfm2_8b_a1b_ep4(num_classes=16384, dtype=jnp.float32):
+    """One chip's share of LFM2-8B-A1B where 4 chips share each layer by
+    expert parallelism: published layers 1 and 3-6 (one leading dense layer
+    and the first whole period of expert layers), experts 0-7 of 32, every width as
+    published; ``num_classes`` is the vocabulary slice (16,384 of 65,536).
+    Each block is recomputed in the backward pass: without that, 4 workers'
+    16,384 tokens a step beside a 4 x 508M gradient stack do not fit one
+    v5e (16.3 GiB of 15.75 at compile, PERF.md section 4)."""
+    return Lfm2Moe(num_classes=num_classes, dtype=dtype, remat=True)
+
+
+def lfm2_moe_tiny(num_classes=64, dtype=jnp.float32, experts_held=(0, 1),
+                  **fields):
+    """The family at a size the CPU tests hold: hidden 64, 8 experts of
+    which ``experts_held`` are here, top-2."""
+    sizes = dict(
+        hidden=64, layer_types=("conv", "full_attention", "conv"),
+        num_dense_layers=1, heads=4, kv_heads=2, head_dim=16, dense_width=96,
+        expert_width=48, num_experts=8, experts_held=tuple(experts_held),
+        experts_per_token=2)
+    sizes.update(fields)
+    return Lfm2Moe(num_classes=num_classes, dtype=dtype, **sizes)

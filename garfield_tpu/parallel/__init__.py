@@ -88,7 +88,11 @@ class EvalSet:
         if self.binary:
             pred = (logits.reshape(-1) > 0.5).astype(y.dtype)
             return jnp.sum(pred == y).astype(jnp.int32)
-        return jnp.sum(logits.argmax(-1) == y).astype(jnp.int32)
+        # Labels are kept flat: one a sample, or one a position for a
+        # next-token model's (batch, time, vocabulary) logits.
+        return jnp.sum(
+            logits.argmax(-1).reshape(-1) == y
+        ).astype(jnp.int32)
 
     def counts(self, state, eval_fn):
         """(correct device scalar, total) — no host sync."""
@@ -136,7 +140,7 @@ def _accuracy_counts(state, eval_fn, test_batches, *, binary=False):
             pred = (logits.reshape(-1) > 0.5).astype(yj.dtype)
             correct = correct + jnp.sum(pred == yj)
         else:
-            correct = correct + jnp.sum(logits.argmax(-1) == yj)
+            correct = correct + jnp.sum(logits.argmax(-1).reshape(-1) == yj)
         total += int(y_np.shape[0])
     return correct, total
 

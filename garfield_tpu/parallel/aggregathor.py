@@ -1077,6 +1077,10 @@ def make_trainer(
                 wire_state=new_wire,
             )
         metrics = {"loss": mean_loss}
+        # The counters of a model that keeps some (core.COUNTER_SUMS /
+        # COUNTER_MAXES), under their own names; {} for every other model,
+        # which traces nothing.
+        metrics.update(core.step_counters(ms_local, axis))
         if wire_ef:
             # Per-rank EF residual L2 norms — the in-graph twin of the
             # wire event's ef_residual_norm field (schema v11).

@@ -21,6 +21,8 @@ Counterpart of the reference's node-role machinery, re-designed for SPMD:
     over RPC).
 """
 
+import re
+
 import flax.struct
 import jax
 import jax.numpy as jnp
@@ -36,6 +38,10 @@ __all__ = [
     "unflatten_like",
     "subset_indices",
     "mean_model_state",
+    "COUNTER_SUMS",
+    "COUNTER_MAXES",
+    "counter_names",
+    "step_counters",
     "default_byz_mask",
 ]
 
@@ -526,6 +532,64 @@ def mean_model_state(stacked_ms, axis_name=None):
     if axis_name is not None:
         ms = jax.tree.map(lambda l: jax.lax.pmean(l, axis_name), ms)
     return ms
+
+
+# A model's counters of one forward pass (pairs an expert layer computed, its
+# fullest expert's load, ...): float32 scalars a module writes into one of
+# these two flax collections, by name as BatchNorm writes ``batch_stats``.
+# They ride in ``model_state`` per slot; a counter is called what its
+# variable is called, and `step_counters` adds it up over the workers or
+# takes their maximum, by the collection it is in.
+COUNTER_SUMS, COUNTER_MAXES = "counters_sum", "counters_max"
+
+
+def _counters(model_state, collection):
+    """``{name: [leaf, ...]}`` of a counters collection, the modules that
+    write one name in the natural order of their paths (layer_2 before
+    layer_10)."""
+
+    def natural(item):
+        return [
+            (0, int(t), "") if t.isdigit() else (1, 0, t)
+            for k in item[0] for t in re.split(r"(\d+)", str(k.key))
+        ]
+
+    by_name = {}
+    for path, leaf in sorted(
+        jax.tree_util.tree_flatten_with_path(
+            model_state.get(collection, {}))[0],
+        key=natural,
+    ):
+        by_name.setdefault(str(path[-1].key), []).append(leaf)
+    return by_name
+
+
+def counter_names(model_state):
+    """The names `step_counters` gives for a model state of this structure."""
+    return sorted(
+        name for c in (COUNTER_SUMS, COUNTER_MAXES)
+        for name in _counters(model_state, c)
+    )
+
+
+def step_counters(stacked_ms, axis_name=None):
+    """The step's counters from the per-slot model state (a leading slot
+    axis per leaf), for the step's ``metrics``; ``{}``, and nothing traced,
+    for a model that keeps none. ``{name: (writers,) float32}``: a counter
+    of ``COUNTER_SUMS`` summed over the slots (and over ``axis_name``), one
+    of ``COUNTER_MAXES`` their maximum, one entry per module that writes
+    the name."""
+    out = {}
+    for collection, over_slots, over_axis in (
+        (COUNTER_SUMS, jnp.sum, jax.lax.psum),
+        (COUNTER_MAXES, jnp.max, jax.lax.pmax),
+    ):
+        for name, leaves in _counters(stacked_ms, collection).items():
+            value = jnp.stack([over_slots(l, axis=0) for l in leaves])
+            out[name] = (
+                value if axis_name is None else over_axis(value, axis_name)
+            )
+    return out
 
 
 def default_byz_mask(n, f):

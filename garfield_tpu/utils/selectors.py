@@ -18,7 +18,9 @@ def select_loss(name):
     ``crossentropy`` (expects raw logits), ``bce`` / ``binary-cross-entropy``
     (expects a *probability* per example like torch nn.BCELoss — the pima
     model ends in sigmoid), ``bce-logits`` / ``bce-with-logits`` (expects a
-    single raw logit per example).
+    single raw logit per example), ``next-token`` (raw logits (batch,
+    time, vocabulary) against the next token at every position (batch,
+    time): the mean softmax cross-entropy over all positions, in float32).
     """
     name = name.lower()
     if name == "nll":
@@ -48,8 +50,15 @@ def select_loss(name):
                 optax.sigmoid_binary_cross_entropy(logits, labels.astype(logits.dtype))
             )
         return bce_logits
+    if name in ("next-token", "next_token"):
+        def next_token(logits, labels):
+            return jnp.mean(optax.softmax_cross_entropy_with_integer_labels(
+                logits.astype(jnp.float32), labels
+            ))
+        return next_token
     raise ValueError(
-        f"unknown loss {name!r}; available: nll, cross-entropy, bce, bce-logits"
+        f"unknown loss {name!r}; available: nll, cross-entropy, bce, "
+        f"bce-logits, next-token"
     )
 
 

@@ -1,0 +1,491 @@
+"""The LFM2-MoE family (models/lfm2.py) against its plain reference.
+
+The reference is the benchmark's own file, imported by path
+(benchmark/references/lfm2_moe.py): what these tests hold the program to
+and what decides a benchmark cell's `correct` cannot drift apart. Float32,
+``lfm2_moe_tiny``, weights made from the seed by the benchmark's
+`weights.make_params` over the reference's ``param_shapes``.
+"""
+
+import importlib.util
+import pathlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from garfield_tpu import models
+from garfield_tpu.aggregators import dataplane
+from garfield_tpu.models import lfm2
+from garfield_tpu.parallel import aggregathor, core, make_mesh
+from garfield_tpu.utils import selectors
+
+BENCH = pathlib.Path(__file__).resolve().parents[1] / "benchmark"
+
+
+def _by_path(name, path):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+ref = _by_path("_lfm2_reference", BENCH / "references/lfm2_moe.py")
+ref_loss = _by_path(
+    "_next_token_reference", BENCH / "references/losses/next_token.py").loss
+weights = _by_path("_bench_weights", BENCH / "harness/weights.py")
+
+VOCAB, SEQ = 64, 16
+# The two collections a model's counters live in: the model writes them by
+# name, the trainers read them by core's.
+COUNTERS = (core.COUNTER_SUMS, core.COUNTER_MAXES)
+assert COUNTERS == (lfm2.COUNTER_SUMS, lfm2.COUNTER_MAXES)
+
+
+def _model(layer_types=("conv", "full_attention", "conv"), dense=1,
+           held=(0, 1), published=8, top=2):
+    """The reference's ``model`` group at the tiny preset's sizes."""
+    return {
+        "family": "lfm2_moe", "hidden_size": 64, "intermediate_size": 96,
+        "moe_intermediate_size": 48, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": 16, "conv_L_cache": 3,
+        "norm_eps": 1e-5, "rope_theta": 1e6,
+        "layer_types": list(layer_types), "num_dense_layers": dense,
+        "num_experts_published": published, "experts_held": list(held),
+        "num_experts_per_tok": top, "routed_scaling_factor": 1,
+        "vocab_size": VOCAB, "seq_len": SEQ,
+    }
+
+
+def _module(model):
+    return lfm2.lfm2_moe_tiny(
+        num_classes=model["vocab_size"],
+        experts_held=tuple(model["experts_held"]),
+        layer_types=tuple(model["layer_types"]),
+        num_dense_layers=model["num_dense_layers"],
+        num_experts=model["num_experts_published"],
+        experts_per_token=model["num_experts_per_tok"])
+
+
+def _paths(tree):
+    return {
+        "/".join(str(k.key) for k in path): leaf
+        for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def _tokens(seed=0, batch=3):
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
+    return (jax.random.randint(k1, (batch, SEQ), 0, VOCAB),
+            jax.random.randint(k2, (batch, SEQ), 0, VOCAB))
+
+
+def _setup(model, seed=5):
+    """``(module, variables, flat reference weights)`` with the program's
+    parameters set to the reference's, leaf by leaf by path."""
+    module = _module(model)
+    variables = dict(module.init(jax.random.PRNGKey(0), _tokens()[0]))
+    shapes = ref.param_shapes(model)
+    have = {p: v.shape for p, v in _paths(variables["params"]).items()}
+    assert have == {p: tuple(s) for p, s in shapes.items()}
+    made = weights.make_params(
+        jax.random.PRNGKey(seed), shapes, ref.init_scales(model),
+        ref.leaf_rules(model))
+    variables["params"] = jax.tree.unflatten(
+        jax.tree.structure(variables["params"]), [made[p] for p in have])
+    return module, variables, made
+
+
+def _apply(module, variables, x):
+    return module.apply(variables, x, mutable=list(COUNTERS))
+
+
+BLOCKS = {
+    "conv": dict(layer_types=("conv",), dense=1),
+    "attention": dict(layer_types=("full_attention",), dense=1),
+    "dense": dict(layer_types=("conv",), dense=1),
+    "expert": dict(layer_types=("conv",), dense=0),
+    "whole": dict(),
+}
+
+
+@pytest.mark.parametrize("block", list(BLOCKS))
+def test_logits_and_gradient_equal_the_reference(block):
+    """Each kind of block alone (one layer between embedding and head), and
+    the whole tiny model: logits and ``jax.grad`` of the next-token loss."""
+    model = _model(**BLOCKS[block])
+    module, variables, made = _setup(model)
+    x, y = _tokens()
+    loss_fn = selectors.select_loss("next-token")
+
+    def program(params):
+        logits, _ = _apply(module, {**variables, "params": params}, x)
+        return loss_fn(logits, y), logits
+
+    (loss, logits), grads = jax.value_and_grad(program, has_aux=True)(
+        variables["params"])
+    with jax.default_matmul_precision("highest"):
+        want_logits = ref.forward(made, x, model)
+        want_loss, want = jax.value_and_grad(
+            lambda p: ref_loss(ref.forward(p, x, model), y))(made)
+    assert logits.dtype == jnp.float32 and logits.shape == (3, SEQ, VOCAB)
+    np.testing.assert_allclose(logits, want_logits, atol=2e-5)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
+    for path, grad in _paths(grads).items():
+        np.testing.assert_allclose(
+            grad, want[path], atol=2e-5 * max(1.0, float(
+                jnp.linalg.norm(want[path]))), err_msg=path)
+    if block == "expert":
+        assert float(jnp.linalg.norm(want["layer_0/moe/w1"])) > 0
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """4 shares of 2 experts of 8, with what every chip computes alike (the
+    operator's output and the residual stream) counted once, equal the
+    reference's layer that holds all 8."""
+    uncut = _model(layer_types=("conv",), dense=0, held=range(8))
+    made = weights.make_params(
+        jax.random.PRNGKey(7), ref.param_shapes(uncut),
+        ref.init_scales(uncut), ref.leaf_rules(uncut))
+    h = jax.random.normal(jax.random.PRNGKey(8), (2, SEQ, 64))
+    with jax.default_matmul_precision("highest"):
+        want = ref.block(made, 0, h, uncut, lambda t: t)
+        u = ref.rms_norm(h, made["layer_0/operator_norm/scale"], 1e-5)
+        alike = h + ref.conv_operator(made, "layer_0", u, uncut, lambda t: t)
+
+    def share(held):
+        held = jnp.asarray(held)
+        params = {
+            "operator_norm": {"scale": made["layer_0/operator_norm/scale"]},
+            "ffn_norm": {"scale": made["layer_0/ffn_norm/scale"]},
+            "conv": {
+                "in_proj": {"kernel": made["layer_0/conv/in_proj/kernel"]},
+                "conv_kernel": made["layer_0/conv/conv_kernel"],
+                "out_proj": {"kernel": made["layer_0/conv/out_proj/kernel"]}},
+            "moe": {
+                "router_kernel": made["layer_0/moe/router_kernel"],
+                "expert_bias": made["layer_0/moe/expert_bias"],
+                **{w: made[f"layer_0/moe/{w}"][held]
+                   for w in ("w1", "w2", "w3")}},
+        }
+        sizes = _module(dict(uncut, experts_held=held.tolist())).sizes()
+        return lfm2.Block("conv", sizes, True).apply({"params": params}, h)
+
+    shares = [share([2 * c, 2 * c + 1]) for c in range(4)]
+    np.testing.assert_allclose(
+        alike + sum(s - alike for s in shares), want, atol=2e-5)
+    # No share alone is the layer: each adds its own experts' part.
+    assert float(jnp.abs(shares[0] - want).max()) > 1e-3
+
+
+def _expert_layer(held, published=8, top=2):
+    return lfm2.ExpertLayer(published, tuple(held), top, 48)
+
+
+def _expert_params(seed=3, held=2, published=8):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return {
+        "router_kernel": jax.random.normal(keys[0], (64, published)) / 8,
+        "expert_bias": jnp.zeros((published,)),
+        "w1": jax.random.normal(keys[1], (held, 64, 48)) / 8,
+        "w3": jax.random.normal(keys[2], (held, 64, 48)) / 8,
+        "w2": jax.random.normal(keys[3], (held, 48, 64)) / 7,
+    }
+
+
+def test_the_bias_moves_the_selection_and_not_the_weights():
+    model = _model(held=(0, 1))
+    params = _expert_params()
+    u = jax.random.normal(jax.random.PRNGKey(1), (1, SEQ, 64))
+    chosen, w = ref.route(u, params["router_kernel"], params["expert_bias"],
+                          model)
+    bias = jnp.zeros((8,)).at[5].set(10.0)  # expert 5 wins every token
+    chosen_b, w_b = ref.route(u, params["router_kernel"], bias, model)
+    assert bool(jnp.all(jnp.any(chosen_b == 5, -1)))
+    assert not bool(jnp.all(jnp.any(chosen == 5, -1)))
+    # The weights are the scores' alone: each row's sum is its chosen
+    # scores over themselves, 1 up to the 1e-6, bias or no bias.
+    np.testing.assert_allclose(jnp.sum(w_b, -1), 1.0, atol=1e-4)
+    scores = jax.nn.sigmoid(u @ params["router_kernel"])
+    np.testing.assert_allclose(
+        w_b, jnp.take_along_axis(scores, chosen_b, -1) / (
+            jnp.take_along_axis(scores, chosen_b, -1).sum(-1, keepdims=True)
+            + 1e-6), atol=1e-6)
+    # The program: the same output as the reference under that bias, and no
+    # gradient reaches the bias.
+    layer = _expert_layer((0, 1))
+    biased = {**params, "expert_bias": bias}
+    out = layer.apply({"params": biased}, u)
+    with jax.default_matmul_precision("highest"):
+        want = ref.expert_ff(
+            {f"l/moe/{k}": v for k, v in biased.items()}, "l", u, model,
+            lambda t: t)
+    np.testing.assert_allclose(out, want, atol=2e-5)
+    grad = jax.grad(lambda p: jnp.sum(layer.apply({"params": p}, u) ** 2))(
+        biased)
+    assert float(jnp.abs(grad["expert_bias"]).max()) == 0.0
+    assert float(jnp.abs(grad["router_kernel"]).max()) > 0.0
+
+
+def test_no_token_is_dropped_when_every_token_chooses_one_held_expert():
+    """All 48 tokens choose expert 0 (held) and expert 7 (absent): one
+    expert carries every pair, far above any even share, and each token's
+    output is its weight times that expert's MLP."""
+    params = _expert_params()
+    bias = jnp.zeros((8,)).at[0].set(10.0).at[7].set(10.0)
+    params = {**params, "expert_bias": bias}
+    u = jax.random.normal(jax.random.PRNGKey(2), (3, SEQ, 64))
+    layer = _expert_layer((0, 1))
+    out, state = layer.apply(
+        {"params": params, **{c: {} for c in COUNTERS}}, u,
+        mutable=list(COUNTERS))
+    sums, maxes = (state[c] for c in COUNTERS)
+    assert float(sums["moe_pairs_held"]) == 3 * SEQ
+    assert float(maxes["moe_max_expert_load"]) == 3 * SEQ
+    assert float(sums["moe_pairs_total"]) == 2 * 3 * SEQ
+    scores = jax.nn.sigmoid(u @ params["router_kernel"])
+    weight = scores[..., 0] / (scores[..., 0] + scores[..., 7] + 1e-6)
+    mlp = (jax.nn.silu(u @ params["w1"][0]) * (u @ params["w3"][0])
+           ) @ params["w2"][0]
+    np.testing.assert_allclose(out, weight[..., None] * mlp, atol=2e-5)
+    assert float(jnp.abs(out).min(-1).max()) > 0  # no token left at zero
+
+
+def test_the_expert_output_is_zero_when_every_choice_is_absent():
+    params = _expert_params()
+    bias = jnp.zeros((8,)).at[6].set(10.0).at[7].set(10.0)
+    u = jax.random.normal(jax.random.PRNGKey(2), (2, SEQ, 64))
+    layer = _expert_layer((0, 1))
+    fn = lambda p: layer.apply({"params": p}, u)
+    out = fn({**params, "expert_bias": bias})
+    assert float(jnp.abs(out).max()) == 0.0
+    grads = jax.grad(lambda p: jnp.sum(fn(p)))({**params, "expert_bias": bias})
+    assert all(bool(jnp.all(jnp.isfinite(g))) for g in jax.tree.leaves(grads))
+    assert float(jnp.abs(grads["w1"]).max()) == 0.0
+
+
+@pytest.mark.parametrize("kind", ["conv", "full_attention"])
+def test_neither_operator_looks_ahead(kind):
+    """Changing the tokens after position t leaves the logits up to t as
+    they were."""
+    model = _model(layer_types=(kind,), dense=1)
+    module, variables, _ = _setup(model)
+    x, _ = _tokens()
+    later = x.at[:, 9:].set((x[:, 9:] + 1) % VOCAB)
+    a, _ = _apply(module, variables, x)
+    b, _ = _apply(module, variables, later)
+    np.testing.assert_array_equal(a[:, :9], b[:, :9])
+    assert float(jnp.abs(a[:, 9:] - b[:, 9:]).max()) > 0
+
+
+def test_the_counters_equal_the_references_count():
+    model = _model()
+    module, variables, made = _setup(model)
+    x, _ = _tokens(seed=4)
+    _, state = _apply(module, variables, x)
+    with jax.default_matmul_precision("highest"):
+        h = made["embed/embedding"][x]
+        for i in range(len(model["layer_types"])):
+            if i >= model["num_dense_layers"]:
+                mid = _after_operator(made, i, h, model)
+                chosen, _ = ref.route(
+                    mid, made[f"layer_{i}/moe/router_kernel"],
+                    made[f"layer_{i}/moe/expert_bias"], model)
+                held = ref.pairs_held(chosen, model)
+                sums, maxes = (
+                    state[c][f"layer_{i}"]["moe"] for c in COUNTERS)
+                assert float(sums["moe_pairs_held"]) == float(held.sum())
+                assert float(maxes["moe_max_expert_load"]) == float(held.max())
+                assert float(sums["moe_pairs_total"]) == x.size * 2
+            h = ref.block(made, i, h, model, lambda t: t)
+
+
+def _after_operator(made, i, h, model):
+    """The normed input of layer i's feed-forward, by the reference."""
+    p, eps = f"layer_{i}", model["norm_eps"]
+    u = ref.rms_norm(h, made[f"{p}/operator_norm/scale"], eps)
+    op = ref.conv_operator if model["layer_types"][i] == "conv" else (
+        ref.attention_operator)
+    mid = h + op(made, p, u, model, lambda t: t)
+    return ref.rms_norm(mid, made[f"{p}/ffn_norm/scale"], eps)
+
+
+def _trainer(module, **kwargs):
+    return aggregathor.make_trainer(
+        module, selectors.select_loss("next-token"),
+        selectors.select_optimizer("sgd", lr=0.05, momentum=0.9,
+                                   weight_decay=5e-4),
+        "median", num_workers=4, f=1, attack="lie",
+        # One device holds the 4 slots, as the chip does: the unroll.
+        mesh=make_mesh({"workers": 1}, devices=jax.devices()[:1]), **kwargs)
+
+
+def _worker_batches(seed=0):
+    k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
+    return (jax.random.randint(k1, (4, 2, SEQ), 0, VOCAB),
+            jax.random.randint(k2, (4, 2, SEQ), 0, VOCAB))
+
+
+@pytest.fixture
+def metadata_in_cache_key():
+    name = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, name)
+    jax.config.update(name, True)
+    yield
+    jax.config.update(name, before)
+
+
+def test_the_model_scopes_stand_inside_the_gradient_phase(
+        metadata_in_cache_key):
+    module = models.select_model("lfm2_moe_tiny", "synthtokens")
+    init_fn, step_fn, _ = _trainer(module)
+    x, y = _worker_batches()
+    state = init_fn(jax.random.PRNGKey(0), x[0])
+    text = step_fn.lower(state, x, y).compile().as_text()
+    for name in lfm2.SCOPES:
+        assert f"model.{name}" in text, name
+    # Wherever an instruction names a model scope, phase.grads stands
+    # outside it.
+    for op_name in re.findall(r'op_name="([^"]*model\.[^"]*)"', text):
+        for part in op_name.split(";"):
+            if "model." in part:
+                assert part.index("phase.grads") < part.index("model."), part
+    with pytest.raises(ValueError, match="nonsense"):
+        lfm2.scope("nonsense")
+
+
+def test_three_trainer_steps_equal_slot_by_slot_gradients(monkeypatch):
+    """aggregathor (n = 4, f = 1, median under lie): the unroll over the 4
+    slots against the same gradients taken one slot after another
+    (``lax.map``, what ``vmap`` computes), three steps; the step's metrics
+    carry the expert layers' counters. ``vmap`` itself cannot take this
+    family yet: jax 0.9.0 has no batching rule for a ragged dot whose group
+    sizes are batched, which is what more than ``UNROLL_MAX_SLOTS`` slots a
+    shard would ask for."""
+    module = _module(_model())
+    x, y = _worker_batches()
+
+    def three_steps():
+        init_fn, step_fn, _ = _trainer(module)
+        state = init_fn(jax.random.PRNGKey(0), x[0])
+        out = []
+        for i in range(3):
+            state, metrics = step_fn(state, jnp.roll(x, i, 0), jnp.roll(y, i, 0))
+            out.append(metrics)
+        return state, out
+
+    unrolled, metrics = three_steps()
+
+    def slot_by_slot(grad_fn, params, ms, xs, ys, keys, **_):
+        with core.phase("grads"):
+            return jax.lax.map(
+                lambda a: grad_fn(params, ms, *a), (xs, ys, keys))
+
+    monkeypatch.setattr(core, "per_slot_grads", slot_by_slot)
+    mapped, metrics_m = three_steps()
+    for a, b in zip(jax.tree.leaves(unrolled.params),
+                    jax.tree.leaves(mapped.params)):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+    for m, mm in zip(metrics, metrics_m):
+        np.testing.assert_allclose(m["loss"], mm["loss"], rtol=1e-5)
+        assert m["moe_pairs_total"].tolist() == [4 * 2 * SEQ * 2] * 2
+        assert m["moe_pairs_held"].shape == (2,)
+        assert bool(jnp.all(m["moe_pairs_held"] <= m["moe_pairs_total"]))
+        assert bool(jnp.all(m["moe_max_expert_load"] <= 2 * SEQ * 2))
+        np.testing.assert_array_equal(
+            m["moe_pairs_held"], mm["moe_pairs_held"])
+    monkeypatch.undo()
+    monkeypatch.setattr(core, "UNROLL_MAX_SLOTS", 1)
+    with pytest.raises(NotImplementedError, match="ragged_dot vmap"):
+        three_steps()
+
+
+def test_the_next_token_loss_is_the_mean_over_all_positions():
+    logits = jax.random.normal(jax.random.PRNGKey(0), (2, 5, 7), jnp.bfloat16)
+    labels = jax.random.randint(jax.random.PRNGKey(1), (2, 5), 0, 7)
+    loss = selectors.select_loss("next-token")(logits, labels)
+    assert loss.dtype == jnp.float32
+    np.testing.assert_allclose(loss, ref_loss(logits, labels), rtol=1e-6)
+    with pytest.raises(ValueError, match="next-token"):
+        selectors.select_loss("no-such-loss")
+
+
+def test_the_cli_and_the_benchmark_draw_the_same_tokens_by_the_stated_law():
+    """`data/tokens.py` and the benchmark's copy (its reference imports
+    nothing of the program) give the same tokens from the same key; the
+    dataset is x and x moved one place on; the ids follow the
+    Zipf-Mandelbrot law both state, copies and all."""
+    from garfield_tpu import data
+    from garfield_tpu.data import tokens
+
+    bench = _by_path("_bench_next_tokens", BENCH / "inputs/next_tokens.py")
+    assert (bench.COPY, bench.ZIPF_ALPHA, bench.ZIPF_BETA) == (
+        tokens.COPY, tokens.ZIPF_ALPHA, tokens.ZIPF_BETA) == (0.5, 1.0, 2.7)
+    key = jax.random.PRNGKey(7)
+    np.testing.assert_array_equal(
+        tokens.sequences(key, (2, 3), 66, 512),
+        bench.sequences(key, (2, 3), 66, 512))
+    (tx, ty), (ex, ey) = data.load_dataset("synthtokens", 64)
+    assert tx.shape == ty.shape == (64, data.SYNTHTOKENS_SEQ)
+    assert ex.shape[1] == 2048 and tx.dtype == np.int32
+    np.testing.assert_array_equal(tx[:, 1:], ty[:, :-1])
+    assert 0 <= tx.min() and ex.max() < data.SYNTHTOKENS_VOCAB == 16384
+    # 278,528 tokens: the first id is 3.17% of them, the first hundred
+    # 40.6%; half of all tokens repeat the one two places back.
+    share = np.bincount(ex.ravel(), minlength=16384) / ex.size
+    law = np.diff(np.asarray(tokens.unigram_cdf(16384)), prepend=0)
+    np.testing.assert_allclose(share[:3], law[:3], rtol=0.1)
+    np.testing.assert_allclose(share[:100].sum(), law[:100].sum(), rtol=0.02)
+    np.testing.assert_allclose(law[0], 1 / 3.7 / (1 / (
+        np.arange(1, 16385) + 2.7)).sum(), rtol=1e-4)
+    assert abs((ex[:, 2:] == ex[:, :-2]).mean() - 0.5) < 0.02
+
+
+def test_a_models_counters_reach_the_metrics_by_cores_convention():
+    """`core.step_counters` knows no model: scalars in ``counters_sum`` are
+    summed over the slots, those in ``counters_max`` maxed, one entry per
+    module that writes the name, in the natural order of the paths; a model
+    state without the collections gives nothing."""
+    slots = jnp.arange(3.0)
+    ms = {
+        "batch_stats": {"bn": {"mean": jnp.ones((3, 4))}},
+        core.COUNTER_SUMS: {
+            f"layer_{i}": {"moe": {"seen": slots + i}} for i in (10, 2)},
+        core.COUNTER_MAXES: {"layer_2": {"moe": {"peak": slots * 2}}},
+    }
+    got = core.step_counters(ms)
+    assert sorted(got) == core.counter_names(ms) == ["peak", "seen"]
+    assert got["seen"].tolist() == [3 + 3 * 2, 3 + 3 * 10]
+    assert got["peak"].tolist() == [4.0]
+    assert core.step_counters({"batch_stats": ms["batch_stats"]}) == {}
+    assert core.counter_names({}) == []
+
+
+def test_the_presets_and_the_token_dataset_are_registered():
+    module = models.select_model("lfm2_8b_a1b_ep4", "synthtokens")
+    assert module.num_classes == models.num_classes_dict["synthtokens"] == 16384
+    assert (module.hidden, module.heads, module.kv_heads, module.head_dim,
+            module.dense_width, module.expert_width, module.num_experts,
+            module.experts_per_token, module.conv_length) == (
+                2048, 32, 8, 64, 7168, 1792, 32, 4, 3)
+    assert tuple(module.experts_held) == tuple(range(8)) and module.remat
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32)))["params"]
+    assert sum(int(np.prod(v.shape)) for v in jax.tree.leaves(shapes)) == (
+        507820288)
+
+
+def test_the_data_plane_defense_refuses_the_tied_family_by_name():
+    """The family's head is its embedding: the data-plane defense refuses it
+    and says so; with the defense off nothing asks."""
+    module = _module(_model())
+    params = module.init(jax.random.PRNGKey(0), _tokens()[0])["params"]
+    with pytest.raises(ValueError, match="embedding-tied.*lfm2"):
+        dataplane.head_spec(params)
+    x, y = _worker_batches()
+    init_fn, step_fn, _ = _trainer(
+        module, defense={"weighted": False, "data": {}})
+    with pytest.raises(ValueError, match="embedding-tied"):
+        step_fn(init_fn(jax.random.PRNGKey(0), x[0]), x, y)

@@ -24,10 +24,12 @@ def test_registry_covers_reference_names():
 
 def test_num_classes_dict_parity():
     # garfieldpp/tools.py:89 — plus copytask, the token-sequence task
-    # behind the transformer family (no reference counterpart).
+    # behind the transformer family, and synthtokens, whose "classes" are
+    # the vocabulary slice of the lfm2 family's next-token labels (no
+    # reference counterpart for either).
     assert models.num_classes_dict == {
         "cifar10": 10, "cifar100": 100, "mnist": 10, "imagenet": 1000, "pima": 1,
-        "copytask": 10,
+        "copytask": 10, "synthtokens": 16384,
     }
 
 
