@@ -61,12 +61,15 @@ class GAR:
         # materializes ``W @ poisoned_stack`` as a stacked tree for any
         # (r, n) weight matrix — phase-2-style reductions then run on it.
         self.fold_aggregate = fold_aggregate
-        # Folded form for coordinate-wise rules (median, tmean):
-        # ``tree_aggregate_ext(ext_tree, row_map, row_scale, **params)``
-        # aggregates the EXTENDED stacked tree (raw rows + the attack's
-        # shared fake row) under a STATIC row remap/scale — the Pallas
-        # kernels apply the remap in-register (ops.coordinate_median's
-        # row_map/row_scale), so the poisoned stack never materializes.
+        # Folded form for coordinate-wise rules (median, tmean, condense):
+        # ``tree_aggregate_ext(stacked_tree, extra_tree, row_map,
+        # row_scale, **params)`` aggregates the raw stacked tree and,
+        # APART, the attack's shared fake row (a tree of row shapes, or
+        # None: row n of ``row_map``) under a STATIC row remap/scale — the
+        # Pallas kernels take the fake row as a second operand and apply
+        # the remap in-register (ops.coordinate_median's extra/row_map/
+        # row_scale), so neither the poisoned nor the extended stack ever
+        # materializes.
         self.tree_aggregate_ext = tree_aggregate_ext
         # Folded form for iterative row-value rules (cclip): ``
         # fold_flat_aggregate(ext_stack, row_map, row_scale, f, **params)``

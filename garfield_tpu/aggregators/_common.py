@@ -126,17 +126,26 @@ def tree_weighted_sum(grads_tree, w):
     return jax.tree.map(one, grads_tree)
 
 
-def tree_coordinatewise(fn, stacked_tree):
-    """Apply a coordinate-wise ``(n, d) -> (d,)`` reducer per LEAF of a
-    stacked gradient tree — the shared plumbing of the tree-mode twins
-    (median, tmean, cclip's center init): coordinate-wise rules decompose
-    per leaf, so the (n, d) flat stack never materializes (PERF.md:
-    21.3 -> 16.2 ms/step for the median aggregathor step on the chip)."""
+def tree_coordinatewise(fn, stacked_tree, extra_tree=None, *, name):
+    """Apply a coordinate-wise ``(n,) + shape -> shape`` reducer per LEAF of
+    a stacked gradient tree — the shared plumbing of the tree-mode twins
+    (median, tmean, condense, cclip's center init): coordinate-wise rules
+    decompose per leaf, so the (n, d) flat stack never materializes
+    (PERF.md: 21.3 -> 16.2 ms/step for the median aggregathor step on the
+    chip). Each leaf goes to ``fn`` in its own shape — ``ops.coordinate``
+    picks the view that costs no copy — and, where ``extra_tree`` holds a
+    folded attack's fake row, as ``fn(leaf, extra_leaf)``. ``name`` says
+    which rule in the once-per-trace ``[coordinate]`` line."""
+    from ..ops import coordinate
+
     leaves, treedef = jax.tree.flatten(stacked_tree)
-    n = leaves[0].shape[0]
-    return jax.tree.unflatten(treedef, [
-        fn(l.reshape(n, -1)).reshape(l.shape[1:]) for l in leaves
-    ])
+    coordinate.log_views(name, leaves, extra_tree is not None)
+    if extra_tree is None:
+        return jax.tree.unflatten(treedef, [fn(l) for l in leaves])
+    extras = treedef.flatten_up_to(extra_tree)
+    return jax.tree.unflatten(
+        treedef, [fn(l, e) for l, e in zip(leaves, extras)]
+    )
 
 
 def concat_stack(leaves):

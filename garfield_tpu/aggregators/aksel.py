@@ -58,7 +58,9 @@ def tree_aggregate(stacked_tree, f, mode="mid", **kwargs):
 
     leaves = jax.tree.leaves(stacked_tree)
     n = leaves[0].shape[0]
-    med = tree_coordinatewise(coordinate_median, stacked_tree)
+    med = tree_coordinatewise(
+        coordinate_median, stacked_tree, name="aksel"
+    )
     dist = sum(
         jnp.sum(
             jnp.square(

@@ -42,60 +42,71 @@ def _leaf_spans(leaves):
     return spans, off
 
 
+def _masked_mix(leaves, medians, rows0, p, key):
+    """``mask * median + (1 - mask) * row 0`` per leaf: the Bernoulli mask
+    is drawn once over the full flat dimension (the same (d,) draw the
+    flat path makes) and SLICED per leaf in ravel order, so the same key
+    gives the same trajectory on either path."""
+    spans, d = _leaf_spans(leaves)
+    if key is None:
+        key = jax.random.key(0)
+    mask = jax.random.bernoulli(key, p, shape=(d,))
+    out = []
+    for l, m, r0, (a, b) in zip(leaves, medians, rows0, spans):
+        mk = mask[a:b].reshape(l.shape[1:]).astype(l.dtype)
+        out.append(m.astype(l.dtype) * mk + r0 * (1.0 - mk))
+    return out
+
+
 def tree_aggregate(stacked_tree, f, p=0.9, key=None, **kwargs):
-    """Tree-mode condense, EXACTLY equal to the flat path: the Bernoulli
-    mask is drawn once over the full flat dimension (the same (d,) draw
-    the flat path makes) and SLICED per leaf in ravel order, so the same
-    key gives the same trajectory on either path; the median runs per leaf
-    (Pallas kernels on TPU)."""
+    """Tree-mode condense, EXACTLY equal to the flat path (see
+    ``_masked_mix``); the median runs per leaf (Pallas kernels on TPU)."""
     from ._common import tree_coordinatewise
 
     leaves, treedef = jax.tree.flatten(stacked_tree)
-    spans, d = _leaf_spans(leaves)
-    if key is None:
-        key = jax.random.key(0)
-    mask = jax.random.bernoulli(key, p, shape=(d,))
     med = jax.tree.leaves(
-        tree_coordinatewise(coordinate_median, stacked_tree)
+        tree_coordinatewise(coordinate_median, stacked_tree, name="condense")
     )
-    out = []
-    for l, m, (a, b) in zip(leaves, med, spans):
-        mk = mask[a:b].reshape(l.shape[1:]).astype(l.dtype)
-        out.append(m * mk + l[0] * (1.0 - mk))
-    return jax.tree.unflatten(treedef, out)
+    return jax.tree.unflatten(
+        treedef, _masked_mix(leaves, med, [l[0] for l in leaves], p, key)
+    )
 
 
-def tree_aggregate_ext(ext_tree, row_map, row_scale, f=0, key=None, p=0.9,
-                       **kwargs):
+def tree_aggregate_ext(stacked_tree, extra_tree, row_map, row_scale, f=0,
+                       key=None, p=0.9, **kwargs):
     """Folded-attack twin (parallel/fold.py): per-leaf REMAPPED medians
-    (the Pallas kernels apply row_map/row_scale in-register) and the
-    poisoned row 0 reconstructed from the remap — one static row index and
-    scale — so the poisoned stack never materializes."""
+    (the Pallas kernels apply row_map/row_scale in-register, the fake row
+    a second operand) and the poisoned row 0 reconstructed from the remap
+    — one static row index and scale — so the poisoned stack never
+    materializes."""
     import numpy as np
 
     from .. import ops
+    from ._common import tree_coordinatewise
 
     rmap = np.asarray(row_map)
     scales = np.asarray(row_scale, np.float32)
-    leaves, treedef = jax.tree.flatten(ext_tree)
-    spans, d = _leaf_spans(leaves)
-    if key is None:
-        key = jax.random.key(0)
-    mask = jax.random.bernoulli(key, p, shape=(d,))
+    leaves, treedef = jax.tree.flatten(stacked_tree)
+    med = jax.tree.leaves(tree_coordinatewise(
+        lambda g, e=None: ops.coordinate_median(
+            g, extra=e, row_map=rmap, row_scale=scales
+        ),
+        stacked_tree, extra_tree, name="condense",
+    ))
     i0, s0 = int(rmap[0]), float(scales[0])
-    out = []
-    for l, (a, b) in zip(leaves, spans):
-        n = l.shape[0]
-        med = ops.coordinate_median(
-            l.reshape(n, -1), row_map=rmap, row_scale=scales
-        ).reshape(l.shape[1:])
-        if s0 == 0.0:
-            row0 = jnp.zeros_like(l[i0])  # crash: exact zeros, not 0*inf
-        else:
-            row0 = l[i0] if s0 == 1.0 else l[i0] * s0
-        mk = mask[a:b].reshape(l.shape[1:]).astype(l.dtype)
-        out.append(med.astype(l.dtype) * mk + row0 * (1.0 - mk))
-    return jax.tree.unflatten(treedef, out)
+    n = leaves[0].shape[0]
+    if s0 == 0.0:  # crash: exact zeros, not 0*inf
+        rows0 = [jnp.zeros_like(l[0]) for l in leaves]
+    elif i0 == n:
+        rows0 = [e.astype(l.dtype) for l, e in zip(
+            leaves, treedef.flatten_up_to(extra_tree))]
+    else:
+        rows0 = [l[i0] for l in leaves]
+    if s0 not in (0.0, 1.0):
+        rows0 = [r * s0 for r in rows0]
+    return jax.tree.unflatten(
+        treedef, _masked_mix(leaves, med, rows0, p, key)
+    )
 
 
 def check(gradients, f, p=0.9, key=None, **kwargs):

@@ -28,21 +28,23 @@ def tree_aggregate(stacked_tree, key=None, **kwargs):
     ResNet-18 aggregathor step under lie drops 21.3 -> 16.2 ms/step
     (PERF.md); the per-leaf Pallas launches cost less than the flat-stack
     plumbing they replace."""
-    return tree_coordinatewise(coordinate_median, stacked_tree)
+    return tree_coordinatewise(coordinate_median, stacked_tree, name="median")
 
 
-def tree_aggregate_ext(ext_tree, row_map, row_scale, key=None, **kwargs):
-    """Folded-attack twin (parallel/fold.py): per-leaf median over the
-    EXTENDED stacked tree with the attack's static row remap applied
-    in-register by the Pallas kernel — no poisoned stack, no moment
-    passes."""
+def tree_aggregate_ext(stacked_tree, extra_tree, row_map, row_scale,
+                       key=None, **kwargs):
+    """Folded-attack twin (parallel/fold.py): per-leaf median over the raw
+    stacked tree and, beside it, the plan's fake row (``extra_tree``, or
+    None: row ``n`` of ``row_map``), the attack's static row remap applied
+    in-register by the Pallas kernel — no poisoned stack, no extended
+    stack, no moment passes (PERF.md section 6, PR 29)."""
     from .. import ops
 
     return tree_coordinatewise(
-        lambda g: ops.coordinate_median(
-            g, row_map=row_map, row_scale=row_scale
+        lambda g, e=None: ops.coordinate_median(
+            g, extra=e, row_map=row_map, row_scale=row_scale
         ),
-        ext_tree,
+        stacked_tree, extra_tree, name="median",
     )
 
 

@@ -32,19 +32,23 @@ def tree_aggregate(stacked_tree, f, key=None, **kwargs):
     (see median.tree_aggregate for the chip measurement)."""
     from .. import ops
 
-    return tree_coordinatewise(lambda g: ops.trimmed_mean(g, f), stacked_tree)
+    return tree_coordinatewise(
+        lambda g: ops.trimmed_mean(g, f), stacked_tree, name="tmean"
+    )
 
 
-def tree_aggregate_ext(ext_tree, row_map, row_scale, f, key=None, **kwargs):
+def tree_aggregate_ext(stacked_tree, extra_tree, row_map, row_scale, f,
+                       key=None, **kwargs):
     """Folded-attack twin (parallel/fold.py): per-leaf trimmed mean over
-    the EXTENDED stacked tree, remap applied in-register by the kernel."""
+    the raw stacked tree with the fake row beside it, remap applied
+    in-register by the kernel (see median.tree_aggregate_ext)."""
     from .. import ops
 
     return tree_coordinatewise(
-        lambda g: ops.trimmed_mean(
-            g, f, row_map=row_map, row_scale=row_scale
+        lambda g, e=None: ops.trimmed_mean(
+            g, f, extra=e, row_map=row_map, row_scale=row_scale
         ),
-        ext_tree,
+        stacked_tree, extra_tree, name="tmean",
     )
 
 

@@ -4,10 +4,12 @@ The reference ships hand-written CUDA kernels for exactly this layer
 (pytorch_impl/libs/native/py_median/median.cu, py_bulyan/bulyan.cu — SURVEY
 P13): the GAR math that sweeps the full d-dimensional gradient (d ≈ 1.1e7 for
 ResNet-18) rather than the tiny (n, n) score matrices. On TPU the equivalents
-are Pallas kernels: each kernel makes ONE pass over HBM, streaming (n, TILE)
-column blocks through VMEM and running an in-register odd-even transposition
-sorting network over the small n axis on the VPU — no (n, d) re-layout, no
-XLA variadic sort, no second pass for the selection step.
+are Pallas kernels: each kernel makes ONE pass over HBM, streaming blocks of
+the stack through VMEM where the gradient pass left it (worker axis leading,
+rows upcast in VMEM, a folded attack's fake row a second operand) and running
+an in-register odd-even transposition sorting network over the small n axis
+on the VPU — no (n, d) re-layout, no XLA variadic sort, no second pass for
+the selection step.
 
 Public entry points dispatch by backend: the Pallas path on TPU (or when
 forced via ``interpret=True`` for CPU testing), a pure-jnp fallback elsewhere

@@ -1,33 +1,44 @@
 """Pallas TPU kernels for coordinate-wise robust statistics.
 
-Two kernels, mirroring the two CUDA kernels the reference dedicates to this
+Three kernels, mirroring the CUDA kernels the reference dedicates to this
 layer (SURVEY P13):
 
-  - ``coordinate_median``: lower coordinate-wise median of an (n, d) stack
-    (py_median/median.cu counterpart). torch semantics: for even n the lower
-    of the two middle values; NaN sorts last, so up to ceil(n/2)-1 NaNs per
-    coordinate do not contaminate the result (median.py:39).
+  - ``coordinate_median``: lower coordinate-wise median of a stack of n
+    rows (py_median/median.cu counterpart). torch semantics: for even n the
+    lower of the two middle values; NaN sorts last, so up to ceil(n/2)-1
+    NaNs per coordinate do not contaminate the result (median.py:39).
+  - ``trimmed_mean``: the same sort, then the mean of rows f..n-f-1.
   - ``averaged_median_mean``: Bulyan's second phase (py_bulyan/bulyan.cu
     counterpart, bulyan.py:77-84): per coordinate, take the beta values
     closest to the lower median (stable ties: lowest row index wins) and
-    average them. Fused into one kernel so the (s, d) stack is read from HBM
+    average them. Fused into one kernel so the stack is read from HBM
     exactly once; the jnp fallback needs a sort, an argsort and a gather.
 
 Design notes (see /opt/skills/guides/pallas_guide.md):
-  - n is tiny (worker count, <= MAX_SORT_N) and d is huge, so the kernel
-    grid tiles d in LANE-multiple blocks and each program fully sorts its
-    (n, TILE) block with an odd-even transposition network unrolled at trace
-    time. Compare-exchange on strict ``<`` keeps the network STABLE, which
-    is what makes tie-breaking match ``jnp.argsort(..., stable=True)``.
+  - n is tiny (worker count, <= MAX_SORT_N) and d is huge, so the grid tiles
+    the coordinates and each program fully sorts its block with an odd-even
+    transposition network unrolled at trace time. Compare-exchange on strict
+    ``<`` keeps the network STABLE, which is what makes tie-breaking match
+    ``jnp.argsort(..., stable=True)``.
   - The comparator implements the jnp/torch sort total order for floats:
     ascending with NaN last — swap iff (b < a) or (a is NaN and b is not).
-  - d is padded to a TILE multiple host-side; columns are independent so the
-    pad values are irrelevant and sliced off.
+  - The operands are read where they lie (``_view``): a stacked leaf
+    ``(n, ..., L)`` whose last axis is a multiple of 128 is viewed
+    ``(n, R, L)`` — a bitcast under XLA:TPU's tiled layout, the worker axis
+    leading, each worker's slab whole native tiles — and blocked
+    ``(n, rb, lb)``; anything else is viewed flat ``(n, d)`` and blocked
+    ``(n, tile)``. No operand is padded (the last block of a view may be
+    ragged: columns are independent, what lies past the edge is computed
+    and dropped), none is upcast outside the kernel (rows are upcast to
+    float32 in VMEM, ``_load_rows``), and a folded attack's fake row is a
+    second operand, not a concatenated row (parallel/fold.py).
 """
 
 import functools
+import math
 import os
 import warnings
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -44,12 +55,29 @@ from jax.experimental import pallas as pl
 MAX_SORT_N = 32
 
 _LANES = 128
-# Lanes per program. Swept on the v5e chip (r5, n=8 d=11.2M f32): 1024 ->
-# 5.8 ms, 4096 -> 4.4, 8192 -> 3.8 (best), 16384+ regress — the old 1024
-# default optimized for a 128 KiB VMEM budget that is ~100x below the
-# ~16 MB/core reality, and its 10.9k-program grid paid per-program
-# overhead. Worst case (n = MAX_SORT_N + out + padding) stays under 2 MB.
-_TILE = 8192
+# What one program takes. One buffer of a program's input block (n rows, the
+# fake row, the output) stays under _BLOCK_BYTES — Pallas holds two: 4.4 MB
+# of VMEM at n = MAX_SORT_N in float32 — and under _BLOCK_VALUES
+# coordinates, in lanes of up to _BLOCK_LANES; a flat (n, tile) block stays
+# under _FLAT_TILE lanes, whose rows the network sorts whole. Swept on the
+# v5e chip (PR 29, jax 0.9.0 / libtpu 0.0.34, the kernel alone). n = 4 and
+# the fake row, bf16[16384, 2048] (least 0.49 ms): 8,192 values a program
+# 1.49 ms, 65,536 0.82, 262,144 0.70 in 128-lane blocks; 0.84, 0.83, 0.67
+# in 512-lane ones — a grid step costs about 0.35 us, which at 8,192 values
+# is most of the time. n = 16 and the fake row, bf16[3, 3, 512, 512]:
+# 0.59-0.63 ms at every block (the network's vector work), 512 lanes 4%
+# under 128. The same shapes through the (n + 1, d) float32 operand this
+# module took until PR 29: 2.24 and 0.56 ms, 5.72 and 1.17 with the
+# concatenate and the upcast in front.
+_BLOCK_BYTES = 4 << 20
+_BLOCK_VALUES = 262144
+_BLOCK_LANES = 512
+_FLAT_TILE = 8192
+# float32 vregs of rows the network holds at once inside a program: the
+# in-place block is sorted (cr, 128) rows at a time in a loop, cr chosen so
+# that n rows of it stay about this many of the 64 vregs. 16 to 56 measured
+# within 3% of each other at n = 4 and n = 16 (same sweep).
+_CHUNK_VREGS = 40
 
 _warned_large_n = set()
 
@@ -112,64 +140,151 @@ def _oddeven_exchange(keys, payloads=None):
     return keys if payloads is None else (keys, payloads)
 
 
-def _pad_cols(g, tile):
-    d = g.shape[-1]
-    pad = (-d) % tile
-    if pad:
-        g = jnp.pad(g, ((0, 0), (0, pad)))
-    return g, d
+class _View(NamedTuple):
+    """How a kernel reads one stacked operand ``(n,) + tail``."""
+
+    in_place: bool   # the leaf's own last two axes, else flat (n, d)
+    tap_major: bool  # in place: (K, n, R, L), else worker-major (n, K, R, L)
+    shape: tuple     # the view without the worker axis: (K, R, L) or (d,)
+    block: tuple     # one program's block of it: (rb, lb) or (tile,)
+    chunk: tuple     # what the network sorts at once: (cr, lc) or (tile,)
 
 
-def _load_rows(x_ref, n, sel=None):
-    """Rows upcast to f32 in VMEM: Mosaic on current targets rejects bf16
-    compares ("Target does not support this comparison" — caught by the
-    on-device tests, tests/test_ops_tpu.py), and bf16 -> f32 is exact and
-    order-preserving, so the sort network is unchanged semantically while
-    HBM traffic stays bf16.
+def _view(rows, n, tail, dtype, tile=None):
+    """The view and block for ``rows`` physical rows (the fake row counted)
+    sorted as ``n`` logical ones — a function of shape and dtype alone.
+
+    In place wherever the leaf has an axis before its last: the last two
+    axes ``(R, L)`` stay the sublane and lane axes, the K matrices before
+    them are walked by the grid, and each worker's slab is whole native
+    tiles. Under XLA:TPU's tiled layout that view is a bitcast of what the
+    gradient pass writes: a dense kernel or a stack of expert matrices
+    worker-major, ``(n, K, R, L)``; a convolution kernel ``(kh, kw, cin,
+    cout)`` TAP-major, ``(kh*kw, n, cin, cout)`` — the twin's grouped dw
+    convolution writes ``[kh][kw][n][cin][cout]``, and a worker-major view
+    of it cost one relayout copy a kernel (2.5 ms a step in r50n16 until
+    PR 29; compiled for a described v5e). The result is ``(K, R, L)``: the
+    leaf's shape but for a split of its leading axis, which XLA fuses into
+    the optimizer with the cast. A last axis that is no multiple of 128 (64
+    channels, 100 classes) is taken whole, its lanes part filled as they
+    are in HBM. A vector leaf, and the flat (n, d) stack of aksel, cclip
+    and Bulyan, has the worker axis in its sublanes and is read flat.
+    ``tile`` (coordinates a program, a multiple of 128) overrides the
+    budget."""
+    from ..models.slotlayers import sublane_rows
+
+    tail = tuple(int(t) for t in tail)
+    dtype = jnp.dtype(dtype)
+    if tile is None:
+        values = _BLOCK_BYTES // ((rows + 1) * dtype.itemsize)
+        values = min(_BLOCK_VALUES, 1 << (values.bit_length() - 1))
+    elif tile % _LANES:
+        raise ValueError(f"tile must be a multiple of {_LANES}, got {tile}")
+    else:
+        values = tile
+    if not _in_place(tail):
+        d = math.prod(tail)
+        t = min(d, values, _FLAT_TILE)
+        return _View(False, False, (d,), (t,), (t,))
+    mats, r_all, l_all = math.prod(tail[:-2]), tail[-2], tail[-1]
+    if l_all % _LANES:
+        lb = lc = l_all
+    else:
+        lanes = l_all // _LANES
+        lb = _LANES * max(
+            k for k in range(1, _BLOCK_LANES // _LANES + 1) if lanes % k == 0
+        )
+        lc = _LANES
+    sub = sublane_rows(dtype)
+    if r_all < sub:
+        cr = rb = r_all  # the whole axis: the one block shape under a tile
+    else:
+        cr = 8 * _CHUNK_VREGS // (n * pl.cdiv(lc, _LANES))
+        cr = min(max(sub, cr // sub * sub), r_all // sub * sub)
+        rb = min(max(cr, values // lb // cr * cr), r_all // cr * cr)
+    return _View(
+        True, len(tail) >= 4, (mats, r_all, l_all), (rb, lb), (cr, lc)
+    )
+
+
+def _in_place(tail):
+    """A leaf with an axis before its last is read in place (``_view``)."""
+    return len(tail) >= 2
+
+
+def _kernel_dtype(dtype, tail):
+    """The dtype the kernel reads a stack ``(n,) + tail`` of ``dtype`` in:
+    its own, or float32 where XLA converts the operand in front of the
+    kernel instead of ``_load_rows`` in VMEM — the one place that decides
+    it, by what Mosaic takes and what the chip showed. float16: Mosaic on
+    the v5e has no f16 vector load ("Invalid vector type for load",
+    compiled for a described v5e, jax 0.9.0). A half-precision FLAT view:
+    its rows are single sublanes of packed (16, 128) tiles, one strided
+    load and one unpack each — at n = 8, d = 11.2M in bf16 the kernel with
+    the convert in front takes 2.57 ms, with the upcast in VMEM 6.82 (1.61
+    on a float32 stack; v5e, PR 29: r5's 3.8 against 7.8 ms still stands
+    for this view), and it compiles ten times longer at n = 32. In place the
+    upcast is a tile-wise unpack and stays in VMEM."""
+    dtype = jnp.dtype(dtype)
+    outside = dtype == jnp.float16 or (
+        not _in_place(tail) and dtype.itemsize < 4
+    )
+    return jnp.dtype(jnp.float32) if outside else dtype
+
+
+def _load_rows(x_ref, e_ref, at, shape, n, sel=None):
+    """The block's rows at index ``at``, upcast to f32 in VMEM: Mosaic on
+    current targets rejects bf16 compares ("Target does not support this
+    comparison" — caught by the on-device tests, tests/test_ops_tpu.py),
+    and bf16 -> f32 is exact and order-preserving, so the sort network is
+    unchanged semantically while HBM traffic stays bf16.
 
     ``sel`` (optional, STATIC): list of (row_index, scale) pairs — the
     folded-attack remap (parallel/fold.py): logical row i is
-    ``scale * block[row_index]``. Duplicate indices (lie's shared fake
-    row) are free VMEM re-reads; the indexing and scaling unroll at trace
-    time, so the poisoned stack is never materialized anywhere."""
+    ``scale * stack[row_index]``, and row_index ``n`` (the stack's own row
+    count) is the fake row, the second operand ``e_ref``. Duplicate indices
+    (lie's shared fake row) are free VMEM re-reads; the indexing and scaling
+    unroll at trace time, so the poisoned stack is never materialized
+    anywhere."""
+
+    def load(idx):
+        ref, i = (e_ref, 0) if idx == n else (x_ref, idx)
+        return ref[(i,) + at].astype(jnp.float32)
+
     if sel is None:
-        return [x_ref[i, :].astype(jnp.float32) for i in range(n)]
+        return [load(i) for i in range(n + (e_ref is not None))]
 
     def one(idx, scale):
         if scale == 0.0:
             # Exact zeros, not 0*row: the crash attack's where-path writes
             # literal zero rows, and 0*inf/0*nan would leak NaN into the
             # sort where the reference semantics have 0.
-            return jnp.zeros_like(x_ref[idx, :], jnp.float32)
-        row = x_ref[idx, :].astype(jnp.float32)
+            return jnp.zeros(shape, jnp.float32)
+        row = load(idx)
         return row if scale == 1.0 else row * scale
 
     return [one(idx, scale) for idx, scale in sel]
 
 
-def _median_kernel(n, sel, x_ref, o_ref):
-    rows = _oddeven_exchange(_load_rows(x_ref, n, sel))
-    o_ref[0, :] = rows[(n - 1) // 2].astype(o_ref.dtype)
+def _median_rows(n, rows):
+    return _oddeven_exchange(rows)[(n - 1) // 2]
 
 
-def _tmean_kernel(n, f, sel, x_ref, o_ref):
-    rows = _oddeven_exchange(_load_rows(x_ref, n, sel))
+def _tmean_rows(n, f, rows):
+    rows = _oddeven_exchange(rows)
     acc = rows[f]
     for i in range(f + 1, n - f):
         acc = acc + rows[i]
-    o_ref[0, :] = (acc / (n - 2 * f)).astype(o_ref.dtype)
+    return acc / (n - 2 * f)
 
 
-def _avgmed_kernel(s, beta, quant_dtype, x_ref, o_ref):
-    vals = _load_rows(x_ref, s)
+def _avgmed_rows(s, beta, quant_dtype, vals):
     med = _oddeven_exchange(list(vals))[(s - 1) // 2]
     # Deviations are the SORT KEYS and must carry the LOGICAL input
     # dtype's rounding: the spec computes |g - med| in the caller's dtype,
     # where bf16 rounding creates ties (broken stably by row index) that
-    # exact f32 deviations would order differently. ``quant_dtype`` is the
-    # caller's dtype — the kernel itself now always runs on f32 blocks
-    # (_dispatch upcasts half inputs), so x_ref.dtype no longer carries
-    # it. Quantize, then upcast for the comparisons Mosaic supports.
+    # exact f32 deviations would order differently. Quantize, then upcast
+    # for the comparisons Mosaic supports.
     devs = [
         jnp.abs(v - med).astype(quant_dtype).astype(jnp.float32)
         for v in vals
@@ -178,27 +293,126 @@ def _avgmed_kernel(s, beta, quant_dtype, x_ref, o_ref):
     acc = picked[0]
     for i in range(1, beta):
         acc = acc + picked[i]
-    o_ref[0, :] = (acc / beta).astype(o_ref.dtype)
+    return acc / beta
 
 
-def _column_call(kernel, g, tile, interpret, name):
-    """Run a (n, TILE) -> (1, TILE) kernel over d-tiles of g. ``name`` is
-    the custom call's name in the compiled program, and so its events' name
-    in a device trace (``%coordinate_median.N``)."""
-    if tile % _LANES:
-        raise ValueError(f"tile must be a multiple of {_LANES}, got {tile}")
-    g, d = _pad_cols(g, tile)
-    n, dp = g.shape
+def _column_kernel(reduce, n, sel, view, *refs):
+    """One program: ``reduce`` (rows -> one row, f32) over the block, a
+    chunk at a time; the one rounding to the operand's dtype is the
+    store's."""
+    x_ref, o_ref = refs[0], refs[-1]
+    e_ref = refs[1] if len(refs) == 3 else None
+
+    lead = () if view.in_place else (0,)  # the flat result is (1, d)
+
+    def run(at, shape):
+        rows = _load_rows(x_ref, e_ref, at, shape, n, sel)
+        o_ref[lead + at] = reduce(rows).astype(o_ref.dtype)
+
+    if not view.in_place:
+        run((slice(None),), view.block)
+        return
+    (rb, lb), (cr, lc) = view.block, view.chunk
+
+    def chunk_rows(row_slice):
+        for l0 in range(0, lb, lc):
+            run((row_slice, slice(l0, l0 + lc)), (cr, lc))
+
+    if rb == cr:
+        chunk_rows(slice(None))
+        return
+
+    def body(c, carry):
+        chunk_rows(pl.ds(pl.multiple_of(c * cr, cr), cr))
+        return carry
+
+    jax.lax.fori_loop(0, rb // cr, body, 0)
+
+
+def _column_call(reduce, g, extra, sel, n, tile, interpret, name):
+    """Run ``reduce`` over every coordinate of the stack ``g`` ``(rows,) +
+    tail`` and, where the plan has one, the fake row ``extra`` ``tail`` —
+    each read through ``_view``, neither padded nor concatenated. ``name``
+    is the custom call's name in the compiled program, and so its events'
+    name in a device trace (``%coordinate_median.N``)."""
+    rows, tail, out_dtype = g.shape[0], g.shape[1:], g.dtype
+    dtype = _kernel_dtype(out_dtype, tail)
+    g = g.astype(dtype)
+    if extra is not None:  # rounded to the stack's dtype, as a row of it
+        extra = extra.astype(out_dtype).astype(dtype)
+    view = _view(rows + (extra is not None), n, tail, dtype, tile)
+    if view.in_place:
+        axis = 1 if view.tap_major else 0  # where the worker axis goes
+
+        def put(a):  # (lead,) + tail -> (lead, K, R, L) or (K, lead, R, L)
+            return jnp.moveaxis(a.reshape(a.shape[:1] + view.shape), 0, axis)
+
+        def spec(lead):
+            block = [None, *view.block]
+            block.insert(axis, lead)
+            return pl.BlockSpec(
+                tuple(block),
+                lambda k, i, j: (k, 0, i, j) if axis else (0, k, i, j),
+            )
+
+        out_spec = pl.BlockSpec(
+            (None,) + view.block, lambda k, i, j: (k, i, j)
+        )
+        grid = view.shape[:1]
+        out_shape = view.shape
+    else:
+
+        def put(a):  # (lead, ...) -> (lead, d)
+            return a.reshape(a.shape[:1] + view.shape)
+
+        def spec(lead):
+            return pl.BlockSpec((lead,) + view.block, lambda i: (0, i))
+
+        out_spec = spec(1)
+        grid = ()
+        out_shape = (1,) + view.shape
+    operands = [put(g)] + ([] if extra is None else [put(extra[None])])
     out = pl.pallas_call(
-        kernel,
-        grid=(dp // tile,),
-        in_specs=[pl.BlockSpec((n, tile), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, dp), g.dtype),
+        functools.partial(_column_kernel, reduce, rows, sel, view),
+        grid=grid + tuple(
+            pl.cdiv(s, b) for s, b in zip(view.shape[-len(view.block):],
+                                          view.block)
+        ),
+        in_specs=[spec(rows), spec(1)][:len(operands)],
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, dtype),
         interpret=interpret,
         name=name,
-    )(g)
-    return out[0, :d]
+    )(*operands)
+    return out.reshape(tail).astype(out_dtype)
+
+
+def log_views(name, leaves, has_extra):
+    """Say once per trace, on the ``info`` channel, how rule ``name`` reads
+    the stacked ``leaves``: how many leaves and values in place and flat,
+    the largest leaf's block, whether a fake row comes as a second
+    operand — and where no kernel will run, that."""
+    from ..utils import tools
+
+    rows = leaves[0].shape[0]
+    tally = {True: [0, 0], False: [0, 0]}
+    for leaf in leaves:
+        entry = tally[_in_place(leaf.shape[1:])]
+        entry[0] += 1
+        entry[1] += math.prod(leaf.shape[1:])
+    big = max(leaves, key=lambda leaf: leaf.size)
+    dtype = _kernel_dtype(big.dtype, big.shape[1:])
+    block = (rows,) + _view(rows + has_extra, rows, big.shape[1:], dtype).block
+    (k_in, v_in), (k_flat, v_flat) = tally[True], tally[False]
+    tools.info(
+        f"[coordinate] {name}: in place {k_in} leaves / {v_in / 1e6:.2f}M "
+        f"values, flat {k_flat} / {v_flat / 1e6:.2f}M, block {block} "
+        f"{dtype.name}, "
+        + ("fake row apart" if has_extra else "no fake row")
+        + ("" if use_pallas(rows) else
+           "; no kernel here (XLA sort: no TPU backend, n > "
+           f"{MAX_SORT_N} or GARFIELD_NO_PALLAS)")
+    )
 
 
 # --- public entry points ---------------------------------------------------
@@ -251,7 +465,7 @@ def averaged_median_mean_xla(g, beta):
     return jnp.where(jnp.isnan(thresh), jnp.nan, out)
 
 
-def _dispatch(g, kernel, fallback_fn, tile, interpret, n, op):
+def _dispatch(g, extra, reduce, spec_fn, sel, n, tile, interpret, op):
     """Route to the Pallas kernel or the XLA fallback.
 
     The Pallas branch is selected by the *lowering* platform
@@ -261,46 +475,42 @@ def _dispatch(g, kernel, fallback_fn, tile, interpret, n, op):
     large-n warning) is consulted only when the kernel is NOT forced via
     ``interpret=True`` — an interpret-mode call runs the kernel and must
     not warn or consume the once-per-op warning budget.
-    """
-    # Half-precision inputs run the KERNEL in f32: Mosaic's packed (2, 1)
-    # sublane loads + per-row converts made the bf16 kernel SLOWER than
-    # the f32 one despite half the HBM traffic (measured r5: 7.8 vs
-    # 3.8 ms at n=8 d=11.2M), so one XLA convert outside the kernel wins
-    # ~2x. bf16 -> f32 is exact, selection ops (median) round-trip
-    # losslessly, and the mean-producing kernels (tmean/avgmed) gain f32
-    # accumulation accuracy before the single round back.
-    orig = g.dtype
-    half = orig in (jnp.bfloat16, jnp.float16)
 
-    def run_kernel(a, interp):
-        out = _column_call(
-            kernel, a.astype(jnp.float32) if half else a, tile, interp, op
-        )
-        return out.astype(orig) if half else out
+    The kernel reads the stack in its own dtype and shape and the fake row
+    beside it (``_column_call``); only the fallback — ``spec_fn`` over the
+    written-out (n, d) rows — flattens, concatenates and remaps.
+    """
+    operands = (g,) if extra is None else (g, extra)
+
+    def run_kernel(interp, a, e=None):
+        return _column_call(reduce, a, e, sel, n, tile, interp, op)
+
+    def fallback(a, e=None):
+        return spec_fn(_written_out(a, e, sel)).reshape(a.shape[1:])
 
     if interpret:
-        return run_kernel(g, True)
+        return run_kernel(True, *operands)
     if not use_pallas(n, op=op):
-        return fallback_fn(g)
+        return fallback(*operands)
     return jax.lax.platform_dependent(
-        g,
-        tpu=lambda a: run_kernel(a, False),
-        default=fallback_fn,
+        *operands,
+        tpu=functools.partial(run_kernel, False),
+        default=fallback,
     )
 
 
-def _remap_sel(g, row_map, row_scale):
+def _remap_sel(rows, row_map, row_scale):
     """Normalize the folded-attack remap to a static ``sel`` list (or None)
-    plus the logical row count; validates bounds against g's physical rows.
-    ``row_map``/``row_scale`` must be concrete (numpy) — the remap is baked
-    into the kernel at trace time."""
+    plus the logical row count; validates bounds against the ``rows``
+    physical rows (the stack's and the fake row). ``row_map``/``row_scale``
+    must be concrete (numpy) — the remap is baked into the kernel at trace
+    time."""
     import numpy as np
 
     if row_map is None and row_scale is None:
-        return None, g.shape[0]
-    ne = g.shape[0]
+        return None, rows
     row_map = (
-        np.arange(ne) if row_map is None else np.asarray(row_map, np.int64)
+        np.arange(rows) if row_map is None else np.asarray(row_map, np.int64)
     )
     n = row_map.size
     row_scale = (
@@ -310,9 +520,9 @@ def _remap_sel(g, row_map, row_scale):
         raise ValueError(
             f"row_scale has {row_scale.size} entries for {n} mapped rows"
         )
-    if row_map.min() < 0 or row_map.max() >= ne:
+    if row_map.min() < 0 or row_map.max() >= rows:
         raise ValueError(
-            f"row_map references rows outside the {ne}-row stack"
+            f"row_map references rows outside the {rows}-row stack"
         )
     return [
         (int(i), float(s)) for i, s in zip(row_map, row_scale)
@@ -335,48 +545,75 @@ def _remap_fallback(g, sel):
     return eff
 
 
-def coordinate_median(g, *, row_map=None, row_scale=None, interpret=False,
-                      tile=_TILE):
-    """Lower coordinate-wise median of an (n, d) stack -> (d,).
+def _written_out(g, extra, sel):
+    """The logical rows as one flat (n, d) array, the XLA way: the stack
+    flattened, the fake row concatenated under it, the remap gathered."""
+    flat = g.reshape(g.shape[0], -1)
+    if extra is not None:
+        flat = jnp.concatenate(
+            [flat, extra.astype(g.dtype).reshape(1, -1)], axis=0
+        )
+    return flat if sel is None else _remap_fallback(flat, sel)
+
+
+def _stack_and_sel(g, extra, row_map, row_scale):
+    """The operands as arrays, the static remap and the logical row count.
+    ``extra`` is the stack's row ``g.shape[0]`` for ``row_map``."""
+    g = jnp.asarray(g)
+    if extra is not None:
+        extra = jnp.asarray(extra)
+        if extra.shape != g.shape[1:]:
+            raise ValueError(
+                f"the fake row has shape {extra.shape}, the stack's rows "
+                f"{g.shape[1:]}"
+            )
+    sel, n = _remap_sel(
+        g.shape[0] + (extra is not None), row_map, row_scale
+    )
+    return g, extra, sel, n
+
+
+def coordinate_median(g, *, extra=None, row_map=None, row_scale=None,
+                      interpret=False, tile=None):
+    """Lower coordinate-wise median of a stack ``(n,) + shape`` -> ``shape``
+    (an (n, d) stack -> (d,)).
 
     ``row_map``/``row_scale`` (static) apply the folded-attack remap INSIDE
-    the kernel — logical row i is ``row_scale[i] * g[row_map[i]]`` — so the
-    poisoned stack of a deterministic attack is never materialized
-    (parallel/fold.py)."""
-    g = jnp.asarray(g)
-    sel, n = _remap_sel(g, row_map, row_scale)
+    the kernel — logical row i is ``row_scale[i] * ext[row_map[i]]``, where
+    ``ext`` is the stack with ``extra`` (a fake row of ``shape``, read as a
+    second operand) as its row n — so the poisoned stack of a deterministic
+    attack is never materialized (parallel/fold.py). ``tile``: coordinates
+    per program, a multiple of 128 (default: by n and dtype, ``_view``)."""
+    g, extra, sel, n = _stack_and_sel(g, extra, row_map, row_scale)
     if n == 1:
-        return g[0] if sel is None else _remap_fallback(g, sel)[0]
-    fallback = (
-        coordinate_median_reference if sel is None
-        else lambda a: coordinate_median_reference(_remap_fallback(a, sel))
-    )
+        return _single_row(g, extra, sel)
     return _dispatch(
-        g, functools.partial(_median_kernel, n, sel),
-        fallback, tile, interpret,
-        n, "coordinate_median",
+        g, extra, functools.partial(_median_rows, n),
+        coordinate_median_reference, sel, n, tile, interpret,
+        "coordinate_median",
     )
 
 
-def trimmed_mean(g, f, *, row_map=None, row_scale=None, interpret=False,
-                 tile=_TILE):
+def _single_row(g, extra, sel):
+    """One logical row: the rule is that row (no kernel)."""
+    return _written_out(g, extra, sel)[0].reshape(g.shape[1:])
+
+
+def trimmed_mean(g, f, *, extra=None, row_map=None, row_scale=None,
+                 interpret=False, tile=None):
     """Coordinate-wise trimmed mean: average of rows f..n-f-1 per sorted
     column, fused into the sorting-network kernel (one HBM pass).
-    ``row_map``/``row_scale``: see ``coordinate_median``."""
-    g = jnp.asarray(g)
-    sel, n = _remap_sel(g, row_map, row_scale)
+    ``extra``/``row_map``/``row_scale``/``tile``: see
+    ``coordinate_median``."""
+    g, extra, sel, n = _stack_and_sel(g, extra, row_map, row_scale)
     if not (0 <= f and n - 2 * f >= 1):
         raise ValueError(f"need n - 2f >= 1, got n={n}, f={f}")
     if n == 1:
-        return g[0] if sel is None else _remap_fallback(g, sel)[0]
-    fallback = (
-        (lambda a: trimmed_mean_reference(a, f)) if sel is None
-        else (lambda a: trimmed_mean_reference(_remap_fallback(a, sel), f))
-    )
+        return _single_row(g, extra, sel)
     return _dispatch(
-        g, functools.partial(_tmean_kernel, n, f, sel),
-        fallback, tile, interpret,
-        n, "trimmed_mean",
+        g, extra, functools.partial(_tmean_rows, n, f),
+        lambda a: trimmed_mean_reference(a, f), sel, n, tile, interpret,
+        "trimmed_mean",
     )
 
 
@@ -567,7 +804,7 @@ def sortnet_row_sums(dist, k, *, axis=-1):
     return acc
 
 
-def averaged_median_mean(g, beta, *, interpret=False, tile=_TILE):
+def averaged_median_mean(g, beta, *, interpret=False, tile=None):
     """Mean of the beta rows closest (per coordinate) to the lower median.
 
     Equivalent to ``averaged_median_mean_reference`` (ties broken stably by
@@ -581,7 +818,7 @@ def averaged_median_mean(g, beta, *, interpret=False, tile=_TILE):
     if not (1 <= beta <= s):
         raise ValueError(f"beta must be in [1, {s}], got {beta}")
     return _dispatch(
-        g, functools.partial(_avgmed_kernel, s, beta, g.dtype),
-        lambda a: averaged_median_mean_xla(a, beta), tile, interpret,
-        s, "averaged_median_mean",
+        g, None, functools.partial(_avgmed_rows, s, beta, g.dtype),
+        lambda a: averaged_median_mean_xla(a, beta), None, s, tile,
+        interpret, "averaged_median_mean",
     )

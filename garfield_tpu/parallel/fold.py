@@ -31,10 +31,16 @@ each other (PERF.md).
 
 Applies when the topology's tree path is eligible, the attack is
 deterministic (lie/empire/reverse/crash), and the rule exposes a
-fold-capable interface: ``gram_select`` (krum, average),
-``fold_aggregate`` (Bulyan), ``tree_aggregate_ext`` (the coordinate-wise
-median/tmean — their Pallas kernels apply the row remap/scale
-in-register, ops/coordinate.py), or ``fold_flat_aggregate`` (cclip —
+fold-capable interface: ``gram_select`` (krum, average: the only form
+that gets the EXTENDED tree, fake row concatenated under every leaf —
+its Gram and weighted sum want it), ``fold_aggregate`` (Bulyan),
+``tree_aggregate_ext`` (the coordinate-wise median/tmean/condense: the
+raw stacked tree and the fake row's tree APART — their Pallas kernels
+read the stack where the gradient pass left it, take the fake row as a
+second operand and apply the row remap/scale in-register,
+ops/coordinate.py; the concatenated, upcast (n+1)-row copy this path
+made until PR 29 cost 81 of lfm2n4's 598 ms a step, PERF.md section 6),
+or ``fold_flat_aggregate`` (cclip —
 the remap applies to per-row scalars of its iterations, r5). Randomized
 attacks (random/drop) keep the ``where`` tree path. Zero-scale rows
 (the crash attack) are sanitized everywhere a 0*inf could otherwise
@@ -178,8 +184,6 @@ def folded_tree_aggregate(gar, plan, stacked_tree, *, f, key=None,
         the extension is assembled in BLOCK form (raw Gram, cross-dots c,
         |a|^2) without ever materializing an (n+1, d) array.
     """
-    leaves, treedef = jax.tree.flatten(stacked_tree)
-    n = leaves[0].shape[0]
     if subset_sel is not None and gar.gram_select is None:
         raise ValueError(
             "subset_sel composes with gram_select rules only (the "
@@ -206,45 +210,47 @@ def folded_tree_aggregate(gar, plan, stacked_tree, *, f, key=None,
     # TREE from TrainState.gar_state; only the flat-iteration branch
     # consumes it (as the concatenated vector).
     center_tree = params.pop("center", None)
-    # The attack's share comes first: the shared fake row, appended to the
-    # tree where the rule consumes one. All that follows is the rule's.
-    tree_form = (
-        gar.gram_select is not None or gar.tree_aggregate_ext is not None
-    )
+    # The attack's share comes first: the shared fake row — appended to the
+    # tree for the Gram-form rules, whose Gram and weighted sum want the
+    # extended tree, and handed on apart for every other form. All that
+    # follows is the rule's.
     ext = extra = None
-    if tree_form:
+    if gar.gram_select is not None:
         ext = _extended(plan.build_extra, stacked_tree)
     elif plan.build_extra is not None:
         with core.phase("attack"):
             extra = plan.build_extra(stacked_tree)
     return _folded_rule(
-        gar, plan, leaves, treedef, ext, extra, center_tree, params,
+        gar, plan, stacked_tree, ext, extra, center_tree, params,
         f=f, key=key, subset_sel=subset_sel, row_weights=row_weights,
         return_weights=return_weights,
     )
 
 
 @core.phase("rule")
-def _folded_rule(gar, plan, leaves, treedef, ext, extra, center_tree, params,
+def _folded_rule(gar, plan, stacked_tree, ext, extra, center_tree, params,
                  *, f, key, subset_sel, row_weights, return_weights):
     """``folded_tree_aggregate`` after the attack's share: ``ext`` is the
-    extended tree (tree-form rules), ``extra`` the fake row alone (flat
-    forms), either None where the plan has no fake row."""
+    extended tree (Gram-form rules), ``extra`` the fake row's tree alone
+    (every other form; None where the plan has no fake row)."""
+    leaves, treedef = jax.tree.flatten(stacked_tree)
     n = leaves[0].shape[0]
-    tree_form = ext is not None
 
     def sanitize_gram(gram_p):
         """See ``_sanitize_gram`` — closure over this plan's scales."""
         return _sanitize_gram(gram_p, plan.row_scale)
 
-    if tree_form:
-        if gar.gram_select is None:
-            # Coordinate-wise rules (median, tmean): per-leaf kernels with
-            # the remap applied in-register — no poisoned stack, no
-            # cohort-moment passes outside the fake-row build.
-            return gar.tree_aggregate_ext(
-                ext, plan.row_map, plan.row_scale, f=f, key=key, **params
-            )
+    if gar.tree_aggregate_ext is not None and gar.gram_select is None:
+        # Coordinate-wise rules (median, tmean, condense): per-leaf kernels
+        # that read the stack where the gradient pass left it and the fake
+        # row beside it, the remap applied in-register — no poisoned stack,
+        # no extended stack, no cohort-moment passes outside the fake-row
+        # build.
+        return gar.tree_aggregate_ext(
+            stacked_tree, extra, plan.row_map, plan.row_scale,
+            f=f, key=key, **params
+        )
+    if ext is not None:
         rmap = plan.row_map
         scale = jnp.asarray(plan.row_scale)
         if row_weights is not None:

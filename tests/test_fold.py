@@ -407,3 +407,137 @@ def test_crash_fold_nonfinite_row_stays_zero(gar_name, f):
     )
     for leaf in jax.tree.leaves(got):
         assert np.isfinite(np.asarray(leaf)).all()
+
+
+# --- the coordinate rules read the stack where it lies (PR 29) -------------
+
+
+@pytest.fixture
+def kernel_in_interpret_mode(monkeypatch):
+    """Every dispatch of ``ops.coordinate`` runs the Pallas kernel, in
+    interpret mode: the folded path below is the kernel's, not the XLA
+    fallback this CPU backend would take."""
+    from garfield_tpu.ops import coordinate
+
+    dispatch = coordinate._dispatch
+
+    def forced(g, extra, reduce, spec_fn, sel, n, tile, interpret, op):
+        return dispatch(g, extra, reduce, spec_fn, sel, n, tile, True, op)
+
+    monkeypatch.setattr(coordinate, "_dispatch", forced)
+
+
+def _conv_tree(key, n=N, dtype=jnp.float32):
+    """Leaves of every view: a convolution kernel (tap-major), a matrix
+    with a last axis of 64, a stack of matrices, a vector, a scalar."""
+    ks = jax.random.split(key, 5)
+    shapes = {"conv": (3, 3, 8, 128), "dense": (24, 64), "experts": (2, 16, 128),
+              "bias": (7,), "scale": (1,)}
+    return {
+        name: jax.random.normal(k, (n,) + shape).astype(dtype)
+        for k, (name, shape) in zip(ks, shapes.items())
+    }
+
+
+@pytest.mark.parametrize("attack", ["lie", "empire", "reverse", "crash"])
+@pytest.mark.parametrize("gar_name", ["median", "tmean", "condense"])
+def test_folded_kernel_matches_where_path(
+        gar_name, attack, kernel_in_interpret_mode):
+    """Every ``tree_aggregate_ext`` rule: the folded result through the
+    kernel (stack and fake row apart) equals poisoning the rows and
+    aggregating them."""
+    gar = gars[gar_name]
+    mask = core.default_byz_mask(N, F)
+    tree = _conv_tree(jax.random.PRNGKey(23))
+    plan = plan_gradient_attack_fold(attack, mask)
+    key = jax.random.PRNGKey(7)
+    got = folded_tree_aggregate(gar, plan, tree, f=F, key=key)
+    poisoned = apply_gradient_attack_tree(attack, tree, jnp.asarray(mask))
+    want = gar.tree_aggregate(poisoned, f=F, key=key)
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
+        ),
+        got, want,
+    )
+
+
+def test_folded_median_lie_at_f1_takes_the_second_smallest_honest_row(
+        kernel_in_interpret_mode):
+    """lie at f = 1 (lfm2n4's traffic): the cohort's Bessel deviation is
+    0 / 0, the fake row all NaN and last in the order, so the median of
+    four is the second smallest of the three honest rows."""
+    n = 4
+    tree = _conv_tree(jax.random.PRNGKey(5), n=n, dtype=jnp.bfloat16)
+    plan = plan_gradient_attack_fold("lie", core.default_byz_mask(n, 1))
+    got = folded_tree_aggregate(gars["median"], plan, tree, f=1)
+    want = jax.tree.map(lambda l: jnp.sort(l[:3], axis=0)[1], tree)
+    jax.tree.map(
+        lambda a, b: np.testing.assert_array_equal(
+            np.asarray(a, np.float32), np.asarray(b, np.float32)),
+        got, want,
+    )
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of what it calls, except inside a
+    Pallas kernel's body, with the branch index path it lies on."""
+    def walk(jaxpr, path):
+        for eqn in jaxpr.eqns:
+            yield path, eqn
+            if eqn.primitive.name == "pallas_call":
+                continue
+            if eqn.primitive.name == "cond":
+                for i, branch in enumerate(eqn.params["branches"]):
+                    yield from walk(branch.jaxpr, path + ((id(eqn), i),))
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub, path)
+    return list(walk(jaxpr, ()))
+
+
+def test_folded_median_of_a_bf16_tree_copies_no_stack(monkeypatch):
+    """On the kernel's branch the folded median holds no ``concatenate``
+    along the worker axis and no ``convert_element_type`` of an (n, ·) or
+    (n + 1, ·) operand: the stack goes to the kernel as it lies, the fake
+    row beside it. (The XLA fallback's branch of ``platform_dependent`` may
+    concatenate.)"""
+    from garfield_tpu.ops import coordinate
+
+    monkeypatch.setattr(
+        coordinate, "use_pallas", lambda n=None, op=None: True)
+    tree = _conv_tree(jax.random.PRNGKey(1), dtype=jnp.bfloat16)
+    tree.pop("bias"), tree.pop("scale")  # vectors: flat, upcast outside
+    plan = plan_gradient_attack_fold("lie", core.default_byz_mask(N, F))
+    jaxpr = jax.make_jaxpr(
+        lambda t: folded_tree_aggregate(gars["median"], plan, t, f=F)
+    )(tree).jaxpr
+    eqns = _eqns(jaxpr)
+    kernels = [path for path, e in eqns if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 3 and all(kernels)  # one a leaf, each in a branch
+    on_kernel_branch = [  # at the top, or down some kernel's own branch
+        e for path, e in eqns
+        if any(path == kpath[:len(path)] for kpath in kernels)
+    ]
+    assert on_kernel_branch
+    for eqn in on_kernel_branch:
+        rows = [v.aval.shape[0] for v in eqn.invars
+                if getattr(v.aval, "ndim", 0) >= 2]
+        if eqn.primitive.name == "concatenate":
+            assert eqn.params["dimension"] != 0 or not any(
+                r in (N, N + 1) for r in rows), eqn
+        if eqn.primitive.name == "convert_element_type":
+            assert not any(r in (N, N + 1) for r in rows), eqn
+
+
+def test_the_coordinate_line_is_logged_once_per_trace(capsys):
+    tree = _conv_tree(jax.random.PRNGKey(2), dtype=jnp.bfloat16)
+    plan = plan_gradient_attack_fold("lie", core.default_byz_mask(N, F))
+    step = jax.jit(
+        lambda t: folded_tree_aggregate(gars["median"], plan, t, f=F))
+    step(tree), step(tree)  # the second call traces nothing
+    lines = [l for l in capsys.readouterr().err.splitlines()
+             if "[coordinate] median:" in l]
+    assert len(lines) == 1
+    assert "in place 3 leaves / 0.01M values, flat 2 / 0.00M" in lines[0]
+    assert "block (8, 8, 128) bfloat16, fake row apart" in lines[0]

@@ -6,6 +6,8 @@ bulyan.py:77-84); the kernels must match them bit-for-bit, including NaN
 placement and stable tie-breaking.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -412,3 +414,184 @@ class TestSortNetSelection:
             coordinate.sortnet_top_m(x, 0)
         with pytest.raises(ValueError, match=r"k must be in \[1, 8\]"):
             coordinate.sortnet_row_sums(x, 9)
+
+
+# --- the operands where they lie (PR 29): the fake row a second operand,
+# --- half-precision rows upcast in VMEM, the worker axis leading -----------
+
+_DTYPES = [jnp.bfloat16, jnp.float16, jnp.float32]
+
+
+def _stack_and_fake(n, tail, dtype, seed, fake="normal"):
+    rng = np.random.default_rng(seed)
+    g = jnp.asarray(rng.standard_normal((n,) + tail), dtype)
+    e = jnp.asarray(rng.standard_normal(tail), dtype)
+    if fake == "nan":
+        e = jnp.full(tail, jnp.nan, dtype)
+    return g, e
+
+
+def _written_out(g, e, row_map, row_scale):
+    """The logical rows as f32, written out the slow way: ``scale *
+    ext[row_map]`` with exact zeros under a zero scale."""
+    ext = np.concatenate(
+        [np.asarray(g, np.float32), np.asarray(e, np.float32)[None]]
+    ).reshape(g.shape[0] + 1, -1)
+    with np.errstate(invalid="ignore"):  # 0 * inf, replaced just below
+        rows = ext[np.asarray(row_map)] * np.asarray(
+            row_scale, np.float32)[:, None]
+    rows[np.asarray(row_scale) == 0.0] = 0.0
+    return jnp.sort(jnp.asarray(rows), axis=0)  # ascending, NaN last
+
+
+@jax.jit
+def _chain_mean(rows):
+    """The kernels' mean: rows added in order, one division — jitted like
+    the kernel's body, so that XLA treats the division by a constant the
+    same way on both sides."""
+    acc = rows[0]
+    for i in range(1, rows.shape[0]):
+        acc = acc + rows[i]
+    return acc / rows.shape[0]
+
+
+def _expect(op, sorted_rows, f):
+    n = sorted_rows.shape[0]
+    if op == "median":
+        return sorted_rows[(n - 1) // 2]
+    return _chain_mean(sorted_rows[f:n - f])
+
+
+def _run(op, g, e, row_map, row_scale, f, tile=128):
+    kwargs = dict(extra=e, row_map=row_map, row_scale=row_scale,
+                  interpret=True, tile=tile)
+    if op == "median":
+        return coordinate.coordinate_median(g, **kwargs)
+    return coordinate.trimmed_mean(g, f, **kwargs)
+
+
+def _lie_plan(n, f):
+    """Lie's remap: the f Byzantine rows (the last f) all read the fake
+    row, index n — a duplicated map where f > 1."""
+    return np.array(list(range(n - f)) + [n] * f), np.ones(n)
+
+
+@pytest.mark.parametrize("dtype", _DTYPES, ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("n", [2, 4, 5, 8, 16, 17, 32])
+@pytest.mark.parametrize("op", ["median", "tmean"])
+def test_fake_row_apart_matches_sorted_rows(op, n, dtype):
+    """The kernel over (stack, fake row) against ``jnp.sort`` over the
+    written-out rows, bit for bit, in the operand's own dtype."""
+    f = max(1, n // 4)
+    g, e = _stack_and_fake(n, (24, 128), dtype, seed=n)
+    row_map, row_scale = _lie_plan(n, f)
+    trim = min(f, (n - 1) // 2)
+    got = _run(op, g, e, row_map, row_scale, trim)
+    want = _expect(op, _written_out(g, e, row_map, row_scale), trim)
+    assert got.dtype == jnp.dtype(dtype) and got.shape == (24, 128)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32), np.asarray(want.astype(dtype), np.float32)
+        .reshape(24, 128))
+
+
+def _plans(n):
+    ident = np.arange(n)
+    byz3 = np.arange(n) >= n - 3
+    return {
+        # lie at f = 1: the cohort's Bessel deviation is 0/0, the row NaN
+        "lie_f1_nan_fake": (np.where(ident == n - 1, n, ident), np.ones(n)),
+        # crash: a zero scale over a row that holds inf and NaN
+        "crash_nonfinite": (ident, np.where(byz3, 0.0, 1.0)),
+        "lie_f3_duplicated": (np.where(byz3, n, ident), np.ones(n)),
+        "reverse_scale": (ident, np.where(byz3, -100.0, 1.0)),
+    }
+
+
+@pytest.mark.parametrize("dtype", _DTYPES, ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("plan", list(_plans(8)))
+@pytest.mark.parametrize("op", ["median", "tmean"])
+def test_fake_row_apart_under_each_attack_plan(op, plan, dtype):
+    n = 8
+    g, e = _stack_and_fake(
+        n, (3, 3, 16, 128), dtype, seed=5,
+        fake="nan" if plan == "lie_f1_nan_fake" else "normal")
+    if plan == "crash_nonfinite":
+        g = g.at[n - 1].set(jnp.inf).at[n - 2, 0].set(jnp.nan)
+    row_map, row_scale = _plans(n)[plan]
+    got = _run(op, g, e, row_map, row_scale, 3)
+    want = _expect(op, _written_out(g, e, row_map, row_scale), 3)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32).reshape(-1),
+        np.asarray(want.astype(dtype), np.float32))
+    if plan != "reverse_scale" or op == "median":
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+
+
+@pytest.mark.parametrize("dtype", _DTYPES, ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("tail,tile,why", [
+    ((7, 19), 128, "size no multiple of 128, taken whole in the lanes"),
+    ((300,), 128, "flat, its last block ragged in the lanes"),
+    ((3, 3, 8, 64), 128, "a last axis of 64, tap-major"),
+    ((40, 128), 2048, "in place, its last block of rows ragged"),
+    ((2, 50, 256), None, "a stack of matrices, rows collapsed, two lane blocks"),
+    ((5,), None, "a vector under one tile"),
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_views_and_ragged_blocks(tail, tile, why, dtype):
+    n = 5
+    g, e = _stack_and_fake(n, tail, dtype, seed=len(tail))
+    row_map, row_scale = _lie_plan(n, 1)
+    got = _run("median", g, e, row_map, row_scale, 0, tile=tile)
+    want = _expect("median", _written_out(g, e, row_map, row_scale), 0)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32).reshape(-1),
+        np.asarray(want.astype(dtype), np.float32))
+
+
+def test_view_is_a_function_of_shape_and_dtype():
+    view = coordinate._view
+    # worker-major for matrices, tap-major for convolution kernels, flat
+    # for vectors and flat stacks; bf16 blocks in whole (16, 128) tiles
+    assert view(5, 4, (16384, 2048), jnp.bfloat16) == coordinate._View(
+        True, False, (1, 16384, 2048), (480, 512), (80, 128))
+    assert view(5, 4, (8, 2048, 1792), jnp.bfloat16) == coordinate._View(
+        True, False, (8, 2048, 1792), (960, 256), (80, 128))
+    assert view(17, 16, (3, 3, 256, 256), jnp.bfloat16) == coordinate._View(
+        True, True, (9, 256, 256), (256, 256), (16, 128))
+    assert view(17, 16, (2048, 100), jnp.bfloat16).block == (640, 100)
+    assert view(17, 16, (2048,), jnp.float32) == coordinate._View(
+        False, False, (2048,), (2048,), (2048,))
+    assert view(33, 32, (11_000_000,), jnp.float32).block == (8192,)
+    # the double-buffered block stays under a few MB at MAX_SORT_N
+    v = view(33, 32, (4096, 1024), jnp.float32)
+    assert 2 * 34 * v.block[0] * v.block[1] * 4 <= 5 << 20
+    with pytest.raises(ValueError):
+        view(5, 4, (300,), jnp.float32, tile=100)
+
+
+@pytest.mark.parametrize("dtype", _DTYPES, ids=lambda d: jnp.dtype(d).name)
+@pytest.mark.parametrize("s,beta", [(4, 2), (5, 3), (8, 4), (13, 7), (17, 9)])
+def test_bulyan_second_phase_matches_sorted_rows(s, beta, dtype):
+    """``averaged_median_mean`` through the same call, in the operand's own
+    dtype: the beta rows nearest the lower median (deviations rounded to
+    the dtype, ties to the lowest row), summed in that order in f32."""
+    g = jnp.asarray(
+        np.random.default_rng(s).standard_normal((s, 6, 128)), dtype)
+    got = coordinate.averaged_median_mean(g, beta, interpret=True, tile=128)
+    rows = np.asarray(g, np.float32).reshape(s, -1)
+    med = np.asarray(jnp.sort(jnp.asarray(rows), axis=0))[(s - 1) // 2]
+    dev = np.asarray(
+        jnp.asarray(np.abs(rows - med)).astype(dtype), np.float32)
+    order = np.argsort(dev, axis=0, kind="stable")[:beta]
+    picked = jnp.asarray(np.take_along_axis(rows, order, axis=0))
+    want = _chain_mean(picked).astype(dtype)
+    assert got.dtype == jnp.dtype(dtype) and got.shape == (6, 128)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32).reshape(-1), np.asarray(want, np.float32))
+
+
+def test_fake_row_shape_is_checked():
+    g, e = _stack_and_fake(4, (8, 128), jnp.float32, seed=0)
+    with pytest.raises(ValueError, match="fake row"):
+        coordinate.coordinate_median(g, extra=e[:4], row_map=[0, 1, 2, 4])
+    with pytest.raises(ValueError):  # index 5: past the fake row
+        coordinate.coordinate_median(g, extra=e, row_map=[0, 1, 2, 5])

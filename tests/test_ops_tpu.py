@@ -88,3 +88,31 @@ def test_remap_kernel_on_device():
         jnp.asarray(eff, jnp.float32)
     ), np.float32)
     np.testing.assert_allclose(got, want, rtol=1e-2, atol=1e-2)
+
+
+@pytest.mark.parametrize("n,f,tail", [
+    (4, 1, (2048, 1792)),     # worker-major, blocks of (480, 128), a ragged one
+    (16, 3, (3, 3, 256, 256)),  # tap-major, chunks of one (16, 128) tile a row
+    (16, 3, (2048, 100)),     # a last axis of 100 taken whole
+    (5, 1, (20000,)),         # flat, its last block ragged mid-tile
+])
+def test_operands_in_place_on_device(n, f, tail):
+    """The block shapes of PR 29 through real Mosaic lowering, in bf16: the
+    stack read where it lies, upcast in VMEM, the fake row (all NaN, as
+    lie at f = 1 makes it) a second operand — against ``jnp.sort`` over
+    the written-out rows, bit for bit."""
+    rng = np.random.default_rng(n)
+    g = jnp.asarray(rng.standard_normal((n,) + tail), jnp.bfloat16)
+    e = (jnp.full(tail, jnp.nan, jnp.bfloat16) if f == 1 else
+         jnp.asarray(rng.standard_normal(tail), jnp.bfloat16))
+    row_map = np.array(list(range(n - f)) + [n] * f)
+    row_scale = np.array([-2.0] + [1.0] * (n - 1))
+    got = coordinate.coordinate_median(
+        g, extra=e, row_map=row_map, row_scale=row_scale)
+    ext = jnp.concatenate([g, e[None]]).astype(jnp.float32)
+    eff = ext[row_map] * jnp.asarray(row_scale, jnp.float32).reshape(
+        (-1,) + (1,) * len(tail))
+    want = jnp.sort(eff, axis=0)[(n - 1) // 2].astype(jnp.bfloat16)
+    assert got.dtype == jnp.bfloat16 and got.shape == tail
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32), np.asarray(want, np.float32))
