@@ -14,7 +14,7 @@ WORKDIR /app
 COPY pyproject.toml README.md ./
 COPY garfield_tpu ./garfield_tpu
 COPY tests ./tests
-COPY bench.py chip_smoke.py __graft_entry__.py BASELINE.json ./
+COPY chip_smoke.py __graft_entry__.py BASELINE.json ./
 
 RUN pip install --no-cache-dir "jax[cpu]" flax optax orbax-checkpoint \
         chex einops pytest && \

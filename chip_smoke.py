@@ -17,8 +17,8 @@ synthetic surrogate for data, random weights from a seed):
                   steps of Multi-Krum with accuracy evaluations and WITHOUT
                   ``--bench``, so async dispatch, TrainState donation and
                   the eval side thread all run;
-  trainer_median  ``parallel.aggregathor.make_trainer`` (the entry
-                  ``bench.py`` uses) with median + lie, AOT compiled, a few
+  trainer_median  ``parallel.aggregathor.make_trainer`` (the entry the
+                  benchmark's harness uses) with median + lie, AOT compiled, a few
                   steps ended by ``block_until_ready``; the compiled step
                   must hold the Mosaic custom call — the rule inside the
                   step is the kernel, not its XLA fallback.
@@ -272,7 +272,8 @@ def leg_trainer_krum(size):
 
 
 def leg_trainer_median(size):
-    """``make_trainer`` as ``bench.py`` calls it: median under lie, AOT."""
+    """``make_trainer`` as the benchmark's harness calls it: median under
+    lie, AOT."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -374,10 +375,9 @@ def main():
     import jax
     import jaxlib
 
-    import bench
     from garfield_tpu.utils import profiling
 
-    bench.peak_bf16(device)  # a device kind without a published peak: error
+    profiling.peak_bf16(device)  # a device kind without a published peak: error
     device_facts = {
         "platform": device.platform,
         "kind": device.device_kind,

@@ -23,7 +23,7 @@ from ._common import as_stack, num_gradients, pairwise_distances
 
 # Enumeration guard: C(n, n-f) combinations are materialized as one index
 # tensor; keep the same practical bound the reference applies to its brute
-# sweeps (benchmarks/gar_bench.py bounds n for brute).
+# sweeps.
 MAX_COMBINATIONS = 2_000_000
 
 

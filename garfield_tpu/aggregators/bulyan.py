@@ -58,11 +58,10 @@ def _selection_weight_matrix(dist, n, f, m, dtype, use_sortnet=None):
     masked matrix carries only finite values and +inf, never NaN). Unlike
     krum, this is OPT-IN rather than env-default: the fori_loop re-sorts
     the masked n x n matrix every round, so the network's O(n^2) exchange
-    rounds compound — SELBENCH_r01 measured it slower than the XLA sort
-    at every bucket size (265.61 vs 103.46 us/bucket at n=16, 7950.73 vs
-    1039.06 at n=32). GARFIELD_SORTNET_SELECT therefore does not reach
-    this loop; pass ``use_sortnet=True`` to A/B it (gar_bench --selection
-    does).
+    rounds compound (slower than the XLA sort at every bucket size:
+    XLA:CPU, round 19; not measured on the chip). GARFIELD_SORTNET_SELECT
+    therefore does not reach this loop; pass ``use_sortnet=True`` to A/B
+    it.
     """
     m_max = n - f - 2
     rounds = n - 2 * f - 2

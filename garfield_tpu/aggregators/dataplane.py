@@ -1,6 +1,6 @@
 """Data-plane defense: per-class gradient fingerprints + two detectors.
 
-The one cell the GAR-side stack cannot touch (DEFBENCH_r02, DESIGN.md
+The one cell the GAR-side stack cannot touch (XLA:CPU, round 15; DESIGN.md
 §17): a low-``poison_frac`` BadNets backdoor submits HONEST gradients of
 a poisoned task — in-distribution rows, nothing divergence-shaped for
 Gram distances, suspicion weighting or the escalation ladder to measure
@@ -46,7 +46,7 @@ weighs exactly 1.0, and occasional single-round false flags wash out in
 the EMA instead of down-weighting an honest rank. The COMPOSITION of
 those weights is deliberately different, and the measured negative
 result behind it is recorded here: multiplying data-plane weights into
-the row-scale slot (the staleness algebra) made DEFBENCH's backdoor
+the row-scale slot (the staleness algebra) made defense_bench's backdoor
 cell WORSE than undefended (ASR 0.97 vs 0.10) — a toward-zero-scaled
 cohort row lands where late-training honest gradients cluster, so krum
 ADMITS it (the same inlier inversion that puts r02's
@@ -467,7 +467,7 @@ def center_pull_rows(rows, w):
     w_j`` — rows the EMA trusts at ~1.0 define it; flagged rows barely
     contribute).
 
-    Two measured negative results shaped this (DEFBENCH probes,
+    Two measured negative results shaped this (defense_bench probes,
     recorded in DESIGN.md §18):
 
       - Plain row SCALING (the staleness/GAR-suspicion algebra) is the

@@ -35,7 +35,7 @@ bursts ARE excluded, concentration rises, and escalation swaps in a rule
 with a lower admission threshold (Bulyan's trimmed phase bounds exactly
 the coordinate-wise excess the lie attack injects); the attacker's
 bracket then re-closes at a smaller magnitude, and the accuracy bar is
-restored (the committed DEFBENCH_r01 record).
+restored (XLA:CPU, round 14).
 """
 
 import dataclasses
@@ -414,7 +414,7 @@ class DefensePlan:
 # --defense mode table: (weighted, escalate, data). The GAR-side modes
 # compose with the data plane via "+data" — the two defenses run
 # SIMULTANEOUSLY (independent evidence, one row-weight algebra), which
-# is how DEFBENCH_r03's backdoor bar is met without giving up the
+# is how the backdoor bar is met (XLA:CPU, round 16) without giving up the
 # adaptive-lie coverage the ladder provides.
 DEFENSE_MODES = {
     "weighted": (True, False, False),

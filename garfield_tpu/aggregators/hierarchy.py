@@ -2,8 +2,8 @@
 
 The flat GARs are built for tens of workers: every rule makes one pass over
 an (n, d) stack, the coordinate kernels fall off the Pallas fast path past
-``MAX_SORT_N`` = 32 (ops/coordinate.py), and GARBENCH_r3/r4 show the
-single-shot rules stay graceful only to n ≈ 512. Federated scale — the
+``MAX_SORT_N`` = 32 (ops/coordinate.py), and the single-shot rules stay
+graceful only to n ≈ 512 (XLA:CPU, rounds 3-4). Federated scale — the
 ROADMAP's "millions of users" — needs Byzantine resilience that COMPOSES:
 
   1. partition the n client gradients into buckets of ≤ ``bucket_size``
@@ -43,7 +43,7 @@ order over the host plane: each pushed vector fills the current bucket;
 completed buckets fold in vmapped waves the moment they close, and their
 summaries cascade up the level states the same way. Peak memory is
 O(wave · bucket_size · d) per level — O(log n) buffers, NOT O(n · d) — so
-n = 2^17 clients at d = 1e5 fit the 1-core container (HIERBENCH_r01).
+n = 2^17 clients at d = 1e5 fit a 1-core container (XLA:CPU, round 10).
 ``push_frame``/``wire_transform`` accept typed wire frames (utils/wire.py);
 the transform plugs straight into ``PeerExchange.collect_begin`` so decode +
 bucket folding runs in the exchange's pre-registered waiter threads, and a
@@ -641,7 +641,7 @@ class StreamingAggregator:
         lands as one or two memcpys per drain cycle) instead of the
         per-row ``_push_one`` loop — at federated-shard widths (d/S a
         few thousand) the per-row Python overhead otherwise dominates
-        the fold and flattens the 1/S round-time scaling FEDBENCH
+        the fold and flattens the 1/S round-time scaling fed_bench
         measures. Fold boundaries are unchanged (``_drain`` triggers at
         the same cursor positions regardless of ingest granularity), so
         streaming-vs-batch bitwise equality holds verbatim.
@@ -1059,7 +1059,7 @@ class StreamingAggregator:
         hier_ingest record per wave — emitted even when the accumulated
         duration is zero (the stable path's whole point), so per-level
         span counts obey count(hier_ingest) == count(hier_wave) ==
-        count(hier_h2d) exactly (the FEDBENCH_r02 undercount fix).
+        count(hier_h2d) exactly (round 19's records undercounted ingest).
         """
         level = state["level"]
         if _trace.enabled():

@@ -33,7 +33,7 @@ from ..ops import coordinate as _coord
 def _sortnet_select(use_sortnet=None):
     """Whether the fast selection path is on: explicit override wins, else
     the ``GARFIELD_SORTNET_SELECT`` knob (default ON). Read at TRACE time —
-    callers that bench both paths (gar_bench --selection) must pass the
+    callers that compare both paths must pass the
     override explicitly so each impl gets its own jit closure instead of
     poisoning a shared cache with an env read."""
     if use_sortnet is not None:

@@ -1,3 +1,4 @@
-"""Microbenchmarks (P21): GAR kernel latency sweeps and collective-transfer
-latency, counterparts of pytorch_impl/applications/benchmarks/
-{gar_bench,rpc_bench}.py."""
+"""Scenario harnesses that drive a host plane end to end: the attack x
+defense matrix (``defense_bench``), sharded federated rounds
+(``fed_bench``) and the control plane under rolling restarts, partitions
+and churn (``soak_bench``). The benchmark is ``benchmark/run.py``."""

@@ -55,7 +55,7 @@ JSONL twin; the .json artifact adds the derived acceptance verdicts.
 Run (CPU container, ~2-4 min):
 
   python -m garfield_tpu.apps.benchmarks.defense_bench \
-      --out DEFBENCH_r01 --num_iter 240
+      --out DEFBENCH --num_iter 240
 """
 
 import argparse
@@ -582,7 +582,7 @@ def run_grid(args):
         "clean_asr": clean_asr,
         # --- r03 gates: the data-plane defense bar (ISSUE 12) ----------
         # The composed loop drops the backdoor trigger ASR to <=
-        # --asr_bar (vs ~0.6 GAR-only in DEFBENCH_r02) while the SAME
+        # --asr_bar (vs ~0.6 GAR-only: XLA:CPU, round 15) while the SAME
         # cell's clean accuracy stays within --acc_margin of the bar...
         "backdoor_data_asr_bar": bool(
             by["grad/backdoor/escalate+data"]["asr"] is not None

@@ -11,11 +11,10 @@ Three checks, one committed artifact (schema v10 ``fed_bench`` rows):
     The container is 1-core, so shard processes run back to back and a
     cell's ``round_s`` is the MAX over its shard processes' per-round
     walls — the round time of the real deployment, where the S shards
-    are independent processes on S cores with no cross-shard traffic
-    (the same pacing-style argument EXCHBENCH's rank-0-paced rounds
-    make); ``round_s_sum`` records the serialized total so the 1-core
+    are independent processes on S cores with no cross-shard traffic;
+    ``round_s_sum`` records the serialized total so the 1-core
     provenance is never hidden. Gradients are simulated from two cycled
-    pools (generation outside the timed region, HIERBENCH's method);
+    pools (generation outside the timed region);
     every shard slices the SAME pool bytes, so cells differ only in
     shard width.
 
@@ -94,7 +93,7 @@ def _spawn_env():
 # Span-name -> artifact phase-name map for the child's per-phase digest
 # (schema v12): the trace plane's hierarchy spans keep their producer
 # names in the JSONL stream; the fed_bench row speaks the ISSUE's
-# vocabulary (ingest/h2d/fold/selection).
+# vocabulary (ingest/h2d/fold).
 _PHASE_NAMES = {
     "hier_ingest": "ingest",     # pre-timed, one per dispatched wave —
     #                              counts align 1:1 with h2d/fold (the
@@ -104,37 +103,7 @@ _PHASE_NAMES = {
     "hier_wave": "fold",         # wave dispatch (+ readback in sync mode)
     "hier_fold_wait": "fold_wait",  # double-buffer blocking readback
     "hier_finalize": "finalize",
-    "selection": "selection",    # the Gram-selection micro-probe below
 }
-
-
-def _selection_probe(server, wave, reps=24):
-    """Emit ``selection`` spans: the bucket rule's Gram selection at the
-    deployed level-0 bucket size, timed on a wave-shaped batch. The
-    selection runs FUSED inside the wave fold program (that fusion is
-    the point of the sortnet path), so it cannot be timed in situ — the
-    probe times the selection subgraph alone (Gram matmul + ranked
-    pick), gar_bench --selection's methodology at this cell's exact
-    (rule, bucket_size, d_shard). Median buckets have no selection
-    phase; their rows simply omit it."""
-    red = server._red
-    if red is None or not red._levels:
-        return
-    level = red._levels[0]["level"]
-    if level.rule not in ("krum", "bulyan"):
-        return
-    import jax
-
-    from ...telemetry import trace as tele_trace
-    from .gar_bench import _selection_fn
-
-    s = max(level.sizes)
-    g = jax.random.normal(jax.random.PRNGKey(7), (wave, s, server.d_shard))
-    fn = jax.jit(_selection_fn(level.rule, level.f, True))
-    jax.block_until_ready(fn(g))  # compile + warm outside the spans
-    for _ in range(reps):
-        with tele_trace.span("selection", buckets=int(wave), size=int(s)):
-            jax.block_until_ready(fn(g))
 
 
 def _shard_run(args):
@@ -144,8 +113,7 @@ def _shard_run(args):
     is a warmup (fold-program compiles) and is not reported. The child
     installs a private MetricsHub + trace for the timed rounds, so the
     line carries per-phase p50/p95 (schema v12): ingest waves, H2D
-    staging, wave fold dispatch/readback, and the selection micro-probe
-    (see _selection_probe)."""
+    staging and wave fold dispatch/readback."""
     from ... import federated as fed
     from ...telemetry import hub as tele_hub
     from ...telemetry import trace as tele_trace
@@ -192,7 +160,6 @@ def _shard_run(args):
         bytes_out = len(frame)
         if r > 0:
             walls.append(time.perf_counter() - t0)
-    _selection_probe(server, args.wave)
     phases = {
         _PHASE_NAMES.get(ph, ph): {
             "count": int(st["count"]),
@@ -309,7 +276,7 @@ def ingest_micro_cell(args):
     — the pre-ISSUE-20 ingest loop — and (b) in one
     ``decode_batch_into`` call into the same slab. Both paths are
     asserted bitwise-identical before any timing is committed, and
-    min-over-reps is recorded (the gar_bench timing discipline: the
+    min-over-reps is recorded (the usual micro-timing discipline: the
     floor is the signal on a noisy shared host). The ``--ingest_d``
     sweep brackets the claim: at small frames the per-frame Python
     header trip dominates and the vectorized screen wins; at the

@@ -471,19 +471,6 @@ def _robust_stats(rows, f):
     return np.mean(s[t:q - t], axis=0).astype(np.float32)
 
 
-def _eager_h2d():
-    """Whether decoded rows are ``jax.device_put`` from the exchange
-    waiter threads (overlapping H2D staging with the still-open quorum
-    and the local device step). Default on — jax dispatch is thread-safe
-    on the pinned jax/jaxlib; ``GARFIELD_EAGER_H2D=0`` opts out for a
-    backend where it is not."""
-    import os
-
-    return os.environ.get("GARFIELD_EAGER_H2D", "1").lower() not in (
-        "0", "false",
-    )
-
-
 class WireStats:
     """Per-role wire-plane accounting for the telemetry plane
     (docs/TELEMETRY.md): bytes and codec seconds, both directions,
@@ -673,11 +660,12 @@ def _frame_transform(split, stats=None, pass_empty=False, plane=0):
             exc.nbytes = len(payload)
             raise
         head, tail = vec[:d0], vec[d0:]
-        if _eager_h2d():
-            try:
-                head = jax.device_put(head)
-            except Exception:  # noqa: BLE001 — host row still works
-                pass  # jnp.stack uploads at harvest instead
+        # From the waiter thread (jax dispatch is thread-safe on the
+        # pinned jax/jaxlib): H2D staging overlaps the still-open quorum.
+        try:
+            head = jax.device_put(head)
+        except Exception:  # noqa: BLE001 — host row still works
+            pass  # jnp.stack uploads at harvest instead
         if stats is not None:
             stats.received(
                 len(payload), time.perf_counter() - t0, plane,
@@ -1038,7 +1026,7 @@ def run(args):
     # (machine-feature validation mismatch — observed on the dev image),
     # every jit pays a failed load per executable and the error spam +
     # retries starved worker startup past the PS's quorum budget. TPU
-    # entry points (bench.py, __graft_entry__) keep the cache, where it
+    # entry points (apps/common.train, __graft_entry__) keep the cache, where it
     # works and matters.
     cfg_probe = multihost.ClusterConfig(args.cluster)
     if cfg_probe.nodes or (args.task or "").startswith("node"):

@@ -25,19 +25,7 @@ import numpy as np
 from .. import data as data_lib, models as models_lib, parallel
 from ..utils import checkpoint as ckpt_lib, profiling, selectors, tools
 
-__all__ = ["base_parser", "build_ingredients", "chunk_length",
-           "peak_rss_bytes", "train"]
-
-
-def peak_rss_bytes():
-    """Process high-water RSS in bytes (``getrusage``) — the shared
-    flat-memory accounting every committed bench row carries
-    (HIERBENCH/EXCHBENCH/FEDBENCH; one definition so the artifacts stay
-    comparable). Monotone: record rows in ascending-size order so an
-    O(1)-memory claim reads as a flat profile."""
-    import resource
-
-    return int(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss) * 1024
+__all__ = ["base_parser", "build_ingredients", "chunk_length", "train"]
 
 
 def base_parser(description, *, default_model="convnet", default_loss="nll"):
@@ -162,7 +150,7 @@ def base_parser(description, *, default_model="convnet", default_loss="nll"):
            "membership's steady state is measured, not the transient).")
     a("--straggler_ms", type=int, default=0,
       help="Scenario-injection knob (the straggler half of the async "
-           "harness, exchange_bench --scenario): in cluster mode THIS "
+           "plane's tests): in cluster mode THIS "
            "worker sleeps the given milliseconds after each gradient "
            "compute before publishing — a reproducible 'slow rank'. "
            "0 (default) disables; ignored on-mesh and on PS roles.")

@@ -303,7 +303,7 @@ def plan_gradient_attack_fold(attack, byz_mask, *, z=LIE_Z, eps=EMPIRE_EPS,
     """Return the ``GradientAttackFold`` for ``attack``, or None when the
     attack has no folded form (randomized rows, or no Byzantine slots, or
     ``GARFIELD_NO_FOLD`` set to any non-empty value — the A/B escape
-    hatch, same any-value convention as GARFIELD_NO_PALLAS)."""
+    hatch)."""
     import os
 
     import numpy as np

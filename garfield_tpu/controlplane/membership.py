@@ -1,7 +1,7 @@
 """Epoch-numbered membership views: who owns which span, provably.
 
 The deployment layer the paper era never needed (ROADMAP item 3,
-DESIGN.md §22): FEDBENCH proved 10^6 clients/round with S shard
+DESIGN.md §22): fed_bench ran 10^6 clients/round with S shard
 processes sharing one host and STATIC membership — no record of which
 host:port serves which span, so a failover or a span split has nowhere
 to publish the new truth and no way to invalidate the old one. This

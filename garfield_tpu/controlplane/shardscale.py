@@ -3,8 +3,8 @@
 ``utils.autoscale.AutoscaleController`` provisions WORKERS — more
 gradient producers per second for the async plane. This module points
 the same control law (mean-rate window, hysteresis, cooldown — and now
-``rescind``) at the OTHER capacity axis: the PS shard count. FEDBENCH
-measured round time scaling ~1/S because every shard folds only d/S of
+``rescind``) at the OTHER capacity axis: the PS shard count. Round
+time scaled ~1/S (XLA:CPU, round 17) because every shard folds only d/S of
 each client, so under round-latency pressure the right move is a span
 SPLIT (S -> S+1, each shard thinner), and under sustained headroom a
 MERGE (S -> S-1, fewer processes doing the same work). The controller

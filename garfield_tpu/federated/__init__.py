@@ -6,8 +6,8 @@ parameter vector across a PS shard group (the axis orthogonal to MSMW
 replication), ``sampler`` prices a Byzantine budget per sampled cohort,
 ``engine`` runs the round loop (ingest -> per-shard hier-GAR ->
 shard broadcast), and ``fleet`` drives simulated client processes
-against a target round rate. ``apps/benchmarks/fed_bench.py`` is the
-committed-record entry point (FEDBENCH_r*).
+against a target round rate. ``apps/benchmarks/fed_bench.py`` drives them
+end to end.
 """
 
 from .engine import FedRoundEngine, ShardServer

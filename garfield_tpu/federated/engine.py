@@ -16,8 +16,8 @@ Bitwise anchor: at S=1 with full participation and no stragglers the
 engine IS the existing unsharded single-PS streaming path — same
 ``StreamingAggregator`` programs over the same arrival order, same
 ``model -= lr * agg`` update — so its trajectory is bitwise equal to
-the pre-sharding path (pinned in tests/test_federated.py and recorded
-as ``s1_bitwise_equal`` in FEDBENCH_r01).
+the pre-sharding path (pinned in tests/test_federated.py; fed_bench's
+``s1_bitwise`` check records it as ``s1_bitwise_equal``).
 
 Why selection is per shard: each shard's hierarchy sees only its column
 span, so krum's inlier geometry (and therefore which clients a bucket
@@ -27,8 +27,8 @@ Byzantine client must now defeat S independent robust folds to corrupt
 the full vector, and each shard's f-composition contract holds verbatim
 over its own slice (every cohort member contributes exactly one row per
 shard). The flip side — a sharded fold is NOT bitwise the unsharded
-fold for S > 1 — is documented in DESIGN.md §19, measured in
-FEDBENCH_r01, and never hidden behind the S=1 anchor.
+fold for S > 1 — is documented in DESIGN.md §19 (XLA:CPU, round 17)
+and never hidden behind the S=1 anchor.
 
 Telemetry (schema v10): one ``fed_round`` event per round (cohort size,
 f budget, realized-Byzantine audit when the driver knows ground truth,
@@ -303,7 +303,7 @@ class FedRoundEngine:
         # enforcement — every shard decodes wire frames with
         # expect_epoch, and each failover / split / merge bumps the
         # epoch (``bump_epoch``). None keeps the pre-epoch wire format
-        # (committed FEDBENCH drivers send v1 frames).
+        # (fed_bench's drivers send v1 frames).
         self.epoch = None if epoch is None else wire.check_epoch(epoch)
         self._ckpt_dir = (
             None if checkpoint_dir is None else str(checkpoint_dir)

@@ -22,7 +22,7 @@ pins both sides).
 
 Sampling is seeded and deterministic in ``(seed, round)`` — every shard
 process derives the SAME cohort without coordination (the sampler is
-metadata, not state), and a committed FEDBENCH row is reproducible.
+metadata, not state), and a fed_bench row is reproducible.
 Client identity is the STABLE GLOBAL id, never the per-round cohort
 index: suspicion keyed by cohort position would reset every round, which
 is a free laundering channel for any resampled Byzantine client
@@ -128,7 +128,7 @@ class CohortSampler:
     def realized_byzantine(self, cohort_ids, byz_ids):
         """How many of ``byz_ids`` (global ids) the cohort sampled — the
         simulation/audit-side ground truth the budget is checked against
-        in FEDBENCH rows and the composition tests."""
+        in fed_bench rows and the composition tests."""
         return int(np.isin(
             np.asarray(cohort_ids), np.asarray(list(byz_ids))
         ).sum())

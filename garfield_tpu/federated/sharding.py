@@ -8,7 +8,7 @@ contiguous shards, each owned by a PS shard process that runs its own
 hierarchy levels (aggregators/hierarchy.py) and its own wire plane
 (utils/exchange.py register slots), so wave ingest, hier-GAR folds and
 model broadcast parallelize across shards — round time scales ~1/S
-(FEDBENCH_r01) because every shard touches only d/S of each client.
+(XLA:CPU, round 17) because every shard touches only d/S of each client.
 
 Shard identity on the wire
 --------------------------

@@ -36,7 +36,6 @@ Design notes (see /opt/skills/guides/pallas_guide.md):
 
 import functools
 import math
-import os
 import warnings
 from typing import NamedTuple
 
@@ -100,9 +99,10 @@ def _warn_large_n(op, n):
 
 
 def use_pallas(n=None, op=None):
-    """True when the Pallas path should be used (TPU backend, n in range)."""
-    if os.environ.get("GARFIELD_NO_PALLAS"):
-        return False
+    """True when the Pallas path should be used: a TPU backend and n within
+    ``MAX_SORT_N``, nothing else. There is no switch: ``_dispatch`` picks
+    the fallback by the platform a computation is lowered for, and a test
+    that needs the other answer patches this function."""
     if n is not None and n > MAX_SORT_N:
         if op is not None and jax.default_backend() == "tpu":
             _warn_large_n(op, n)
@@ -410,8 +410,8 @@ def log_views(name, leaves, has_extra):
         f"{dtype.name}, "
         + ("fake row apart" if has_extra else "no fake row")
         + ("" if use_pallas(rows) else
-           "; no kernel here (XLA sort: no TPU backend, n > "
-           f"{MAX_SORT_N} or GARFIELD_NO_PALLAS)")
+           "; no kernel here (XLA sort: no TPU backend or n > "
+           f"{MAX_SORT_N})")
     )
 
 
@@ -809,8 +809,8 @@ def averaged_median_mean(g, beta, *, interpret=False, tile=None):
 
     Equivalent to ``averaged_median_mean_reference`` (ties broken stably by
     row index, NaN deviations sort last) but fused into a single HBM pass.
-    Off the Pallas path (n > MAX_SORT_N, non-TPU lowering, or
-    GARFIELD_NO_PALLAS) it uses the gather-free ``averaged_median_mean_xla``
+    Off the Pallas path (n > MAX_SORT_N or a non-TPU lowering) it uses the
+    gather-free ``averaged_median_mean_xla``
     — NOT the argsort+gather spec, whose gather is catastrophic at large d.
     """
     g = jnp.asarray(g)

@@ -21,6 +21,7 @@ Counterpart of the reference's node-role machinery, re-designed for SPMD:
     over RPC).
 """
 
+import os as _os
 import re
 
 import flax.struct
@@ -224,19 +225,14 @@ def segmented_aggregate(agg_fn, stack, segments):
 # krum+lie): vmap fallback 127 ms/step (12.6k img/s, compile 6 s) vs forced
 # unroll 103 ms/step (15.6k img/s, compile 136 s) — the relayout tax at
 # n=64 is ~19%, far below the 36-63% measured at n=8, and the unroll
-# amortizes its compile in ~5.4k steps. For reference-scale runs (100k
-# iters) raising the cap is a win: override with GARFIELD_UNROLL_MAX_SLOTS.
-import os as _os
-
-UNROLL_MAX_SLOTS = int(_os.environ.get("GARFIELD_UNROLL_MAX_SLOTS", 16))
+# amortizes its compile in ~5.4k steps.
+UNROLL_MAX_SLOTS = 16
 
 # Steps at which the unroll's compile-time premium amortizes against its
 # steady-state win over vmap. Both sides scale ~linearly in slots (compile
 # ~2 s/slot premium, win ~0.38 ms/step/slot at ResNet-18 scale, PERF.md
 # r4), so the breakeven is roughly slot-count independent.
-UNROLL_AMORTIZE_STEPS = int(
-    _os.environ.get("GARFIELD_UNROLL_AMORTIZE_STEPS", 6000)
-)
+UNROLL_AMORTIZE_STEPS = 6000
 
 
 def step_donation():
@@ -251,11 +247,8 @@ def step_donation():
     corrupted TrainState leaves (a resumed run's ``state.step`` reading
     an eval count) and native SIGSEGV/SIGABRT mid-run. Donation is only
     a memory-reuse optimization, so it is dropped on CPU; the device
-    backends keep it. ``GARFIELD_DONATE=0|1`` forces either choice.
+    backends keep it.
     """
-    forced = _os.environ.get("GARFIELD_DONATE", "").strip()
-    if forced in ("0", "1"):
-        return (0,) if forced == "1" else ()
     return () if jax.default_backend() == "cpu" else (0,)
 
 
@@ -264,12 +257,7 @@ def chunk_unroll(chunk_steps):
     XLA:CPU (the rolled while loop pins conv layouts at the loop boundary
     and per-iteration relayouts invert the chunk win — measured 2.6x
     WORSE than per-step on convnet/mnist, PERF.md r9), the rolled loop
-    (factor 1) on device backends. ``GARFIELD_CHUNK_UNROLL=<factor>``
-    forces a factor: 1 = rolled, >= chunk_steps = fully unrolled,
-    in between = partial."""
-    forced = _os.environ.get("GARFIELD_CHUNK_UNROLL", "").strip()
-    if forced:
-        return max(1, int(forced))
+    (factor 1) on device backends."""
     return chunk_steps if jax.default_backend() == "cpu" else 1
 
 

@@ -16,9 +16,8 @@ The repo's runtime observability layer (ISSUE 2). Three layers:
     *suspicion scores* — cumulative exclusion frequency under the active
     GAR, the audit signal that makes Byzantine ranks visible without
     ground truth.
-  - ``exporters``: schema-versioned JSONL (the format ``bench.py`` and
-    the bench artifacts adopt), Prometheus text exposition, and stdlib
-    schema validation so malformed artifacts fail loudly.
+  - ``exporters``: schema-versioned JSONL, Prometheus text exposition,
+    and stdlib schema validation so malformed records fail loudly.
 
 See docs/TELEMETRY.md for the record schema and overhead numbers.
 """

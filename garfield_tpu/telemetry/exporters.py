@@ -1,21 +1,19 @@
 """Telemetry exporters: schema-versioned JSONL, Prometheus text, validation.
 
 One record schema serves every producer (training loops, the cluster
-driver, ``bench.py``, ``gar_bench.py``) so consumers — the driver's
-BENCH_r* capture, dashboards, the tier-1 schema check — parse one format:
+driver, the scenario harnesses under ``apps/benchmarks``) so consumers —
+dashboards, ``telemetry.report``, the tier-1 schema check — parse one
+format:
 
     {"schema": "garfield-telemetry", "v": 1, "kind": <kind>, ...}
 
 Kinds: ``run`` (header: config/meta), ``step`` (per-step tap + loss +
 timing), ``event`` (liveness / exchange waits / wire accounting),
-``summary`` (run-closing suspicion + counters + wire totals), ``bench``
-(bench.py's north-star line), ``gar_bench`` (per-cell kernel latencies),
-``transfer_bench`` (mesh all-gather cells), and ``exchange_bench``
-(host-plane publish/collect cells — the wire-codec A/B record).
+``summary`` (run-closing suspicion + counters + wire totals), ``span``
+(one timed phase of a round), and one kind per scenario harness that
+stays: ``defense_bench``, ``fed_bench``, ``soak_bench``.
 ``validate_record`` / ``validate_jsonl`` are stdlib-only and run in the
-tier-1 suite, so a malformed artifact fails loudly instead of going dark
-(the round-5 bench capture that died rc=1 with nothing parseable is the
-post-mortem this subsystem exists for).
+tier-1 suite, so a malformed record fails loudly instead of going dark.
 """
 
 import json
@@ -27,75 +25,60 @@ __all__ = [
     "JsonlExporter",
     "make_record",
     "prometheus_text",
-    "append_record",
     "validate_record",
     "validate_jsonl",
 ]
 
 SCHEMA = "garfield-telemetry"
 # v2 (round 9): summary.step_time gained p50_s/p95_s/p99_s tail
-# percentiles (the chunked-dispatch win lives in the tail, not the mean)
-# and bench records gained the chunk_steps attribution field. v3 (round
-# 10): the ``hier_bench`` kind (hierarchical bucketed-GAR sweep cells —
-# HIERBENCH_r*'s format, with peak-RSS accounting), ``gar_bench`` rows may
-# carry ``peak_rss_bytes``, and bench error records may carry
-# ``backend_outage`` (optional; no producer emits it since PR 21). v4 (round 11,
-# the bounded-staleness async plane — DESIGN.md §14): the per-round
-# ``staleness`` EVENT (per-rank staleness + discount weights, validated
-# below), ``summary.staleness`` digest (count/mean/max/hist), and
-# ``exchange_bench`` rows may carry ``peak_rss_bytes`` plus the
-# straggler-scenario fields (``straggler_ms``, ``sync_round_s``,
-# ``async_round_s``, ``speedup``). v5 (round 12, distributed round
+# percentiles (the chunked-dispatch win lives in the tail, not the mean).
+# v4 (round 11, the bounded-staleness async plane — DESIGN.md §14): the
+# per-round ``staleness`` EVENT (per-rank staleness + discount weights,
+# validated below) and the ``summary.staleness`` digest
+# (count/mean/max/hist). v5 (round 12, distributed round
 # tracing — telemetry/trace.py): the ``span`` kind (one timed phase of
 # a round: ``phase``, wall-clock start ``t_wall``, monotonic ``dur_s``,
 # optional ``step``/``who``/``tid`` tags — the raw material of
-# ``telemetry.report``'s causal timeline), ``summary`` gained the
-# optional ``spans`` count + per-phase ``phases`` digest, and
-# ``exchange_bench`` rows may carry per-phase ``phases`` percentiles
-# plus the tracing A/B fields (``trace_off_round_s``,
-# ``trace_on_round_s``, ``trace_overhead``). v6 (round 13, elastic
-# asynchrony — DESIGN.md §15): exchange events are PLANE-TAGGED
+# ``telemetry.report``'s causal timeline) and ``summary`` gained the
+# optional ``spans`` count + per-phase ``phases`` digest. v6 (round 13,
+# elastic asynchrony — DESIGN.md §15): exchange events are PLANE-TAGGED
 # (``exchange_wait``/``staleness`` may carry ``plane``; per-step
 # ``wire`` events may carry a per-plane byte breakdown under
-# ``planes``), the new ``autoscale`` EVENT (action/rank/active/rate/
+# ``planes``) and the new ``autoscale`` EVENT (action/rank/active/rate/
 # target — validated below) with its ``summary.autoscale`` digest
 # (spawns/retires/active_workers) and the ``garfield_active_workers``
-# Prometheus gauge, and ``exchange_bench`` rows may carry the
-# scaleup/scaledown scenario fields (``pre_rate``, ``spike_rate``,
-# ``recovered_rate``, ``active_initial``, ``active_final``,
-# ``spawns``, ``retires``) plus the LEARN-scenario fields
-# (``learn_ms0_bitwise``). v7 (round 14, adaptive adversaries and the
+# Prometheus gauge. v7 (round 14, adaptive adversaries and the
 # closed-loop defense — DESIGN.md §16): the ``attack_adapt`` EVENT (one
 # adaptive-controller observation: magnitude played, detected verdict,
 # bracket), the ``defense_weights`` EVENT (the PS's per-round
 # suspicion-weight vector), the ``defense_escalate`` EVENT (one rule-
 # ladder transition), the ``attack_fallback`` EVENT (a randomized/
-# rotated attack keeping the where-path, emitted once — benches stop
-# misattributing fold-path wins), ``summary`` gained
-# ``suspicion_decayed``/``suspicion_halflife`` (the windowed score a
-# rotated cohort cannot launder) plus the ``defense``/``attack_adapt``
-# digests, and the new ``defense_bench`` kind (DEFBENCH_r*'s
-# accuracy-cell rows). Older records still validate — consumers key on
-# field presence, not version. v8 (round 15, the full threat-model
-# matrix — DESIGN.md §17): the ``ps_attack_adapt`` EVENT (one MODEL-
-# plane adaptive-controller observation — a Byzantine PS bisecting
-# against the replica gather, or a LEARN node against the gossip; same
-# fields as ``attack_adapt`` plus an optional ``plane`` tag), the
-# ``targeted_eval`` EVENT (the per-class eval digest: per-class
-# accuracy, source→target confusion, backdoor attack-success-rate —
-# what makes a suspicion-blind targeted attack measurable), ``summary``
-# gained the optional ``targeted`` digest (events/last_confusion/
-# last_asr), ``defense_weights`` events and ``defense_escalate`` events
-# may carry a ``plane`` tag (gradient/model/gossip — the per-plane
-# ladder deployment), and ``defense_bench`` rows may carry ``plane``/
-# ``confusion``/``asr``/``clean_confusion`` (the plane column and the
-# targeted rows' success metric). v9 (round 16, the data-plane defense —
-# DESIGN.md §18): the ``data_defense`` EVENT (one round of the
-# fingerprint detectors: per-rank spectral outlier ``scores``, the
-# tau-sigma/2-means ``flags``, the composed ``weights``, optional
-# ``ranks``/``plane`` attribution — validated below), ``summary`` gained
-# the optional ``data_defense`` digest (rounds/flagged/max_score/min_w)
-# and the ``garfield_dataplane_outlier_score`` Prometheus gauge,
+# rotated attack keeping the where-path, emitted once), ``summary``
+# gained ``suspicion_decayed``/``suspicion_halflife`` (the windowed
+# score a rotated cohort cannot launder) plus the ``defense``/
+# ``attack_adapt`` digests, and the new ``defense_bench`` kind
+# (defense_bench's accuracy-cell rows). Older records still validate —
+# consumers key on field presence, not version. v8 (round 15, the full
+# threat-model matrix — DESIGN.md §17): the ``ps_attack_adapt`` EVENT
+# (one MODEL-plane adaptive-controller observation — a Byzantine PS
+# bisecting against the replica gather, or a LEARN node against the
+# gossip; same fields as ``attack_adapt`` plus an optional ``plane``
+# tag), the ``targeted_eval`` EVENT (the per-class eval digest:
+# per-class accuracy, source→target confusion, backdoor
+# attack-success-rate — what makes a suspicion-blind targeted attack
+# measurable), ``summary`` gained the optional ``targeted`` digest
+# (events/last_confusion/last_asr), ``defense_weights`` events and
+# ``defense_escalate`` events may carry a ``plane`` tag (gradient/
+# model/gossip — the per-plane ladder deployment), and
+# ``defense_bench`` rows may carry ``plane``/``confusion``/``asr``/
+# ``clean_confusion`` (the plane column and the targeted rows' success
+# metric). v9 (round 16, the data-plane defense — DESIGN.md §18): the
+# ``data_defense`` EVENT (one round of the fingerprint detectors:
+# per-rank spectral outlier ``scores``, the tau-sigma/2-means
+# ``flags``, the composed ``weights``, optional ``ranks``/``plane``
+# attribution — validated below), ``summary`` gained the optional
+# ``data_defense`` digest (rounds/flagged/max_score/min_w) and the
+# ``garfield_dataplane_outlier_score`` Prometheus gauge,
 # ``targeted_eval`` events and ``defense_bench`` rows may carry
 # ``asr_baseline`` (the clean-model trigger-rate floor — ASR cells
 # report attributable lift, not raw rate), and ``defense_bench``
@@ -113,7 +96,7 @@ SCHEMA = "garfield-telemetry"
 # (rounds/shards/last_cohort/f_budget/budget_exceeded/mean_round_s +
 # ``top_clients``), the ``garfield_fed_*`` /
 # ``garfield_client_suspicion_decayed`` Prometheus series, and the new
-# ``fed_bench`` kind (FEDBENCH_r*'s rows: the 1/S shard-scaling cells,
+# ``fed_bench`` kind (fed_bench's rows: the 1/S shard-scaling cells,
 # the S=1 bitwise anchor, the autoscaled fleet-rate cells).
 # v11 (round 18, the compressed wire — DESIGN.md §20): the ``wire``
 # EVENT gained the per-SCHEME byte breakdown (``schemes`` sub-object:
@@ -121,41 +104,24 @@ SCHEMA = "garfield-telemetry"
 # optional ``compression_ratio`` (send-side f32-equivalent bytes /
 # actual bytes this step) and ``ef_residual_norm`` (the gradient-plane
 # error-feedback accumulator's L2 norm) fields — all validated below —
-# ``summary`` gained the optional ``wire_schemes`` digest, the
+# ``summary`` gained the optional ``wire_schemes`` digest, and the
 # ``garfield_wire_bytes_total{scheme=}`` Prometheus counters landed
-# beside the direction-only totals, and ``exchange_bench`` rows may
-# carry the EXCHBENCH_r05 robustness-cell fields (``cell``,
-# ``final_accuracy``, ``attack_magnitude``, ``headroom``,
-# ``compression_ratio``, ``matched_accuracy``).
+# beside the direction-only totals.
 # v12 (round 19, kernel-grade robust selection — DESIGN.md §21):
-# ``fed_bench`` rows may carry a ``phases`` sub-object (the
-# exchange_bench v5 shape: phase name -> numeric stat object, here the
-# per-phase ingest/h2d/fold/selection p50/p95 from the trace plane — a
-# scaling row attributes WHERE its round time went, not just how much),
-# and ``gar_bench`` rows may carry the --selection micro-mode fields
-# (``grid``, ``impl`` — sortnet vs xla_sort as explicit closures —
-# ``wave_buckets``, ``per_bucket_s``), all validated below.
+# ``fed_bench`` rows may carry a ``phases`` sub-object (phase name ->
+# numeric stat object, here the per-phase ingest/h2d/fold p50/p95 from
+# the trace plane — a scaling row attributes WHERE its round time went,
+# not just how much), validated below.
 # v13 (round 20, the control plane — DESIGN.md §22): the ``membership``
 # EVENT (one membership change: the new ``epoch`` — or null on a
 # pre-epoch deployment — the ``action`` that caused it
 # (failover/split/merge), the affected ``shard`` when there is one, the
 # resulting ``num_shards``, and the round as ``step``), and the new
-# ``soak_bench`` kind (SOAKBENCH_r*'s rows: one sustained-load scenario
+# ``soak_bench`` kind (soak_bench's rows: one sustained-load scenario
 # each — steady / rolling_restart / partition / churn — with round
 # counts, p50/p95/p99 round latency from the trace plane, the
 # failover/partition/epoch accounting, and the measured
 # ``kill_cost_rounds`` for the mid-round-kill SLO).
-# v14 (round 21, slot-fused transformers — DESIGN.md §23): the new
-# ``trans_bench`` kind (TRANSBENCH_r*'s rows). Two row families share
-# it: A/B rows (one ``model`` x ``path`` cell — path ``fused`` is the
-# slot-fused twin, ``unrolled`` the per-slot reference loop — with
-# ``per_slot_grad_s``, ``speedup`` on the fused row, and the gar_bench
-# rep/trial/dce-guard columns) and robustness rows (``cell`` names the
-# scenario — e.g. ``backdoor/none`` vs ``backdoor/data`` — with
-# ``asr``, ``asr_baseline`` (the v9 attribution discipline: report
-# attributable lift, not raw rate), ``accuracy`` and ``defense``).
-# ``gar_bench`` --selection rows additionally sweep the
-# attention-shaped d regimes (heads * d_head * seq) — no new fields.
 # v15 (round 22, batched wire ingest — DESIGN.md §24): the new
 # ``ingest_batch`` EVENT (one bulk ``push_frames`` call on a shard
 # server: the ``shard``, how many ``frames`` arrived, how many were
@@ -164,15 +130,17 @@ SCHEMA = "garfield-telemetry"
 # per-frame decode, the wall ``dur_s``, and the round as ``step``),
 # the ``garfield_ingest_batch_seconds`` Prometheus series beside the
 # wire codec counters, and the ``fed_bench`` check="ingest_micro" row
-# family (INGESTBENCH_r*: batch-vs-per-frame decode isolation — extra
-# numeric columns like ``per_frame_s``/``batch_s``/``batch`` and a
-# ``scheme`` string ride the kind's open extra-field policy; the
-# required check/n/d/shards/gar envelope still applies).
-SCHEMA_VERSION = 15
+# family (batch-vs-per-frame decode isolation — extra numeric columns
+# like ``per_frame_s``/``batch_s``/``batch`` and a ``scheme`` string
+# ride the kind's open extra-field policy; the required
+# check/n/d/shards/gar envelope still applies).
+# v16 (PR 30): the kinds whose only reader was their own validator went
+# with the programs that wrote them; a line of one of them is refused as
+# any unknown kind is. No field of a kind that stays changed.
+SCHEMA_VERSION = 16
 
-KINDS = ("run", "step", "event", "summary", "bench", "gar_bench",
-         "transfer_bench", "exchange_bench", "hier_bench", "span",
-         "defense_bench", "fed_bench", "soak_bench", "trans_bench")
+KINDS = ("run", "step", "event", "summary", "span",
+         "defense_bench", "fed_bench", "soak_bench")
 
 
 def make_record(kind, **fields):
@@ -206,14 +174,6 @@ class JsonlExporter:
 
     def __exit__(self, *exc):
         self.close()
-
-
-def append_record(path, record):
-    """One-shot append (bench entry points: no long-lived exporter)."""
-    validate_record(record)
-    with open(path, "a") as fp:
-        fp.write(json.dumps(record) + "\n")
-    return record
 
 
 # --- validation (stdlib only) ----------------------------------------------
@@ -782,73 +742,9 @@ def validate_record(rec):
                         f"summary.autoscale.{key} must be a non-negative "
                         f"int, got {val!r}"
                     )
-    elif kind == "bench":
-        if not isinstance(rec.get("metric"), str):
-            _fail(f"bench.metric must be a string, got {rec.get('metric')!r}")
-        val = rec.get("value")
-        if val is not None and not _is_num(val):
-            _fail(f"bench.value must be a number or null, got {val!r}")
-        cs = rec.get("chunk_steps")
-        if cs is not None and (
-            not isinstance(cs, int) or isinstance(cs, bool) or cs < 1
-        ):
-            _fail(f"bench.chunk_steps must be a positive int, got {cs!r}")
-    elif kind == "gar_bench":
-        if not isinstance(rec.get("gar"), str):
-            _fail(f"gar_bench.gar must be a string, got {rec.get('gar')!r}")
-        for key in ("n", "f", "d"):
-            val = rec.get(key)
-            if not isinstance(val, int) or isinstance(val, bool):
-                _fail(f"gar_bench.{key} must be an int, got {val!r}")
-        lat = rec.get("latency_s")
-        if lat is not None and not _is_num(lat):
-            _fail(f"gar_bench.latency_s must be a number or null, got {lat!r}")
-        # v12: the --selection micro-mode columns (all optional — plain
-        # sweep rows predate them).
-        for key in ("grid", "impl"):
-            val = rec.get(key)
-            if val is not None and not isinstance(val, str):
-                _fail(
-                    f"gar_bench.{key} must be a string or null, got {val!r}"
-                )
-        wb = rec.get("wave_buckets")
-        if wb is not None and (
-            not isinstance(wb, int) or isinstance(wb, bool) or wb < 1
-        ):
-            _fail(
-                f"gar_bench.wave_buckets must be a positive int or null, "
-                f"got {wb!r}"
-            )
-        pb = rec.get("per_bucket_s")
-        if pb is not None and not _is_num(pb):
-            _fail(
-                f"gar_bench.per_bucket_s must be a number or null, got "
-                f"{pb!r}"
-            )
-    elif kind == "hier_bench":
-        if not isinstance(rec.get("gar"), str):
-            _fail(f"hier_bench.gar must be a string, got {rec.get('gar')!r}")
-        for key in ("n", "f", "d", "bucket_size", "levels", "num_buckets"):
-            val = rec.get(key)
-            if not isinstance(val, int) or isinstance(val, bool):
-                _fail(f"hier_bench.{key} must be an int, got {val!r}")
-        for key in ("latency_s", "per_client_s"):
-            val = rec.get(key)
-            if val is not None and not _is_num(val):
-                _fail(
-                    f"hier_bench.{key} must be a number or null, got {val!r}"
-                )
-        rss = rec.get("peak_rss_bytes")
-        if rss is not None and (
-            not isinstance(rss, int) or isinstance(rss, bool) or rss < 0
-        ):
-            _fail(
-                f"hier_bench.peak_rss_bytes must be a non-negative int or "
-                f"null, got {rss!r}"
-            )
     elif kind == "defense_bench":
         # v7: one accuracy cell of the adaptive-attack / closed-loop-
-        # defense record (DEFBENCH_r*): which attack faced which rule
+        # defense record: which attack faced which rule
         # under which defense, and where the accuracy landed.
         if not isinstance(rec.get("cell"), str) or not rec["cell"]:
             _fail(
@@ -910,7 +806,7 @@ def validate_record(rec):
                 f"or null, got {esc!r}"
             )
     elif kind == "fed_bench":
-        # v10: one FEDBENCH_r* row — a shard-scaling cell (check
+        # v10: one fed_bench row — a shard-scaling cell (check
         # "scaling"), the S=1 bitwise anchor ("s1_bitwise"), or an
         # autoscaled fleet-rate cell ("fleet").
         if not isinstance(rec.get("check"), str) or not rec["check"]:
@@ -953,8 +849,7 @@ def validate_record(rec):
         phases = rec.get("phases")
         if phases is not None:
             # v12: per-phase p50/p95 attribution on scaling rows
-            # (ingest/h2d/fold/selection from the trace plane) — the
-            # exchange_bench v5 shape, so readers share one parser.
+            # (ingest/h2d/fold from the trace plane).
             if not isinstance(phases, dict) or not all(
                 isinstance(v, dict) and all(_is_num(x) for x in v.values())
                 for v in phases.values()
@@ -978,7 +873,7 @@ def validate_record(rec):
                 f"or null, got {rss!r}"
             )
     elif kind == "soak_bench":
-        # v13: one SOAKBENCH_r* scenario row — sustained rounds through
+        # v13: one soak_bench scenario row — sustained rounds through
         # the federated engine under control-plane stress (steady /
         # rolling_restart / partition / churn), with the trace plane's
         # round-latency percentiles as the SLO columns.
@@ -1018,158 +913,6 @@ def validate_record(rec):
             _fail(
                 f"soak_bench.bitwise_equal must be a bool or null, "
                 f"got {bw!r}"
-            )
-    elif kind == "trans_bench":
-        # v14: one TRANSBENCH_r* row — either an A/B cell (fused twin
-        # vs unrolled per-slot reference on a transformer model) or a
-        # robustness/backdoor cell (ASR with baseline attribution).
-        if not isinstance(rec.get("check"), str) or not rec["check"]:
-            _fail(
-                f"trans_bench.check must be a non-empty string, got "
-                f"{rec.get('check')!r}"
-            )
-        if not isinstance(rec.get("model"), str) or not rec["model"]:
-            _fail(
-                f"trans_bench.model must be a non-empty string, got "
-                f"{rec.get('model')!r}"
-            )
-        for key in ("slots", "d"):
-            val = rec.get(key)
-            if not isinstance(val, int) or isinstance(val, bool) \
-                    or val < 1:
-                _fail(
-                    f"trans_bench.{key} must be a positive int, got "
-                    f"{val!r}"
-                )
-        for key in ("path", "cell", "defense", "backend"):
-            val = rec.get(key)
-            if val is not None and not isinstance(val, str):
-                _fail(
-                    f"trans_bench.{key} must be a string or null, got "
-                    f"{val!r}"
-                )
-        for key in ("seq", "heads", "depth", "reps", "trials", "steps"):
-            val = rec.get(key)
-            if val is not None and (
-                not isinstance(val, int) or isinstance(val, bool)
-                or val < 0
-            ):
-                _fail(
-                    f"trans_bench.{key} must be a non-negative int or "
-                    f"null, got {val!r}"
-                )
-        for key in ("per_slot_grad_s", "speedup", "asr", "asr_baseline",
-                    "accuracy"):
-            val = rec.get(key)
-            if val is not None and not _is_num(val):
-                _fail(
-                    f"trans_bench.{key} must be a number or null, "
-                    f"got {val!r}"
-                )
-        dg = rec.get("dce_guard")
-        if dg is not None and not isinstance(dg, bool):
-            _fail(
-                f"trans_bench.dce_guard must be a bool or null, got "
-                f"{dg!r}"
-            )
-        rss = rec.get("peak_rss_bytes")
-        if rss is not None and (
-            not isinstance(rss, int) or isinstance(rss, bool) or rss < 0
-        ):
-            _fail(
-                f"trans_bench.peak_rss_bytes must be a non-negative int "
-                f"or null, got {rss!r}"
-            )
-    elif kind == "transfer_bench":
-        for key in ("devices", "d"):
-            val = rec.get(key)
-            if not isinstance(val, int) or isinstance(val, bool):
-                _fail(f"transfer_bench.{key} must be an int, got {val!r}")
-        lat = rec.get("latency_s")
-        if lat is not None and not _is_num(lat):
-            _fail(
-                f"transfer_bench.latency_s must be a number or null, "
-                f"got {lat!r}"
-            )
-    elif kind == "exchange_bench":
-        for key in ("n", "d"):
-            val = rec.get(key)
-            if not isinstance(val, int) or isinstance(val, bool):
-                _fail(f"exchange_bench.{key} must be an int, got {val!r}")
-        if not isinstance(rec.get("wire"), str):
-            _fail(
-                f"exchange_bench.wire must be a string, got "
-                f"{rec.get('wire')!r}"
-            )
-        phases = rec.get("phases")
-        if phases is not None:
-            # v5: per-phase span percentiles on scenario / trace-A/B
-            # rows — the artifact attributes its speedups, not just
-            # reports them.
-            if not isinstance(phases, dict) or not all(
-                isinstance(v, dict) and all(_is_num(x) for x in v.values())
-                for v in phases.values()
-            ):
-                _fail(
-                    f"exchange_bench.phases must map phases to numeric "
-                    f"stat objects, got {phases!r}"
-                )
-        cell = rec.get("cell")
-        if cell is not None and not isinstance(cell, str):
-            # v11: EXCHBENCH_r05 robustness-matrix cells carry a cell
-            # label (scheme x attack) like the DEFBENCH rows do.
-            _fail(
-                f"exchange_bench.cell must be a string or null, got "
-                f"{cell!r}"
-            )
-        ma = rec.get("matched_accuracy")
-        if ma is not None and not isinstance(ma, bool):
-            _fail(
-                f"exchange_bench.matched_accuracy must be a bool or "
-                f"null, got {ma!r}"
-            )
-        for key in ("round_s", "wire_bytes_per_step", "straggler_ms",
-                    "sync_round_s", "async_round_s", "speedup",
-                    "trace_off_round_s", "trace_on_round_s",
-                    "trace_overhead",
-                    # v6: autoscale scenario rates (scaleup/scaledown).
-                    "pre_rate", "spike_rate", "recovered_rate",
-                    # v11: the compressed-wire robustness cells
-                    # (EXCHBENCH_r05) — matched-accuracy check plus the
-                    # adaptive-attack headroom instrument.
-                    "final_accuracy", "attack_magnitude", "headroom",
-                    "compression_ratio"):
-            val = rec.get(key)
-            if val is not None and not _is_num(val):
-                _fail(
-                    f"exchange_bench.{key} must be a number or null, "
-                    f"got {val!r}"
-                )
-        for key in ("active_initial", "active_final", "spawns",
-                    "retires"):
-            # v6: membership counts — integers, not rates.
-            val = rec.get(key)
-            if val is not None and (
-                not isinstance(val, int) or isinstance(val, bool)
-                or val < 0
-            ):
-                _fail(
-                    f"exchange_bench.{key} must be a non-negative int "
-                    f"or null, got {val!r}"
-                )
-        lb = rec.get("learn_ms0_bitwise")
-        if lb is not None and not isinstance(lb, bool):
-            _fail(
-                f"exchange_bench.learn_ms0_bitwise must be a bool or "
-                f"null, got {lb!r}"
-            )
-        rss = rec.get("peak_rss_bytes")
-        if rss is not None and (
-            not isinstance(rss, int) or isinstance(rss, bool) or rss < 0
-        ):
-            _fail(
-                f"exchange_bench.peak_rss_bytes must be a non-negative "
-                f"int or null, got {rss!r}"
             )
     # kind == "run": meta payload is free-form (validated as JSON above).
     return rec

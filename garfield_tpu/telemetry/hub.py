@@ -771,8 +771,7 @@ class MetricsHub:
     def phase_stats(self):
         """Per-phase duration percentiles over the recorded spans
         ({phase: {count, mean_s, p50_s, p95_s, p99_s}}), or None before
-        any span — the per-phase twin of ``step_time_stats`` (and what
-        exchange_bench scenario rows record to attribute speedups)."""
+        any span — the per-phase twin of ``step_time_stats``."""
         with self._lock:
             if not self._phase:
                 return None

@@ -59,7 +59,7 @@ it; producers may add more):
               hierarchy's hier_ingest spans)
   soak:       soak_round (tag scenario=steady|rolling_restart|
               partition|churn — one span per sustained round; the
-              SOAKBENCH SLO percentiles come from its phase stats)
+              soak_bench SLO percentiles come from its phase stats)
 """
 
 import itertools

@@ -38,8 +38,8 @@ shape every production autoscaler converges to):
 
 ``target_rate <= 0`` auto-calibrates: the first full window's measured
 rate becomes the target, so a deployment scaled for its initial load
-holds that service level through load spikes (the exchange_bench
-``scaleup`` scenario) without anyone computing a number up front.
+holds that service level through load spikes without anyone computing
+a number up front.
 
 The mechanics of spawning/retiring live with the caller (apps/cluster.py
 spawns real OS processes via ``worker_command``; the bench spawns follow

@@ -499,7 +499,7 @@ class PeerExchange:
         eagerly in waiter threads as frames land, the batch hook runs at
         harvest — profitable exactly when per-frame Python overhead
         exceeds the lost overlap (the 10^6-client ingest regime;
-        INGESTBENCH quantifies the crossover).
+        fed_bench's ``ingest_micro`` check brackets the crossover).
 
         Symmetric all-to-all protocols (LEARN gossip) need this split: with
         plain publish-then-``collect``, the moment the last node's frame

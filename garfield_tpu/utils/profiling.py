@@ -20,8 +20,8 @@ import numpy as np
 
 __all__ = [
     "StepTimer",
-    "paired_reps",
-    "trace",
+    "StepsTrace",
+    "peak_bf16",
     "collective_bytes",
     "convert_to_gbit",
     "enable_compile_cache",
@@ -37,8 +37,8 @@ def enable_compile_cache():
     volume). Unset: ``<checkout>/.jax_cache``, derived from this package's
     location. The path is part of the cache key, so it is a fixed place —
     never a temp name, a pid, a time or a version string — and every entry
-    point (``apps/common.train``, ``bench.py``, ``chip_smoke.py``,
-    ``__graft_entry__.py``, ``scripts/step_bench.py``) shares it. A failure
+    point (``apps/common.train``, ``chip_smoke.py``,
+    ``__graft_entry__.py``) shares it. A failure
     to configure the cache raises: a run that silently recompiles
     ResNet-18 every time is a bug, not a degraded mode.
     """
@@ -55,46 +55,31 @@ def enable_compile_cache():
     return cache_dir
 
 
-def paired_reps(timed_fn, reps, floor=1e-9, pairs=3, agg="median"):
-    """Per-iteration latency via the paired-reps difference estimator.
+# Peak dense bf16 FLOP/s per chip by device kind (public spec sheets).
+_PEAK_BF16 = {
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,
+    "TPU v5e": 197e12,
+    "TPU v5": 459e12,
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,
+    "TPU v6e": 918e12,
+}
 
-    ``timed_fn(k)`` must run k *dependency-chained* iterations ended by a
-    device sync (``block_until_ready`` or a host readback), and return the
-    elapsed wall seconds. The chain is run at ``reps`` and ``2 * reps`` and
-    the difference divided by ``reps`` — any constant per-run cost
-    (dispatch ramp-up, the sync's round trip) cancels, so the estimate is
-    the steady per-iteration time of a full pipeline rather than
-    wall / k of one short run (PERF.md "How a step is timed").
 
-    Noise handling: a single (t1, t2) pair can come out
-    with ``t2 - t1 <= 0``; flooring that would report ``1/floor`` as a
-    plausible-looking throughput. Up to ``pairs`` independent pairs are
-    measured, differences at or below ``floor`` are discarded as
-    noise-dominated, and the chosen aggregate of the rest is returned.
-    ``agg="median"`` (default) stops early once two pairs agree to be
-    positive — the right choice for end-to-end steps, where the median
-    tracks the typical window. ``agg="min"`` runs ALL pairs
-    and returns the minimum positive difference — the classic min-time
-    latency methodology for MICRO-benchmarks, where
-    interference only ever adds time and the minimum is the best estimate
-    of the kernel itself (VERDICT r4 weak #2: median-of-3 sub-ms grid
-    cells bounced >1.3x between committed sweeps). Returns **None** when
-    every pair is noise-dominated — the workload is below this host's
-    measurement floor and no number would be honest; callers must treat
-    None as "unmeasurable", not zero.
-    """
-    diffs = []
-    for _ in range(max(1, pairs)):
-        t1 = timed_fn(reps)
-        t2 = timed_fn(2 * reps)
-        d = (t2 - t1) / reps
-        if d > floor:
-            diffs.append(d)
-        if agg == "median" and len(diffs) >= 2:
-            break
-    if not diffs:
+def peak_bf16(device):
+    """Peak bf16 FLOP/s of ``device``; None on the CPU platform (a CPU run
+    checks the program, it has no device metric). An accelerator missing
+    from ``_PEAK_BF16`` is an error, not a default."""
+    if device.platform == "cpu":
         return None
-    return float(np.min(diffs) if agg == "min" else np.median(diffs))
+    if device.device_kind not in _PEAK_BF16:
+        raise RuntimeError(
+            f"device kind {device.device_kind!r} has no entry in "
+            "profiling._PEAK_BF16; add its published peak before running "
+            "on it"
+        )
+    return _PEAK_BF16[device.device_kind]
 
 
 class StepTimer:

@@ -824,8 +824,8 @@ def decode_batch_into(bufs, out2d, *, expect_plane=None, expect_elems=None,
 
     The batched half of the ingest plane (DESIGN.md §24). Per-frame
     ``decode_into`` pays a full Python trip per client frame — header
-    unpack, CRC call, per-frame dequant — which FEDBENCH_r02 showed
-    dominating the million-client round. This runs the SAME validation
+    unpack, CRC call, per-frame dequant — which dominated the
+    million-client round (XLA:CPU, round 19). This runs the SAME validation
     pipeline restructured into three batch passes:
 
     1. **vectorized header screen**: the first 20 bytes of every frame,

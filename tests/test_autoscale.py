@@ -5,8 +5,7 @@ mean-based (burst-proof) rate estimator, auto-calibration, the
 quorum-margin scale-down gate, config validation, and the PS-argv ->
 worker-argv command derivation. The multi-process e2e (a PS actually
 spawning/retiring worker processes) lives in tests/test_async_cluster.py
-(slow); the bench-harness form in exchange_bench --scenario
-scaleup/scaledown.
+(slow).
 """
 
 import sys

@@ -306,7 +306,7 @@ def test_ingraph_data_defense_downweights_backdoor_cohort():
     """The tentpole contract, on-mesh: under a backdoor cohort the dp
     weights pin the Byzantine slots at the floor within the EMA window
     while honest slots keep ~1.0 — the evidence the GAR-side suspicion
-    plane structurally cannot produce (DEFBENCH_r02's open cell)."""
+    plane structurally cannot produce (the cell round 15 left open)."""
     init_fn, step_fn, _ = _data_trainer(
         {"weighted": False,
          "data": {"tau": 2.0, "floor": 0.1, "halflife": 8.0}}

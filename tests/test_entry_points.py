@@ -1,12 +1,13 @@
 """The repo-root entry points and the start-up helper they share.
 
 ``chip_smoke.py`` refuses to run off the chip and its legs work at toy size;
-``bench.py`` runs on the backend it was given, names it on its JSON line and
-exits non-zero on any failure; ``__graft_entry__.dryrun_multichip`` raises
-rather than run somewhere else; ``profiling.enable_compile_cache`` can be
-placed from outside and otherwise stays in the checkout.
+``__graft_entry__.dryrun_multichip`` raises rather than run somewhere else;
+``profiling.enable_compile_cache`` can be placed from outside and otherwise
+stays in the checkout; ``profiling.peak_bf16`` knows no default; and every
+``GARFIELD_*`` variable the program reads is in the README's table.
 """
 
+import ast
 import importlib.util
 import json
 import os
@@ -99,61 +100,44 @@ def test_dryrun_multichip_raises_on_too_few_devices():
         entry.dryrun_multichip(n)
 
 
-def test_bench_unknown_accelerator_is_an_error():
-    bench = _load("bench")
-
+def test_peak_bf16_unknown_accelerator_is_an_error():
     class Fake:
         platform = "tpu"
         device_kind = "TPU v0 imaginary"
 
     with pytest.raises(RuntimeError, match="TPU v0 imaginary"):
-        bench.peak_bf16(Fake)
-    assert bench.peak_bf16(jax.devices()[0]) is None  # cpu: no device metric
+        profiling.peak_bf16(Fake)
+    assert profiling.peak_bf16(jax.devices()[0]) is None  # cpu: no device metric
 
 
-def _run_bench(**knobs):
-    env = dict(os.environ, JAX_PLATFORMS="cpu", GARFIELD_BENCH_JSONL="",
-               GARFIELD_BENCH_STEPS="2", GARFIELD_BENCH_TRIALS="1", **knobs)
-    return subprocess.run(
-        [sys.executable, str(REPO_ROOT / "bench.py")],
-        cwd=REPO_ROOT, env=env, capture_output=True, text=True, timeout=900,
-    )
+def _env_names_read():
+    """Every string constant that is exactly a ``GARFIELD_*`` name in the
+    program's sources: the argument of an ``os.environ`` read (or of a
+    helper that makes one), never a sentence that mentions a name."""
+    sources = [REPO_ROOT / "chip_smoke.py", REPO_ROOT / "__graft_entry__.py"]
+    sources += sorted((REPO_ROOT / "garfield_tpu").rglob("*.py"))
+    names = set()
+    for path in sources:
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Constant) and isinstance(node.value, str) \
+                    and re.fullmatch(r"GARFIELD_[A-Z0-9_]+", node.value):
+                names.add(node.value)
+    return names
 
 
-@pytest.mark.slow
-class TestBenchContract:
-    def test_bad_rule_exits_nonzero_without_a_result(self):
-        proc = _run_bench(GARFIELD_BENCH_GAR="no-such-rule")
-        assert proc.returncode != 0
-        assert "no-such-rule" in proc.stderr
-        assert proc.stdout.strip() == ""  # nothing that parses as a result
-
-    def test_workers_that_do_not_fold_exit_nonzero(self):
-        # 8 virtual CPU devices (conftest's JAX_NUM_CPU_DEVICES), 6 workers.
-        proc = _run_bench(GARFIELD_BENCH_WORKERS="6", GARFIELD_BENCH_F="1")
-        assert proc.returncode != 0
-        assert "do not fold" in proc.stderr
-
-    def test_line_names_the_device(self):
-        """Tiny off-default config on the CPU backend: one valid JSON line
-        that says where it ran; no ratchet ratio and no device metric."""
-        proc = _run_bench(
-            GARFIELD_BENCH_WORKERS="8", GARFIELD_BENCH_F="1",
-            GARFIELD_BENCH_GAR="median", GARFIELD_BENCH_ATTACK="lie",
-            GARFIELD_BENCH_BATCH="2",
-        )
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        lines = [l for l in proc.stdout.splitlines() if l.strip()]
-        assert len(lines) == 1, proc.stdout
-        out = json.loads(lines[0])
-        assert out["value"] > 0
-        assert out["unit"] == "steps/s/chip"
-        assert out["metric"].endswith("w8_f1_median_lie")
-        assert out["vs_baseline"] is None  # off-default config
-        assert out["mfu"] is None  # cpu has no peak: no device metric
-        assert out["chunk_steps"] == 1
-        assert (out["platform"], out["n_devices"]) == ("cpu", 8)
-        assert out["device_kind"] == jax.devices()[0].device_kind
+def test_every_env_knob_is_documented():
+    """The README's "Environment variables" table and the variables the
+    program reads are the same set: a new knob is a row with its default and
+    its reader, a removed one takes its row along."""
+    readme = (REPO_ROOT / "README.md").read_text()
+    section = readme.split("## Environment variables", 1)[1].split("\n## ", 1)[0]
+    rows = [line for line in section.splitlines() if line.startswith("| `")]
+    documented = [re.match(r"\| `([A-Z0-9_]+)`", row).group(1) for row in rows]
+    garfield = [name for name in documented if name.startswith("GARFIELD_")]
+    assert len(garfield) == len(set(garfield)), "a name has two rows"
+    assert set(garfield) == _env_names_read()
+    for row in rows:  # name | default | read by | what it controls
+        assert row.count(" | ") >= 3, row
 
 
 @pytest.mark.slow

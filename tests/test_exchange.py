@@ -360,20 +360,29 @@ def test_collect_begin_latches_before_overwrite():
     """Pre-registered waiters (collect_begin) must latch a frame that is
     later overwritten — the publish-then-collect race a symmetric gossip
     protocol hits on an oversubscribed host (apps/cluster._run_learn)."""
-    import time
+    import threading
 
     peers = _mesh(2)
+    latched = {0: threading.Event(), 1: threading.Event()}
+
+    def note(idx, payload):  # runs on the waiter thread as the frame lands
+        latched[idx].set()
+        return payload
+
     try:
-        wait = peers[0].collect_begin(7, q=2, timeout_ms=15_000)
-        time.sleep(0.2)  # waiters blocked on the register
+        wait = peers[0].collect_begin(7, q=2, timeout_ms=15_000,
+                                      transform=note)
         peers[1].publish(7, b"frame7")
-        time.sleep(0.2)  # latched by the blocked reader...
+        assert latched[1].wait(15)  # latched by the registered reader...
         peers[1].publish(8, b"frame8")  # ...then overwritten in the slot
         peers[0].publish(7, b"self")
         got = wait()
         assert got == {0: b"self", 1: b"frame7"}
 
-        # Control: a collect REGISTERED after the overwrite cannot see 7.
+        # Control: a collect REGISTERED after the overwrite (frame 8 is in
+        # the slot) cannot see 7.
+        assert peers[0].read_latest(1, 8, timeout_ms=15_000) == (
+            8, b"frame8")
         with pytest.raises(TimeoutError):
             peers[0].collect(7, q=1, peers=[1], timeout_ms=300)
     finally:

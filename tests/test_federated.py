@@ -566,7 +566,7 @@ class TestStreamingAdditions:
 
 
 # ---------------------------------------------------------------------------
-# telemetry schema v12: per-phase attribution + selection micro-rows
+# telemetry schema v12: per-phase attribution
 
 
 class TestTelemetryV12:
@@ -578,7 +578,6 @@ class TestTelemetryV12:
                 "ingest": {"count": 8, "p50_s": 0.01, "p95_s": 0.02},
                 "h2d": {"count": 8, "p50_s": 0.001, "p95_s": 0.002},
                 "fold": {"count": 8, "p50_s": 0.005, "p95_s": 0.009},
-                "selection": {"count": 24, "p50_s": 3e-4, "p95_s": 9e-4},
             },
         ))
 
@@ -593,20 +592,6 @@ class TestTelemetryV12:
                 "fed_bench", check="scaling", n=10, d=10, shards=1,
                 gar="hier-krum", phases=phases,
             ))
-
-    def test_gar_bench_selection_rows_validate(self):
-        exporters.validate_record(exporters.make_record(
-            "gar_bench", gar="krum", n=16, f=6, d=256, latency_s=6.7e-5,
-            grid="selection", impl="sortnet", wave_buckets=8,
-            per_bucket_s=8.3e-6, trials=3, dce_guard="softsign",
-        ))
-        for bad in [{"impl": 7}, {"wave_buckets": 0},
-                    {"per_bucket_s": "x"}, {"grid": 1}]:
-            with pytest.raises(ValueError):
-                exporters.validate_record(exporters.make_record(
-                    "gar_bench", gar="krum", n=16, f=6, d=256,
-                    latency_s=1e-5, **bad,
-                ))
 
 
 # ---------------------------------------------------------------------------

@@ -375,20 +375,6 @@ class TestTelemetryV4:
             MetricsHub(num_ranks=2)
         )
 
-    def test_exchange_bench_scenario_record_validates(self):
-        from garfield_tpu.telemetry import exporters
-
-        rec = exporters.make_record(
-            "exchange_bench", n=4, d=100000, wire="f32",
-            scenario="straggler", straggler_ms=120, sync_round_s=0.12,
-            async_round_s=0.004, speedup=30.0, peak_rss_bytes=123456,
-        )
-        exporters.validate_record(rec)
-        with pytest.raises(ValueError):
-            exporters.validate_record(dict(rec, speedup="fast"))
-        with pytest.raises(ValueError):
-            exporters.validate_record(dict(rec, peak_rss_bytes=-1))
-
 
 class TestLearnEmulation:
     """LEARN per-phase staleness emulation (parallel/learn ``staleness=``,
@@ -587,26 +573,3 @@ class TestTelemetryV6:
             plane="model", ranks=[0, 1], staleness=[0, 2],
             weights=[1.0, 0.25], reused=1,
         ))
-
-    def test_exchange_bench_v6_rows_validate(self):
-        from garfield_tpu.telemetry import exporters
-
-        exporters.validate_record(exporters.make_record(
-            "exchange_bench", n=8, d=10000, wire="f32",
-            scenario="scaleup", pre_rate=25.0, spike_rate=6.2,
-            recovered_rate=24.0, active_initial=2, active_final=8,
-            spawns=6, retires=0, peak_rss_bytes=1,
-        ))
-        exporters.validate_record(exporters.make_record(
-            "exchange_bench", n=3, d=0, wire="f32",
-            scenario="learn_ms0", learn_ms0_bitwise=True,
-        ))
-        with pytest.raises(ValueError):
-            exporters.validate_record(exporters.make_record(
-                "exchange_bench", n=3, d=0, wire="f32",
-                learn_ms0_bitwise="yes",
-            ))
-        with pytest.raises(ValueError):
-            exporters.validate_record(exporters.make_record(
-                "exchange_bench", n=8, d=0, wire="f32", spawns=1.5,
-            ))

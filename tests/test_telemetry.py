@@ -6,15 +6,13 @@ Pins the three contracts the subsystem makes:
      krum/cclip x lie/none, with and without wait-n-f subsets);
   2. tap correctness — krum's selection mask equals the rule's own
      ``selection_indices`` / ``influence`` on the same poisoned stack;
-  3. the JSONL schema round-trips and malformed artifacts fail loudly
-     (the tier-1 schema check for bench artifacts), and the derived
+  3. the JSONL schema round-trips and malformed records fail loudly,
+     and the derived
      suspicion score ranks the Byzantine ranks above every honest rank
      on the 8-worker aggregathor run under the lie attack.
 """
 
-import importlib.util
 import json
-import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -36,8 +34,6 @@ from garfield_tpu.telemetry import (
 )
 from garfield_tpu.telemetry import taps as taps_lib
 from garfield_tpu.utils import selectors
-
-REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 
 def _pima_setup():
@@ -238,9 +234,9 @@ class TestExporters:
                         arrived=6, wait_s=0.01, timed_out=False),
             make_record("summary", steps=1, events=1,
                         suspicion=[0.0, 1.0]),
-            make_record("bench", metric="m", value=1.5, unit="steps/s"),
-            make_record("gar_bench", gar="krum", n=8, f=2, d=1000,
-                        latency_s=0.001),
+            make_record("span", phase="grad", t_wall=1.0, dur_s=0.01),
+            make_record("soak_bench", check="steady", rounds=4, d=256,
+                        shards=2, cohort=16, p50_s=0.001),
         ]
         with JsonlExporter(path) as exp:
             for rec in records:
@@ -259,15 +255,17 @@ class TestExporters:
         {"schema": "garfield-telemetry", "v": 1, "kind": "step", "step": 0,
          "tap": {"observed": [1.0], "selected": [1.0, 0.0],
                  "score": [0.0], "tau": 0, "clip_frac": 0}},
-        {"schema": "garfield-telemetry", "v": 1, "kind": "bench"},
-        {"schema": "garfield-telemetry", "v": 1, "kind": "gar_bench",
-         "gar": "krum", "n": "8", "f": 2, "d": 10},
-        # schema v2: bench chunk-attribution and step-time percentiles
-        # must be well-typed when present.
-        {"schema": "garfield-telemetry", "v": 2, "kind": "bench",
-         "metric": "m", "value": 1.0, "chunk_steps": 0},
-        {"schema": "garfield-telemetry", "v": 2, "kind": "bench",
-         "metric": "m", "value": 1.0, "chunk_steps": "4"},
+        {"schema": "garfield-telemetry", "v": 1, "kind": "span"},
+        {"schema": "garfield-telemetry", "v": 1, "kind": "fed_bench",
+         "check": "scaling", "gar": "hier-krum", "n": "8", "d": 10,
+         "shards": 1},
+        # a harness row's envelope and typed columns must be well-formed.
+        {"schema": "garfield-telemetry", "v": 13, "kind": "soak_bench",
+         "check": "steady", "rounds": 4, "d": 256, "shards": 2,
+         "cohort": 0},
+        {"schema": "garfield-telemetry", "v": 7, "kind": "defense_bench",
+         "cell": "grad/lie/off", "gar": "krum", "steps": "4"},
+        # schema v2: step-time percentiles must be well-typed when present.
         {"schema": "garfield-telemetry", "v": 2, "kind": "summary",
          "steps": 1, "events": 0, "step_time": [0.1]},
         {"schema": "garfield-telemetry", "v": 2, "kind": "summary",
@@ -278,16 +276,35 @@ class TestExporters:
         with pytest.raises(ValueError, match="schema violation"):
             validate_record(bad)
 
-    def test_v2_step_time_percentiles_and_chunk_steps_validate(self):
+    def test_v2_step_time_percentiles_validate(self):
         validate_record(make_record(
             "summary", steps=3, events=0,
             step_time={"count": 3, "mean_s": 0.1, "p50_s": 0.09,
                        "p95_s": 0.2, "p99_s": 0.3},
         ))
-        validate_record(make_record(
-            "bench", metric="m", value=1.0, unit="steps/s/chip",
-            chunk_steps=8,
-        ))
+
+    @pytest.mark.parametrize("line", [
+        {"kind": "bench", "metric": "m", "value": 1.5, "unit": "steps/s"},
+        {"kind": "gar_bench", "gar": "krum", "n": 8, "f": 2, "d": 1000,
+         "latency_s": 0.001},
+        {"kind": "hier_bench", "gar": "hier-krum", "n": 1024, "f": 8,
+         "d": 1000, "bucket_size": 32, "levels": 2, "num_buckets": 32,
+         "latency_s": 0.1},
+        {"kind": "transfer_bench", "devices": 8, "d": 1000,
+         "latency_s": 0.001},
+        {"kind": "exchange_bench", "n": 4, "d": 1000, "wire": "f32",
+         "round_s": 0.01},
+        {"kind": "trans_bench", "check": "ab", "model": "vit_tiny",
+         "slots": 8, "d": 1000, "path": "fused", "per_slot_grad_s": 0.01},
+    ], ids=lambda line: line["kind"])
+    def test_removed_kind_is_refused(self, line):
+        """v16: a well-formed v15 line of a kind whose program went is
+        refused like any unknown kind, and the message names it."""
+        rec = {"schema": "garfield-telemetry", "v": 15, **line}
+        with pytest.raises(ValueError, match=repr(line["kind"])):
+            validate_record(rec)
+        with pytest.raises(ValueError, match=repr(line["kind"])):
+            make_record(line["kind"])
 
     def test_malformed_jsonl_fails_loudly(self, tmp_path):
         path = tmp_path / "bad.jsonl"
@@ -345,57 +362,6 @@ class TestSuspicionAudit:
         susp = hub.suspicion()
         assert susp is not None
         assert susp[8 - f:].min() > susp[:8 - f].max(), susp
-
-
-class TestBenchArtifacts:
-    """The tier-1 schema check: bench emitters produce valid JSONL, and
-    any committed telemetry artifact in the repo root validates — a
-    malformed capture fails THIS suite instead of going dark."""
-
-    def test_bench_emit_jsonl(self, tmp_path, monkeypatch):
-        spec = importlib.util.spec_from_file_location(
-            "bench_entry", REPO_ROOT / "bench.py"
-        )
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        path = tmp_path / "bench.jsonl"
-        monkeypatch.setenv("GARFIELD_BENCH_JSONL", str(path))
-        bench._emit_jsonl({
-            "metric": "byzsgd_steps_per_sec_per_chip", "value": 51.2,
-            "unit": "steps/s/chip", "vs_baseline": 1.01, "mfu": 0.3,
-            "chunk_steps": 1, "platform": "tpu",
-            "device_kind": "TPU v5 lite", "n_devices": 1,
-        })
-        assert validate_jsonl(path) == 1
-        with open(path) as fp:
-            rec = json.loads(fp.readline())
-        assert rec["value"] == 51.2
-        # Every bench record names the device it ran on.
-        assert (rec["platform"], rec["device_kind"], rec["n_devices"]) == (
-            "tpu", "TPU v5 lite", 1
-        )
-
-    def test_gar_bench_emits_jsonl_twin(self, tmp_path):
-        from garfield_tpu.apps.benchmarks import gar_bench
-
-        out = tmp_path / "sweep.json"
-        gar_bench.main([
-            "--gars", "average", "--ns", "4", "--ds", "16", "--reps", "2",
-            "--json", str(out),
-        ])
-        twin = tmp_path / "sweep.jsonl"
-        assert out.exists() and twin.exists()
-        count = validate_jsonl(twin)
-        assert count == len(json.loads(out.read_text()))
-
-    def test_committed_telemetry_artifacts_validate(self):
-        found = sorted(REPO_ROOT.glob("*.jsonl")) + sorted(
-            REPO_ROOT.glob("*telemetry*.jsonl")
-        )
-        for path in dict.fromkeys(found):
-            if path.name == "PERF_LEDGER.jsonl":
-                continue  # the driver's own record, not a telemetry stream
-            validate_jsonl(path)  # raises loudly on any malformed line
 
 
 @pytest.mark.slow

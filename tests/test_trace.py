@@ -14,10 +14,7 @@ Pins the tracing plane's contracts:
      the quoted alignment error;
   4. tracing-on vs tracing-off trajectories are BITWISE equal (spans
      are host-only observers — the taps' purity contract, host
-     edition);
-  5. every committed ``*_r*.jsonl`` artifact schema-validates
-     (scripts/validate_artifacts.py — the tier-1 wiring of the CI
-     satellite).
+     edition).
 """
 
 import json
@@ -39,6 +36,7 @@ from garfield_tpu.telemetry import (
     report,
     trace,
     uninstall,
+    validate_jsonl,
     validate_record,
 )
 
@@ -191,18 +189,6 @@ class TestSchemaV5:
                 "summary", steps=1, events=0, spans=-2,
             ))
 
-    def test_exchange_bench_trace_fields(self):
-        validate_record(make_record(
-            "exchange_bench", n=4, d=1000, wire="f32",
-            trace_off_round_s=0.01, trace_on_round_s=0.0102,
-            trace_overhead=1.02,
-            phases={"collect": {"p50_s": 0.008, "p95_s": 0.01}},
-        ))
-        with pytest.raises(ValueError):
-            validate_record(make_record(
-                "exchange_bench", n=4, d=1000, wire="f32",
-                phases={"collect": [1, 2]},
-            ))
 
 
 class TestReport:
@@ -213,7 +199,10 @@ class TestReport:
 
     def test_fixture_present(self):
         assert (FIXTURE / "cluster-ps.telemetry.jsonl").exists()
-        assert len(list(FIXTURE.glob("*.telemetry.jsonl"))) == 5
+        streams = sorted(FIXTURE.glob("*.telemetry.jsonl"))
+        assert len(streams) == 5
+        for path in streams:  # a schema change that orphans it fails here
+            assert validate_jsonl(path) > 0
 
     def test_build_deterministic(self):
         a1 = report.build(str(FIXTURE))
@@ -345,33 +334,12 @@ class TestTrajectoryPin:
         )
 
 
-class TestValidateArtifacts:
-    def test_all_committed_artifacts_validate(self, capsys):
-        """The CI satellite: scripts/validate_artifacts.py over every
-        committed *_r*.jsonl (and the trace fixture) — schema drift in
-        a future round fails tier-1 loudly."""
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "validate_artifacts",
-            REPO_ROOT / "scripts" / "validate_artifacts.py",
-        )
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        paths = mod.find_artifacts(str(REPO_ROOT))
-        # The committed bench captures and the trace fixture are there.
-        names = {pathlib.Path(p).name for p in paths}
-        assert "EXCHBENCH_r03.jsonl" in names
-        assert "cluster-ps.telemetry.jsonl" in names
-        assert mod.main(root=str(REPO_ROOT)) == 0
-
-
 class TestHierIngestAlignment:
     """ISSUE 20 satellite: per-wave ingest accounting. The hierarchy
     reports ONE pre-timed ``hier_ingest`` span per dispatched wave
     (``trace.emit``), so per-level span counts obey
     count(hier_ingest) == count(hier_wave) == count(hier_h2d) EXACTLY —
-    the FEDBENCH_r02 capture timed an outer per-push span instead and
+    round 19's capture timed an outer per-push span instead and
     undercounted ingest attribution (11721 ingest vs 12102 fold/h2d
     spans). Pinned over every ingest entry point: per-row push,
     push_many (copy and zero-copy stable), per-frame push_frame, and

@@ -134,42 +134,6 @@ def test_evalset_matches_list_path():
         parallel.EvalSet([])
 
 
-def test_gar_bench_smoke():
-    from garfield_tpu.apps.benchmarks import gar_bench
-
-    rows = gar_bench.main(
-        ["--gars", "median", "krum", "--ns", "8", "--ds", "10", "--reps", "2"]
-    )
-    assert {r["gar"] for r in rows} == {"median", "krum"}
-    # latency is a positive float, or None with the below_noise_floor flag
-    # (tiny d on a fast backend legitimately sits under the paired-reps
-    # noise floor).
-    for r in rows:
-        if r["latency_s"] is None:
-            assert r.get("below_noise_floor") is True
-        else:
-            assert r["latency_s"] > 0
-
-
-def test_transfer_bench_smoke(tmp_path):
-    from garfield_tpu.apps.benchmarks import transfer_bench
-    from garfield_tpu.telemetry.exporters import validate_jsonl
-
-    out = tmp_path / "transfer.json"
-    rows = transfer_bench.main([
-        "--ds", "100", "--reps", "2", "--trials", "2", "--json", str(out),
-    ])
-    assert rows
-    for r in rows:  # below-noise rows carry no gbit_per_s
-        if r["latency_s"] is None:
-            assert r.get("below_noise_floor") is True
-        else:
-            assert r["gbit_per_s"] > 0
-        assert r["trials"] == 2  # min-over-k provenance (gar_bench parity)
-    # Schema-versioned JSONL twin rides --json (gar_bench r7 parity).
-    assert validate_jsonl(tmp_path / "transfer.jsonl") == len(rows)
-
-
 def test_multihost_config_cli(tmp_path):
     """Flag-driven config generator writes one valid per-task JSON per host
     (reference config_generator.py parity)."""
