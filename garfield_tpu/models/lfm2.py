@@ -50,10 +50,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops import attention
+
 __all__ = [
     "SCOPES", "COUNTER_SUMS", "COUNTER_MAXES", "scope", "RMSNorm", "ShortConv",
-    "Attention", "SwiGLU", "ExpertLayer", "Sizes", "Block", "Lfm2Moe",
-    "lfm2_8b_a1b_ep4", "lfm2_moe_tiny",
+    "einsum_attention", "Attention", "SwiGLU", "ExpertLayer", "Sizes", "Block",
+    "Lfm2Moe", "lfm2_8b_a1b_ep4", "lfm2_moe_tiny",
 ]
 
 SCOPES = (
@@ -127,10 +129,31 @@ class ShortConv(nn.Module):
         return _dense(hidden, self.dtype, "out_proj")(c * y)
 
 
+def einsum_attention(q, k, v):
+    """Causal attention of q (n, t, heads, head) over k, v (n, t, kv_heads,
+    head), each KV head serving heads / kv_heads adjacent query heads, as
+    two einsums with the (t, t) scores between them: scores and softmax in
+    float32, probabilities rounded to v's dtype. The spec of
+    `ops.attention`'s kernels, and what runs where they do not."""
+    n, t, heads, hd = q.shape
+    kv = k.shape[2]
+    q = q.reshape(n, t, kv, heads // kv, hd)
+    scores = jnp.einsum("nqkgd,nskd->nkgqs", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores / jnp.sqrt(jnp.float32(hd))
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+    mixed = jnp.einsum("nkgqs,nskd->nqkgd", probs.astype(v.dtype), v)
+    return mixed.reshape(n, t, heads, hd)
+
+
 class Attention(nn.Module):
     """Grouped-query causal attention with a per-head RMSNorm on q and k and
     a rotary embedding; each KV head serves heads / kv_heads query heads.
-    Softmax in float32."""
+    Softmax in float32. The core between the projections is
+    `ops.attention.causal_gqa`: blockwise kernels that write no (t, t)
+    array where the step is lowered for the TPU and t fills their blocks,
+    ``einsum_attention`` elsewhere; it says which once."""
 
     heads: int
     kv_heads: int
@@ -150,13 +173,7 @@ class Attention(nn.Module):
                    self.rope_theta).astype(self.dtype)
         k = rotary(RMSNorm(self.eps, jnp.float32, name="k_norm")(k),
                    self.rope_theta).astype(self.dtype)
-        q = q.reshape(n, t, kv, heads // kv, hd)
-        scores = jnp.einsum("nqkgd,nskd->nkgqs", q, k,
-                            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(hd))
-        causal = jnp.tril(jnp.ones((t, t), bool))
-        probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-        mixed = jnp.einsum("nkgqs,nskd->nqkgd", probs.astype(self.dtype), v)
+        mixed = attention.causal_gqa(q, k, v, einsum_attention)
         return _dense(hidden, self.dtype, "o_proj")(
             mixed.reshape(n, t, heads * hd))
 

@@ -11,6 +11,10 @@ an in-register odd-even transposition sorting network over the small n axis
 on the VPU — no (n, d) re-layout, no XLA variadic sort, no second pass for
 the selection step.
 
+`ops.attention` (imported by the model that uses it, `models/lfm2.py`, not
+from here) is the same idea for a token model's attention: blockwise causal
+grouped-query kernels that never write the (t, t) scores to HBM.
+
 Public entry points dispatch by backend: the Pallas path on TPU (or when
 forced via ``interpret=True`` for CPU testing), a pure-jnp fallback elsewhere
 with identical semantics (the fallback IS the spec; kernels are tested

@@ -7,6 +7,7 @@ and what decides a benchmark cell's `correct` cannot drift apart. Float32,
 `weights.make_params` over the reference's ``param_shapes``.
 """
 
+import functools
 import importlib.util
 import pathlib
 import re
@@ -19,6 +20,7 @@ import pytest
 from garfield_tpu import models
 from garfield_tpu.aggregators import dataplane
 from garfield_tpu.models import lfm2
+from garfield_tpu.ops import attention
 from garfield_tpu.parallel import aggregathor, core, make_mesh
 from garfield_tpu.utils import selectors
 
@@ -279,6 +281,46 @@ def test_neither_operator_looks_ahead(kind):
     assert float(jnp.abs(a[:, 9:] - b[:, 9:]).max()) > 0
 
 
+def _attention_lines(capsys):
+    return [line for line in capsys.readouterr().err.splitlines()
+            if "[attention]" in line]
+
+
+@pytest.fixture
+def kernel_path(monkeypatch):
+    """`Attention` takes the blockwise kernels, in interpret mode with two
+    blocks of 8 over the 16 positions (the second crossed by the diagonal,
+    none skipped but the one above it): the test steers the choice that
+    platform and shape make on the chip; the model has no argument for
+    it."""
+    attention._said.clear()
+    monkeypatch.setattr(attention, "causal_gqa", functools.partial(
+        attention.causal_gqa, block=8, interpret=True))
+
+
+@pytest.mark.parametrize("block", ["attention", "whole"])
+def test_the_kernel_path_equals_the_reference_too(block, kernel_path, capsys):
+    """Logits and gradients against ``ref.attention_operator``'s float32
+    scores, under the einsum path's own tolerances."""
+    test_logits_and_gradient_equal_the_reference(block)
+    assert _attention_lines(capsys) == [
+        "[attention] blockwise: (n, heads, kv_heads, t, head) = "
+        "(3, 4, 2, 16, 16) float32, blocks (8, 8), causal blocks skipped "
+        "1 of 4, interpret mode"]
+
+
+def test_the_kernel_path_does_not_look_ahead_either(kernel_path, capsys):
+    test_neither_operator_looks_ahead("full_attention")
+    assert len(_attention_lines(capsys)) == 1
+
+
+def test_the_einsum_path_says_why_it_was_taken(capsys):
+    attention._said.clear()
+    test_neither_operator_looks_ahead("full_attention")
+    assert _attention_lines(capsys) == [
+        "[attention] einsum: t = 16 is not a multiple of the block 128"]
+
+
 def test_the_counters_equal_the_references_count():
     model = _model()
     module, variables, made = _setup(model)
@@ -399,6 +441,57 @@ def test_three_trainer_steps_equal_slot_by_slot_gradients(monkeypatch):
     monkeypatch.setattr(core, "UNROLL_MAX_SLOTS", 1)
     with pytest.raises(NotImplementedError, match="ragged_dot vmap"):
         three_steps()
+
+
+def _slot_gradients_of_one_step(monkeypatch, module):
+    """The per-worker gradients `make_trainer`'s step takes, as
+    `core.per_slot_grads` hands them to the attack and the rule."""
+    seen = []
+    unrolled = core.per_slot_grads
+
+    def tapped(*args, **kwargs):
+        grads, aux = unrolled(*args, **kwargs)
+        jax.debug.callback(seen.append, grads)
+        return grads, aux
+
+    with monkeypatch.context() as patch:
+        patch.setattr(core, "per_slot_grads", tapped)
+        init_fn, step_fn, _ = _trainer(module)
+        x, y = _worker_batches()
+        state, _ = step_fn(init_fn(jax.random.PRNGKey(0), x[0]), x, y)
+        jax.block_until_ready(state)
+    (grads,) = seen
+    return _paths(grads)
+
+
+def test_the_two_attention_paths_give_the_trainer_the_same_gradients(
+        monkeypatch, capsys):
+    """The 4-slot unroll with every block recomputed (``remat=True``, as
+    the benchmark's preset runs): forward, recomputed forward and backward
+    kernels against the einsum path, worker by worker and leaf by leaf;
+    each trace says its path once, not once a slot."""
+    module = lfm2.lfm2_moe_tiny(num_classes=VOCAB, remat=True)
+    attention._said.clear()
+    want = _slot_gradients_of_one_step(monkeypatch, module)
+    assert _attention_lines(capsys) == [
+        "[attention] einsum: t = 16 is not a multiple of the block 128"]
+    monkeypatch.setattr(attention, "causal_gqa", functools.partial(
+        attention.causal_gqa, block=8, interpret=True))
+    got = _slot_gradients_of_one_step(monkeypatch, module)
+    said = _attention_lines(capsys)
+    assert len(said) == 1 and said[0].startswith(
+        "[attention] blockwise: (n, heads, kv_heads, t, head) = "
+        "(2, 4, 2, 16, 16) float32, blocks (8, 8)")
+    assert got.keys() == want.keys() and len(got) > 20
+    for path, leaf in got.items():
+        assert leaf.shape[0] == 4, path
+        for worker in range(4):
+            np.testing.assert_allclose(
+                leaf[worker], want[path][worker],
+                atol=2e-5 * max(1.0, float(
+                    jnp.linalg.norm(want[path][worker]))),
+                err_msg=f"{path} worker {worker}")
+    assert float(jnp.linalg.norm(want["layer_1/attn/q_proj/kernel"])) > 0
 
 
 def test_the_next_token_loss_is_the_mean_over_all_positions():

@@ -116,3 +116,44 @@ def test_operands_in_place_on_device(n, f, tail):
     assert got.dtype == jnp.bfloat16 and got.shape == tail
     np.testing.assert_array_equal(
         np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("n,heads,kv,t,head,dtype", [
+    (2, 32, 8, 2048, 64, jnp.bfloat16),   # one slot of lfm2n4
+    (1, 4, 4, 384, 128, jnp.float32),     # group 1, blocks of 128, head 128
+])
+def test_attention_kernels_on_device(n, heads, kv, t, head, dtype):
+    """The blockwise attention kernels (ops/attention.py) through real
+    Mosaic lowering, taken by ``causal_gqa`` itself, against the einsum
+    path: the output and dq, dk, dv under ``jax.grad`` with the block
+    recomputed (``jax.checkpoint``), as the model runs it. Tolerances as
+    tests/test_attention.py states them; float32 operands go through the
+    MXU's bf16 passes on either path, hence no tighter there."""
+    from garfield_tpu.models.lfm2 import einsum_attention
+    from garfield_tpu.ops import attention
+
+    keys = jax.random.split(jax.random.PRNGKey(t), 4)
+    q = jax.random.normal(keys[0], (n, t, heads, head), dtype)
+    k = jax.random.normal(keys[1], (n, t, kv, head), dtype)
+    v = jax.random.normal(keys[2], (n, t, kv, head), dtype)
+    weight = jax.random.normal(keys[3], (n, t, heads, head), jnp.float32)
+    assert attention.misfit(q.shape, kv, dtype) is None
+
+    def both(core):
+        def loss(q, k, v):
+            out = jax.checkpoint(core)(q, k, v)
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+
+    (_, got), got_grads = both(
+        lambda *a: attention.causal_gqa(*a, einsum_attention))
+    (_, want), want_grads = both(einsum_attention)
+    f32 = lambda x: np.asarray(x, np.float32)
+    np.testing.assert_allclose(
+        f32(got), f32(want), rtol=2.0 ** -7, atol=2.0 ** -7)
+    for leaf, a, b in zip(("dq", "dk", "dv"), got_grads, want_grads):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_allclose(
+            f32(a), f32(b), atol=2.0 ** -6 * float(np.abs(f32(b)).max()),
+            err_msg=leaf)
