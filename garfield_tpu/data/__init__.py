@@ -48,10 +48,12 @@ __all__ = [
 # tensorflow_impl/libs/dataset.py:41-87 accepts any tfds dataset) +
 # copytask (the synthetic token-sequence task the transformer family
 # trains on — no reference counterpart, synthetic BY CONSTRUCTION) +
-# synthtokens (seeded token sequences labelled with their next token, for
-# the language models of models/lfm2.py).
+# synthtokens, synthtokens24k (seeded token sequences labelled with their
+# next token, for the language models of models/lfm2.py and
+# models/mellum.py: TOKEN_DATASETS).
 datasets_list = [
     "mnist", "cifar10", "cifar100", "pima", "copytask", "synthtokens",
+    "synthtokens24k",
 ]
 
 # Reference normalization constants.
@@ -312,30 +314,37 @@ def load_copytask(train_size=None):
 
 SYNTHTOKENS_VOCAB = 16384
 SYNTHTOKENS_SEQ = 2048
+# The token datasets: ``{name: (vocabulary slice, sequence length)}``, each
+# the slice and the length one language-model preset is benchmarked at
+# (``lfm2_8b_a1b_ep4``; ``mellum2_12b_a2p5b_ep4``). The slice is also
+# ``models.num_classes_dict[name]``.
+TOKEN_DATASETS = {
+    "synthtokens": (SYNTHTOKENS_VOCAB, SYNTHTOKENS_SEQ),
+    "synthtokens24k": (24576, 4096),
+}
 
 
-def load_synthtokens(train_size=None):
+def load_synthtokens(train_size=None, name="synthtokens"):
     """Seeded token sequences labelled with the next token at every
-    position (the ``next-token`` loss; models/lfm2.py).
+    position (the ``next-token`` loss; models/lfm2.py, models/mellum.py).
 
-    x is (N, ``SYNTHTOKENS_SEQ``) int32 below ``SYNTHTOKENS_VOCAB`` (the
-    vocabulary slice of ``models.num_classes_dict["synthtokens"]``; the
-    length is the one ``lfm2_8b_a1b_ep4`` is benchmarked at), y is x moved
-    one place on. The law is `tokens.sequences`'s — a token repeats the one
-    two places back or is a fresh Zipf-Mandelbrot draw — and the benchmark's
-    cell draws from the same (benchmark/inputs/next_tokens.py). Synthetic
-    by construction, like copytask: no file is read, no warning.
+    x is (N, length) int32 below the vocabulary slice, both
+    ``TOKEN_DATASETS[name]``'s; y is x moved one place on. The law is
+    `tokens.sequences`'s — a token repeats the one two places back or is a
+    fresh Zipf-Mandelbrot draw — and the benchmark's cells draw from the
+    same (benchmark/inputs/next_tokens.py). Synthetic by construction, like
+    copytask: no file is read, no warning.
     """
     import jax
 
     from . import tokens
 
+    vocab, length = TOKEN_DATASETS[name]
+
     def make(n, seed):
         made = np.asarray(tokens.sequences(
-            jax.random.PRNGKey(seed), (n,), SYNTHTOKENS_SEQ + 2,
-            SYNTHTOKENS_VOCAB,
-        ))
-        return made[:, :SYNTHTOKENS_SEQ], made[:, 1:SYNTHTOKENS_SEQ + 1]
+            jax.random.PRNGKey(seed), (n,), length + 2, vocab))
+        return made[:, :length], made[:, 1:length + 1]
 
     tx, ty = make(1024, 1234)
     if train_size is not None:
@@ -352,8 +361,8 @@ def load_dataset(name, train_size=None):
         return load_pima(train_size)
     if name == "copytask":
         return load_copytask(train_size)
-    if name == "synthtokens":
-        return load_synthtokens(train_size)
+    if name in TOKEN_DATASETS:
+        return load_synthtokens(train_size, name)
     raise ValueError(f"Existing datasets are: {datasets_list}")
 
 

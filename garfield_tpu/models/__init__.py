@@ -1,14 +1,15 @@
 """Model zoo: the CNNs of pytorch_impl/libs/garfieldpp/models/ and the
 torchvision entries in garfieldpp/tools.py:59-105, the small transformers
-(`transformer.py`) and the LFM2-MoE language models (`lfm2.py`).
+(`transformer.py`) and the language models: LFM2-MoE (`lfm2.py`) and Mellum
+(`mellum.py`, built of `lfm2.py`'s attention and expert layer).
 
 All models are flax.linen modules with the signature
 ``model(x, train: bool)`` and constructor kwargs ``num_classes`` and
 ``dtype`` (compute dtype; pass jnp.bfloat16 to route convs/matmuls to the
 MXU in bf16 while parameters stay float32). ``x`` is an NHWC image batch
 for the CNNs and ``vit_tiny``, an int token batch (batch, time) for
-``gpt_tiny`` (one label per sequence) and for the ``lfm2_*`` presets, whose
-logits are (batch, time, vocabulary) and whose loss is ``next-token``
+``gpt_tiny`` (one label per sequence) and for the ``lfm2_*`` and ``mellum2_*``
+presets, whose logits are (batch, time, vocabulary) and whose loss is ``next-token``
 (``utils.selectors.select_loss``); for them ``num_classes`` is the slice of
 the vocabulary held.
 
@@ -20,12 +21,14 @@ equivalent here — sharding is decided by the caller's mesh, not the model.
 
 import jax.numpy as jnp
 
+from .. import data
 from .densenet import DenseNet121, DenseNet161, DenseNet169, DenseNet201, densenet_cifar
 from .dpn import DPN26, DPN92
 from .efficientnet import EfficientNetB0
 from .googlenet import GoogLeNet
 from .lenet import LeNet
 from .lfm2 import lfm2_8b_a1b_ep4, lfm2_moe_tiny
+from .mellum import mellum2_12b_a2p5b_ep4, mellum2_tiny
 from .mobilenet import MobileNet
 from .mobilenetv2 import MobileNetV2
 from .nets import CNNet, Cifarnet, Net
@@ -100,11 +103,18 @@ models = {
     # 32, published widths); lfm2_moe_tiny is the CPU tests' size.
     "lfm2_8b_a1b_ep4": lfm2_8b_a1b_ep4,
     "lfm2_moe_tiny": lfm2_moe_tiny,
+    # Mellum language models (models/mellum.py): window and full attention
+    # layers, a softmax router, an untied head. mellum2_12b_a2p5b_ep4 is one
+    # chip's share of Mellum2-12B-A2.5B under 4-way expert parallelism (4 of
+    # 28 layers, experts 0-15 of 64, published widths).
+    "mellum2_12b_a2p5b_ep4": mellum2_12b_a2p5b_ep4,
+    "mellum2_tiny": mellum2_tiny,
 }
 
 # tools.py:89 (+ the synthetic sequence datasets of data/__init__.py:
-# copytask, one label per sequence; synthtokens, whose "classes" are the
-# slice of the vocabulary its next-token labels are drawn from)
+# copytask, one label per sequence; the synthtokens datasets, whose
+# "classes" are the slice of the vocabulary their next-token labels are
+# drawn from: data.TOKEN_DATASETS has each one's slice and length)
 num_classes_dict = {
     "cifar10": 10,
     "cifar100": 100,
@@ -112,7 +122,7 @@ num_classes_dict = {
     "imagenet": 1000,
     "pima": 1,
     "copytask": 10,
-    "synthtokens": 16384,
+    **{name: vocab for name, (vocab, _) in data.TOKEN_DATASETS.items()},
 }
 
 

@@ -42,9 +42,11 @@ convention for any model's counters, by name as BatchNorm writes
 step's ``metrics`` under those names, one entry per expert layer.
 """
 
+import contextlib
 import dataclasses
 import functools
-from typing import Any, Sequence
+import math
+from typing import Any, Optional, Sequence
 
 import flax.linen as nn
 import jax
@@ -54,13 +56,19 @@ from ..ops import attention
 
 __all__ = [
     "SCOPES", "COUNTER_SUMS", "COUNTER_MAXES", "scope", "RMSNorm", "ShortConv",
-    "einsum_attention", "Attention", "SwiGLU", "ExpertLayer", "Sizes", "Block",
-    "Lfm2Moe", "lfm2_8b_a1b_ep4", "lfm2_moe_tiny",
+    "Yarn", "rope_table", "rotary", "einsum_attention", "Attention", "SwiGLU",
+    "ExpertLayer", "Sizes", "Block", "Lfm2Moe", "lfm2_8b_a1b_ep4",
+    "lfm2_moe_tiny",
 ]
 
+# ``attention`` stands around a whole attention module (this family's
+# blocks); a block that splits the module instead (`Attention.core_scope`)
+# has ``attention_proj`` around the projections, q/k norm and rotary
+# embedding and ``window_attention`` or ``full_attention`` around the core.
 SCOPES = (
     "embed", "conv_mixer", "attention", "dense_mlp", "moe_router",
     "moe_dispatch", "moe_experts", "moe_combine", "head_loss",
+    "attention_proj", "window_attention", "full_attention",
 )
 # parallel.core's two collections for a model's counters, by name.
 COUNTER_SUMS, COUNTER_MAXES = "counters_sum", "counters_max"
@@ -71,7 +79,10 @@ _normal = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
 
 
 def scope(name):
-    """``jax.named_scope("model.<name>")`` for a name of ``SCOPES``."""
+    """``jax.named_scope("model.<name>")`` for a name of ``SCOPES``; no scope
+    for None."""
+    if name is None:
+        return contextlib.nullcontext()
     if name not in SCOPES:
         raise ValueError(f"unknown model scope {name!r}; have {SCOPES}")
     return jax.named_scope("model." + name)
@@ -91,14 +102,57 @@ class RMSNorm(nn.Module):
         return (x32 * jax.lax.rsqrt(var + self.eps) * scale).astype(self.dtype)
 
 
-def rotary(x, theta):
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """A YaRN rotary table's parameters (Peng et al., arXiv:2309.00071), as
+    a Hugging Face ``rope_parameters`` group of ``rope_type`` ``yarn``
+    spells them."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+
+def rope_table(head_dim, theta, yarn=None):
+    """``(inverse frequencies (head_dim / 2,), scale)`` of a rotary table:
+    theta ** (-2i / head_dim) and 1, or with ``yarn`` Hugging Face's
+    ``_compute_yarn_parameters``: each frequency a blend of itself
+    (extrapolation) and itself over ``factor`` (interpolation) by a linear
+    ramp between the dimensions that turn ``beta_fast`` and ``beta_slow``
+    times over the original positions, cos and sin times
+    ``attention_factor``. The table does not depend on the sequence's
+    length."""
+    extrap = 1.0 / theta ** (
+        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    if yarn is None:
+        return extrap, 1.0
+
+    def turns(rotations):  # the dimension that turns this often
+        return head_dim * math.log(
+            yarn.original_max_position_embeddings
+            / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(turns(yarn.beta_fast)), 0)
+    high = min(math.ceil(turns(yarn.beta_slow)), head_dim - 1)
+    ramp = jnp.clip(
+        (jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+        / max(high - low, 1e-3), 0, 1)
+    return (extrap / yarn.factor * ramp + extrap * (1 - ramp),
+            yarn.attention_factor)
+
+
+def rotary(x, inv, scale=1.0):
     """Rotary embedding of ``x`` (batch, time, heads, head_dim) in float32,
-    half-rotation convention."""
+    half-rotation convention, by the table ``inv`` (inverse frequencies,
+    head_dim / 2) with cos and sin times ``scale`` (`rope_table`)."""
     t, d = x.shape[1], x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None]
     cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[None, :, None]
     sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[None, :, None]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x = x.astype(jnp.float32)
     x1, x2 = x[..., :d // 2], x[..., d // 2:]
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
@@ -129,11 +183,12 @@ class ShortConv(nn.Module):
         return _dense(hidden, self.dtype, "out_proj")(c * y)
 
 
-def einsum_attention(q, k, v):
+def einsum_attention(q, k, v, window=None):
     """Causal attention of q (n, t, heads, head) over k, v (n, t, kv_heads,
     head), each KV head serving heads / kv_heads adjacent query heads, as
     two einsums with the (t, t) scores between them: scores and softmax in
-    float32, probabilities rounded to v's dtype. The spec of
+    float32, probabilities rounded to v's dtype. With a ``window``, key j is
+    visible to query i iff 0 <= i - j < window. The spec of
     `ops.attention`'s kernels, and what runs where they do not."""
     n, t, heads, hd = q.shape
     kv = k.shape[2]
@@ -142,6 +197,8 @@ def einsum_attention(q, k, v):
                         preferred_element_type=jnp.float32)
     scores = scores / jnp.sqrt(jnp.float32(hd))
     causal = jnp.tril(jnp.ones((t, t), bool))
+    if window is not None:
+        causal &= ~jnp.tril(jnp.ones((t, t), bool), -window)
     probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
     mixed = jnp.einsum("nkgqs,nskd->nqkgd", probs.astype(v.dtype), v)
     return mixed.reshape(n, t, heads, hd)
@@ -153,7 +210,13 @@ class Attention(nn.Module):
     Softmax in float32. The core between the projections is
     `ops.attention.causal_gqa`: blockwise kernels that write no (t, t)
     array where the step is lowered for the TPU and t fills their blocks,
-    ``einsum_attention`` elsewhere; it says which once."""
+    ``einsum_attention`` elsewhere; it says which once.
+
+    ``window``: the keys a query sees, itself included (None: all before
+    it). ``yarn``: the rotary table's YaRN parameters (None: the plain
+    table of ``rope_theta``). ``core_scope``: a name of ``SCOPES`` to stand
+    around the core alone, with ``attention_proj`` around the rest of the
+    module (None: no scope of its own; the caller's stands around it all)."""
 
     heads: int
     kv_heads: int
@@ -161,21 +224,33 @@ class Attention(nn.Module):
     rope_theta: float = 1e6
     eps: float = 1e-5
     dtype: Any = jnp.float32
+    window: Optional[int] = None
+    yarn: Optional[Yarn] = None
+    core_scope: Optional[str] = None
 
     @nn.compact
     def __call__(self, u):
         n, t, hidden = u.shape
         heads, kv, hd = self.heads, self.kv_heads, self.head_dim
-        q = _dense(heads * hd, self.dtype, "q_proj")(u).reshape(n, t, heads, hd)
-        k = _dense(kv * hd, self.dtype, "k_proj")(u).reshape(n, t, kv, hd)
-        v = _dense(kv * hd, self.dtype, "v_proj")(u).reshape(n, t, kv, hd)
-        q = rotary(RMSNorm(self.eps, jnp.float32, name="q_norm")(q),
-                   self.rope_theta).astype(self.dtype)
-        k = rotary(RMSNorm(self.eps, jnp.float32, name="k_norm")(k),
-                   self.rope_theta).astype(self.dtype)
-        mixed = attention.causal_gqa(q, k, v, einsum_attention)
-        return _dense(hidden, self.dtype, "o_proj")(
-            mixed.reshape(n, t, heads * hd))
+        rest = "attention_proj" if self.core_scope else None
+        # Made where it is used, once for q and once for k, as `rotary` made
+        # it while it took a theta: the step keeps its operations' order.
+        table = functools.partial(rope_table, hd, self.rope_theta, self.yarn)
+        with scope(rest):
+            q = _dense(heads * hd, self.dtype, "q_proj")(u).reshape(
+                n, t, heads, hd)
+            k = _dense(kv * hd, self.dtype, "k_proj")(u).reshape(n, t, kv, hd)
+            v = _dense(kv * hd, self.dtype, "v_proj")(u).reshape(n, t, kv, hd)
+            q = rotary(RMSNorm(self.eps, jnp.float32, name="q_norm")(q),
+                       *table()).astype(self.dtype)
+            k = rotary(RMSNorm(self.eps, jnp.float32, name="k_norm")(k),
+                       *table()).astype(self.dtype)
+        with scope(self.core_scope):
+            mixed = attention.causal_gqa(
+                q, k, v, einsum_attention, window=self.window)
+        with scope(rest):
+            return _dense(hidden, self.dtype, "o_proj")(
+                mixed.reshape(n, t, heads * hd))
 
 
 class SwiGLU(nn.Module):
@@ -213,11 +288,14 @@ _permute.defvjp(_permute_fwd, _permute_bwd)
 
 class ExpertLayer(nn.Module):
     """The part of a mixture-of-experts feed-forward that the experts held
-    here give (module docstring). Scores are sigmoids over all
-    ``num_experts``; the top ``experts_per_token`` of score + bias are
-    chosen (the bias is a constant leaf under ``stop_gradient``: it enters
-    the selection only); a token's weights are its chosen scores over their
-    sum, held here or not, times ``scaling``."""
+    here give (module docstring). ``score`` is the router's law over all
+    ``num_experts``. ``sigmoid``: the top ``experts_per_token`` of score +
+    bias are chosen (the bias is a constant leaf under ``stop_gradient``: it
+    enters the selection only) and a token's weights are its chosen scores
+    over their sum + ``WEIGHT_EPS``. ``softmax``: the top of the softmax are
+    chosen and renormalised over their sum; no bias leaf, no epsilon. The
+    sums run over all the chosen, held here or not; weights times
+    ``scaling``."""
 
     num_experts: int
     experts_held: Sequence[int]
@@ -225,6 +303,7 @@ class ExpertLayer(nn.Module):
     width: int
     scaling: float = 1.0
     dtype: Any = jnp.float32
+    score: str = "sigmoid"
 
     @nn.compact
     def __call__(self, u):
@@ -235,16 +314,25 @@ class ExpertLayer(nn.Module):
         with scope("moe_router"):
             kernel = self.param(
                 "router_kernel", _normal, (hidden, self.num_experts))
-            bias = self.param(
-                "expert_bias", nn.initializers.zeros, (self.num_experts,))
-            scores = jax.nn.sigmoid(jnp.matmul(
+            logits = jnp.matmul(
                 x.astype(jnp.float32), kernel,
-                precision=jax.lax.Precision.HIGHEST))
-            _, chosen = jax.lax.top_k(
-                scores + jax.lax.stop_gradient(bias), k)
+                precision=jax.lax.Precision.HIGHEST)
+            if self.score == "sigmoid":
+                bias = self.param(
+                    "expert_bias", nn.initializers.zeros, (self.num_experts,))
+                scores = jax.nn.sigmoid(logits)
+                _, chosen = jax.lax.top_k(
+                    scores + jax.lax.stop_gradient(bias), k)
+            elif self.score == "softmax":
+                scores = jax.nn.softmax(logits, axis=-1)
+                _, chosen = jax.lax.top_k(scores, k)
+            else:
+                raise ValueError(f"unknown router law {self.score!r}")
             picked = jnp.take_along_axis(scores, chosen, axis=-1)
-            weights = picked / (
-                jnp.sum(picked, -1, keepdims=True) + WEIGHT_EPS) * self.scaling
+            total = jnp.sum(picked, -1, keepdims=True)
+            if self.score == "sigmoid":
+                total = total + WEIGHT_EPS
+            weights = picked / total * self.scaling
         with scope("moe_dispatch"):
             # The slot of each chosen expert among those held, ``held`` for
             # an absent one; pairs sorted by slot, absent pairs last.

@@ -25,6 +25,15 @@ Blocks wholly above the diagonal are skipped — their grid steps compute
 nothing and their block indices repeat the last needed one, so nothing is
 fetched for them —, blocks the diagonal crosses are masked.
 
+**A band, not only a triangle** (``window``): key j is visible to query i iff
+0 <= i - j < window. Blocks wholly below the band are skipped as those above
+the diagonal are (the block index is clamped into the band from both sides),
+blocks either edge crosses are masked, forward and backward. A row that a
+crossed block hides whole leaves ``exp(MASKED - MASKED) = 1`` terms in its
+running sums; the next block's ``exp(MASKED - max) = 0`` wipes them, and
+every row has at least its own key. ``window=None`` or ``window >= t`` is the
+causal program, operation for operation.
+
 **Arithmetic**: the einsum path's. Operands in their dtype (bf16 in the token
 cell), scores accumulated in float32 and scaled by 1 / sqrt(head), softmax
 statistics in float32, probabilities rounded to the operands' dtype for the
@@ -112,12 +121,16 @@ def misfit(shape, kv_heads, dtype, block=None, lowered=True):
     return None
 
 
-def blocks_run(t, bq, bk):
-    """``(run, all)``: the (q block, key block) pairs at or under the
-    diagonal, which the kernels compute, and all pairs."""
-    nq, nk = t // bq, t // bk
-    return sum(1 for i in range(nq) for j in range(nk)
-               if j * bk < (i + 1) * bq), nq * nk
+def blocks_run(t, bq, bk, window=None):
+    """``(run, above, below)``: the (q block, key block) pairs the kernels
+    compute — at or under the diagonal and, with a ``window``, reaching into
+    the band —, those skipped above the diagonal and those skipped below the
+    band."""
+    pairs = [(i, j) for i in range(t // bq) for j in range(t // bk)]
+    above = sum(1 for i, j in pairs if j * bk >= (i + 1) * bq)
+    below = 0 if window is None else sum(
+        1 for i, j in pairs if i * bq - (j + 1) * bk + 1 >= window)
+    return len(pairs) - above - below, above, below
 
 
 def _across(stat, width):
@@ -126,20 +139,26 @@ def _across(stat, width):
     return jnp.tile(stat, (1, pl.cdiv(width, LANES)))[:, :width]
 
 
-def _visible(i, j, bq, bk, keys_first):
-    """Key position <= q position over block (i, j), q rows by keys or
-    (``keys_first``) keys by q rows."""
+def _visible(i, j, bq, bk, window, keys_first):
+    """0 <= q position - key position (< ``window``, where there is one)
+    over block (i, j), q rows by keys or (``keys_first``) keys by q rows."""
     shape, q_axis = ((bk, bq), 1) if keys_first else ((bq, bk), 0)
     q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
-    return k_pos <= q_pos
+    if window is None:
+        return k_pos <= q_pos
+    return (k_pos <= q_pos) & (q_pos - k_pos < window)
 
 
-def _when_under_diagonal(i, j, bq, bk, step):
+def _when_in_band(i, j, bq, bk, window, step):
     """Run ``step(masked)`` for block (i, j) unless every key of it lies
-    after every q row: masked where the diagonal crosses it."""
+    after every q row or, with a ``window``, a window or more before every
+    one: masked where the diagonal or the band's far edge crosses it."""
     crossed = (j + 1) * bk - 1 > i * bq
     runs = j * bk < (i + 1) * bq
+    if window is not None:
+        crossed |= (i + 1) * bq - 1 - j * bk >= window
+        runs &= i * bq - (j + 1) * bk + 1 < window
     pl.when(runs & crossed)(functools.partial(step, True))
     pl.when(runs & ~crossed)(functools.partial(step, False))
 
@@ -160,7 +179,7 @@ def _store_rows(ref, stat, group, bq):
 
 
 def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                    acc_ref, *, scale, group, bq, bk):
+                    acc_ref, *, scale, group, bq, bk, window):
     i, j = pl.program_id(2), pl.program_id(3)
     rows, head = group * bq, q_ref.shape[-1]
 
@@ -175,7 +194,7 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         s = jax.lax.dot_general(
             q, k_ref[0, 0], _NT, preferred_element_type=jnp.float32) * scale
         if masked:
-            s = jnp.where(_visible(i, j, bq, bk, False)[None],
+            s = jnp.where(_visible(i, j, bq, bk, window, False)[None],
                           s.reshape(group, bq, bk), MASKED).reshape(rows, bk)
         m_prev = m_ref[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -187,7 +206,7 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
             p.astype(v_ref.dtype), v_ref[0, 0],
             preferred_element_type=jnp.float32)
 
-    _when_under_diagonal(i, j, bq, bk, step)
+    _when_in_band(i, j, bq, bk, window, step)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _():
@@ -199,7 +218,7 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 
 def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                     scale, group, bq, bk):
+                     scale, group, bq, bk, window):
     j, i = pl.program_id(2), pl.program_id(3)
     last_j, last_i = pl.num_programs(2) - 1, pl.num_programs(3) - 1
 
@@ -214,7 +233,7 @@ def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def step(masked):
         k, v = k_ref[0, 0], v_ref[0, 0]
-        visible = _visible(i, j, bq, bk, True) if masked else None
+        visible = _visible(i, j, bq, bk, window, True) if masked else None
         q_rows = pl.ds(pl.multiple_of(i * bq, bq), bq)
         for h in range(group):
             q, do = q_ref[0, 0, h], do_ref[0, 0, h]
@@ -233,7 +252,7 @@ def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_acc[h, q_rows, :] += jnp.dot(
                 ds.T.astype(k.dtype), k, preferred_element_type=jnp.float32)
 
-    _when_under_diagonal(i, j, bq, bk, step)
+    _when_in_band(i, j, bq, bk, window, step)
 
     # The scores' scale, left out of ds above, goes onto the sums.
     @pl.when(i == last_i)
@@ -251,15 +270,18 @@ def _params(*semantics):
         dimension_semantics=semantics, vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
-def _forward(q, k, v, block, interpret):
+def _forward(q, k, v, block, window, interpret):
     """q (n, kv, group, t, head), k and v (n, kv, t, head) -> the output in
     q's shape and dtype and the rows' log-sum-exp (n, kv, group, t)."""
     n, kv, group, t, head = q.shape
     bq, bk = block
     rows = group * bq
 
-    def needed(i, j):  # key block j, or the last that q block i sees
-        return jnp.minimum(j, ((i + 1) * bq - 1) // bk)
+    def needed(i, j):  # key block j, or the nearest that q block i sees
+        j = jnp.minimum(j, ((i + 1) * bq - 1) // bk)
+        if window is None:
+            return j
+        return jnp.maximum(j, jnp.maximum(i * bq - window + 1, 0) // bk)
 
     q_spec = pl.BlockSpec(
         (1, 1, group, bq, head), lambda b, h, i, j: (b, h, 0, i, 0))
@@ -267,7 +289,8 @@ def _forward(q, k, v, block, interpret):
         (1, 1, bk, head), lambda b, h, i, j: (b, h, needed(i, j), 0))
     return pl.pallas_call(
         functools.partial(
-            _forward_kernel, scale=head ** -0.5, group=group, bq=bq, bk=bk),
+            _forward_kernel, scale=head ** -0.5, group=group, bq=bq, bk=bk,
+            window=window),
         grid=(n, kv, t // bq, t // bk),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, pl.BlockSpec(
@@ -286,14 +309,18 @@ def _forward(q, k, v, block, interpret):
     )(q, k, v)
 
 
-def _backward(q, k, v, o, lse, do, block, interpret):
+def _backward(q, k, v, o, lse, do, block, window, interpret):
     n, kv, group, t, head = q.shape
     bq, bk = block
     delta = jnp.sum(
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
 
-    def needed(j, i):  # q block i, or the first that sees key block j
-        return jnp.maximum(i, j * bk // bq)
+    def needed(j, i):  # q block i, or the nearest that sees key block j
+        i = jnp.maximum(i, j * bk // bq)
+        if window is None:
+            return i
+        return jnp.minimum(
+            i, jnp.minimum((j + 1) * bk + window - 2, t - 1) // bq)
 
     q_spec = pl.BlockSpec(
         (1, 1, group, bq, head),
@@ -306,7 +333,8 @@ def _backward(q, k, v, o, lse, do, block, interpret):
         (1, 1, group, t, head), lambda b, h, j, i: (b, h, 0, 0, 0))
     return pl.pallas_call(
         functools.partial(
-            _backward_kernel, scale=head ** -0.5, group=group, bq=bq, bk=bk),
+            _backward_kernel, scale=head ** -0.5, group=group, bq=bq, bk=bk,
+            window=window),
         grid=(n, kv, t // bk, t // bq),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[dq_spec, kv_spec, kv_spec],
@@ -325,55 +353,69 @@ def _backward(q, k, v, o, lse, do, block, interpret):
     )(q, k, v, do, lse, delta)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _core(q, k, v, block, interpret):
-    return _forward(q, k, v, block, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _core(q, k, v, block, window, interpret):
+    return _forward(q, k, v, block, window, interpret)[0]
 
 
-def _core_fwd(q, k, v, block, interpret):
-    o, lse = _forward(q, k, v, block, interpret)
+def _core_fwd(q, k, v, block, window, interpret):
+    o, lse = _forward(q, k, v, block, window, interpret)
     return o, (q, k, v, o, lse)
 
 
-def _core_bwd(block, interpret, kept, do):
-    return tuple(_backward(*kept, do, block, interpret))
+def _core_bwd(block, window, interpret, kept, do):
+    return tuple(_backward(*kept, do, block, window, interpret))
 
 
 _core.defvjp(_core_fwd, _core_bwd)
 
 
-def blockwise(q, k, v, *, block=None, interpret=False):
+def _band(window, t):
+    """``window``, or None where it hides nothing a causal mask shows."""
+    return None if window is None or window >= t else int(window)
+
+
+def blockwise(q, k, v, *, window=None, block=None, interpret=False):
     """The kernels on q (n, t, heads, head) and k, v (n, t, kv_heads, head),
     heads of one group adjacent; the output in q's shape and dtype.
+    ``window``: keys a query sees, itself included (None: all before it);
     ``block``: rows of q a head and keys a block, one int or a pair."""
     n, t, heads, head = q.shape
     kv = k.shape[2]
     block = _blocks(t, block)
     grouped = q.reshape(n, t, kv, heads // kv, head).transpose(0, 2, 3, 1, 4)
     out = _core(grouped, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-                block, interpret)
+                block, _band(window, t), interpret)
     return out.transpose(0, 3, 1, 2, 4).reshape(q.shape)
 
 
-def causal_gqa(q, k, v, fallback, *, block=None, interpret=False):
+def causal_gqa(q, k, v, fallback, *, window=None, block=None,
+               interpret=False):
     """Causal grouped-query attention of q (n, t, heads, head) over k, v
-    (n, t, kv_heads, head) by the kernels where they apply (module
-    docstring), else ``fallback(q, k, v)``; says which once."""
-    kv = k.shape[2]
+    (n, t, kv_heads, head), a query seeing the ``window`` keys up to its own
+    (None: all of them), by the kernels where they apply (module docstring),
+    else ``fallback(q, k, v, window)``; says which once."""
+    n, t, heads, head = q.shape
+    kv, window = k.shape[2], _band(window, t)
+    fallback = functools.partial(fallback, window=window)
     why = misfit(q.shape, kv, q.dtype, block, lowered=not interpret)
     if why is None and not interpret and not coordinate.use_pallas():
         why = "no TPU lowering"
     if why is not None:
         _say(f"[attention] einsum: {why}")
         return fallback(q, k, v)
-    n, t, heads, head = q.shape
     bq, bk = _blocks(t, block)
-    run, of = blocks_run(t, bq, bk)
+    run, above, below = blocks_run(t, bq, bk, window)
+    of = run + above + below
     _say(f"[attention] blockwise: (n, heads, kv_heads, t, head) = "
          f"({n}, {heads}, {kv}, {t}, {head}) {jnp.dtype(q.dtype).name}, "
-         f"blocks ({bq}, {bk}), causal blocks skipped {of - run} of {of}"
+         f"blocks ({bq}, {bk}), "
+         + (f"causal blocks skipped {above} of {of}" if window is None else
+            f"window {window}, blocks run {run} of {of} (skipped {above} "
+            f"above the diagonal, {below} below the band)")
          + (", interpret mode" if interpret else ""))
-    kernels = functools.partial(blockwise, block=block, interpret=interpret)
+    kernels = functools.partial(
+        blockwise, window=window, block=block, interpret=interpret)
     if interpret:
         return kernels(q, k, v)
     return jax.lax.platform_dependent(

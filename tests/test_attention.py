@@ -155,12 +155,128 @@ def test_the_line_names_the_blocks_and_is_said_once(capsys):
         "[attention] blockwise: (n, heads, kv_heads, t, head) = "
         "(2, 8, 2, 32, 16) bfloat16, blocks (8, 8), causal blocks skipped "
         "6 of 16, interpret mode"]
-    assert attention.blocks_run(2048, 512, 512) == (10, 16)
-    assert attention.blocks_run(2048, 256, 512) == (20, 32)
+    assert attention.blocks_run(2048, 512, 512) == (10, 6, 0)
+    assert attention.blocks_run(2048, 256, 512) == (20, 12, 0)
+    # The sliding layers of the window cell: 21 of 64 run, causal 36.
+    assert attention.blocks_run(4096, 512, 512, 1024) == (21, 28, 15)
+    assert attention.blocks_run(4096, 512, 512) == (36, 28, 0)
+
+
+# Windows over 32 positions in blocks of 8 (or 8 x 16, 16 x 8): one that
+# aligns with the blocks, ones that cut them (so a crossed block hides whole
+# rows, whose running sums the next block has to wipe), one key alone, one
+# block and a bit, and one short of t.
+WINDOWS = (1, 5, 8, 13, 16, 31)
+BAND_CASES = [(name, window, dtype)
+              for name in ("group4-several-head16", "group4-wide-key-blocks",
+                           "group4-tall-q-blocks", "group1-several-head64")
+              for window in WINDOWS for dtype in (jnp.float32, jnp.bfloat16)
+              if dtype == jnp.float32 or window in (5, 16)]
+
+
+@pytest.mark.parametrize(
+    "name,window,dtype", BAND_CASES,
+    ids=[f"{n}-w{w}-{jnp.dtype(d).name}" for n, w, d in BAND_CASES])
+def test_the_banded_kernels_equal_the_banded_einsum(name, window, dtype):
+    """Output, dq, dk and dv with a window that aligns with, cuts and
+    exceeds the blocks, under the causal tests' tolerances."""
+    operands, weight, block = _operands(name, dtype)
+
+    def both(core):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(core(*a).astype(jnp.float32) * weight),
+            argnums=(0, 1, 2))(*operands)
+
+    want_out = einsum_attention(*operands, window=window)
+    got_out = attention.blockwise(
+        *operands, window=window, block=block, interpret=True)
+    tol = 5e-6 if dtype == jnp.float32 else 2.0 ** -7
+    np.testing.assert_allclose(
+        _f32(got_out), _f32(want_out), rtol=tol, atol=tol)
+    _, got = both(functools.partial(
+        attention.blockwise, window=window, block=block, interpret=True))
+    _, want = both(functools.partial(einsum_attention, window=window))
+    tol = 1e-5 if dtype == jnp.float32 else 2.0 ** -6
+    # With one key the softmax is the constant 1 and dq, dk are zero by the
+    # einsum path: rounding of the others' size is all the kernels leave.
+    largest = max(float(np.abs(_f32(b)).max()) for b in want)
+    for leaf, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_allclose(
+            _f32(a), _f32(b), err_msg=leaf, atol=tol * max(
+                float(np.abs(_f32(b)).max()), 0.1 * largest))
+    # The window does hide keys: the causal result differs.
+    assert float(np.abs(_f32(want_out) - _f32(
+        einsum_attention(*operands))).max()) > 1e-3
+
+
+@pytest.mark.parametrize("window", [None, 32, 33, 4096])
+def test_a_window_of_t_or_more_is_the_causal_program_bit_for_bit(
+        window, capsys):
+    """Outputs and gradients equal to the bit, the same line said, and the
+    same jaxpr: no operation is added for a window that hides nothing."""
+    operands, weight, block = _operands("group4-several-head16", jnp.bfloat16)
+
+    def loss(window):
+        return lambda *a: jnp.sum(attention.causal_gqa(
+            *a, einsum_attention, window=window, block=block,
+            interpret=True).astype(jnp.float32) * weight)
+
+    want = jax.value_and_grad(loss(None), argnums=(0, 1, 2))(*operands)
+    causal_line = capsys.readouterr().err
+    attention._said.clear()
+    got = jax.value_and_grad(loss(window), argnums=(0, 1, 2))(*operands)
+    assert capsys.readouterr().err == causal_line
+    assert "causal blocks skipped 6 of 16" in causal_line
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(_f32(a), _f32(b))
+    text = [str(jax.make_jaxpr(jax.grad(loss(w), argnums=(0, 1, 2)))(
+        *operands)) for w in (None, window)]
+    assert text[0] == text[1]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_key_a_window_back_moves_nothing(dtype):
+    """With a window of 5 over blocks of 8, row i sees keys i - 4 .. i:
+    changing k and v at position 9 moves rows 9..13 and no other, outputs
+    and dq; the line names the window and both kinds of skipped block."""
+    (q, k, v), weight, block = _operands("group4-several-head16", dtype)
+    core = functools.partial(
+        attention.blockwise, window=5, block=block, interpret=True)
+    k2, v2 = k.at[:, 9].add(1), v.at[:, 9].multiply(-2)
+    out, later = core(q, k, v), core(q, k2, v2)
+    still = np.r_[0:9, 14:32]
+    np.testing.assert_array_equal(_f32(out[:, still]), _f32(later[:, still]))
+    moved = np.abs(_f32(out[:, 9:14]) - _f32(later[:, 9:14])).max(axis=-1)
+    assert float(moved.min()) > 0
+
+    def dq(k, v):
+        return jax.grad(lambda q: jnp.sum(
+            core(q, k, v).astype(jnp.float32) * weight))(q)
+
+    a, b = dq(k, v), dq(k2, v2)
+    np.testing.assert_array_equal(_f32(a[:, still]), _f32(b[:, still]))
+    assert attention.blocks_run(32, 8, 8, 5) == (7, 6, 3)
+
+
+def test_the_line_names_the_window_and_the_blocks_skipped_on_each_side(
+        capsys):
+    operands, _, block = _operands("group4-several-head16", jnp.bfloat16)
+    attention.causal_gqa(*operands, einsum_attention, window=5, block=block,
+                         interpret=True)
+    assert ("blocks (8, 8), window 5, blocks run 7 of 16 (skipped 6 above "
+            "the diagonal, 3 below the band), interpret mode"
+            ) in capsys.readouterr().err
+    # The einsum way takes the window too.
+    got = attention.causal_gqa(*operands, einsum_attention, window=5)
+    assert "[attention] einsum: t = 32" in capsys.readouterr().err
+    np.testing.assert_array_equal(
+        _f32(got), _f32(einsum_attention(*operands, window=5)))
 
 
 @pytest.mark.parametrize("shape,kv,dtype,why", [
     ((2, 2048, 32, 64), 8, jnp.bfloat16, None),
+    ((1, 4096, 32, 128), 4, jnp.bfloat16, None),
     ((2, 2048, 32, 64), 8, jnp.float32, None),
     ((1, 384, 4, 128), 4, jnp.bfloat16, None),
     ((2, 2048, 32, 64), 5, jnp.bfloat16, "do not share"),

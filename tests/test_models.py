@@ -29,7 +29,7 @@ def test_num_classes_dict_parity():
     # reference counterpart for either).
     assert models.num_classes_dict == {
         "cifar10": 10, "cifar100": 100, "mnist": 10, "imagenet": 1000, "pima": 1,
-        "copytask": 10, "synthtokens": 16384,
+        "copytask": 10, "synthtokens": 16384, "synthtokens24k": 24576,
     }
 
 

@@ -118,11 +118,14 @@ def test_operands_in_place_on_device(n, f, tail):
         np.asarray(got, np.float32), np.asarray(want, np.float32))
 
 
-@pytest.mark.parametrize("n,heads,kv,t,head,dtype", [
-    (2, 32, 8, 2048, 64, jnp.bfloat16),   # one slot of lfm2n4
-    (1, 4, 4, 384, 128, jnp.float32),     # group 1, blocks of 128, head 128
+@pytest.mark.parametrize("n,heads,kv,t,head,dtype,window", [
+    (2, 32, 8, 2048, 64, jnp.bfloat16, None),   # one slot of lfm2n4
+    (1, 4, 4, 384, 128, jnp.float32, None),  # group 1, blocks of 128, head 128
+    (1, 32, 4, 4096, 128, jnp.bfloat16, 1024),  # a sliding layer of mellum2n4
+    (1, 32, 4, 4096, 128, jnp.bfloat16, None),  # its full layer: dq at 32 MiB
+    (1, 8, 2, 1024, 64, jnp.bfloat16, 200),     # a window that cuts blocks
 ])
-def test_attention_kernels_on_device(n, heads, kv, t, head, dtype):
+def test_attention_kernels_on_device(n, heads, kv, t, head, dtype, window):
     """The blockwise attention kernels (ops/attention.py) through real
     Mosaic lowering, taken by ``causal_gqa`` itself, against the einsum
     path: the output and dq, dk, dv under ``jax.grad`` with the block
@@ -147,8 +150,9 @@ def test_attention_kernels_on_device(n, heads, kv, t, head, dtype):
             loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
 
     (_, got), got_grads = both(
-        lambda *a: attention.causal_gqa(*a, einsum_attention))
-    (_, want), want_grads = both(einsum_attention)
+        lambda *a: attention.causal_gqa(*a, einsum_attention, window=window))
+    (_, want), want_grads = both(
+        lambda *a: einsum_attention(*a, window=window))
     f32 = lambda x: np.asarray(x, np.float32)
     np.testing.assert_allclose(
         f32(got), f32(want), rtol=2.0 ** -7, atol=2.0 ** -7)
