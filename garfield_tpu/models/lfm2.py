@@ -51,11 +51,13 @@ from typing import Any, Optional, Sequence
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import attention
 
 __all__ = [
-    "SCOPES", "COUNTER_SUMS", "COUNTER_MAXES", "scope", "RMSNorm", "ShortConv",
+    "SCOPES", "COUNTER_SUMS", "COUNTER_MAXES", "KEPT", "scope", "keep",
+    "recomputed", "RMSNorm", "ShortConv",
     "Yarn", "rope_table", "rotary", "einsum_attention", "Attention", "SwiGLU",
     "ExpertLayer", "Sizes", "Block", "Lfm2Moe", "lfm2_8b_a1b_ep4",
     "lfm2_moe_tiny",
@@ -74,6 +76,25 @@ SCOPES = (
 COUNTER_SUMS, COUNTER_MAXES = "counters_sum", "counters_max"
 # Added to the sum of a token's selected scores before it divides them.
 WEIGHT_EPS = 1e-6
+# What a recomputed block (``remat``) keeps of its forward pass, by the name
+# `keep` gives it where it is made: what a matmul, a kernel or a sort made,
+# as far as the backward pass reads it. The projections of the two
+# operators (the last of each, ``conv_out`` and ``attention_o_proj``, is
+# what the feed-forward's norm reads; ``v_proj``'s result reaches the
+# backward pass as the kernels' ``attention_v``, so it has no name); the
+# dense MLP's two inner products; of an expert layer the router's logits
+# and choice, the two sorts and the groups' sizes, the rows as dispatched,
+# the two inner grouped matmuls and the third as the combine reads it,
+# rows back in place; and `ops.attention`'s own. Norms, the rotary
+# embedding, gates, masks, casts and the combine's sum are computed again.
+# One set for every family made of this file's modules: a family differs
+# in which of the sites it contains.
+KEPT = (
+    "conv_in", "conv_out", "attention_q_proj", "attention_k_proj",
+    "attention_o_proj", "mlp_w1", "mlp_w3", "moe_logits", "moe_chosen",
+    "moe_order", "moe_inverse", "moe_sizes", "moe_rows", "moe_w1", "moe_w3",
+    "moe_out",
+) + attention.KEPT
 
 _normal = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
 
@@ -86,6 +107,43 @@ def scope(name):
     if name not in SCOPES:
         raise ValueError(f"unknown model scope {name!r}; have {SCOPES}")
     return jax.named_scope("model." + name)
+
+
+# names -> bytes kept under them, of the model whose blocks are being traced.
+_kept_bytes = {}
+
+
+def _count(names, nbytes):
+    _kept_bytes[names] = _kept_bytes.get(names, 0) + nbytes
+
+
+def keep(x, name):
+    """``x`` under ``name`` of ``KEPT``: the identity, and the mark by which
+    `recomputed`'s policy keeps ``x`` for the backward pass."""
+    if name not in KEPT:
+        raise ValueError(f"unknown name {name!r}; a block keeps {KEPT}")
+    _count((name,), x.size * x.dtype.itemsize)
+    return checkpoint_name(x, name)
+
+
+@contextlib.contextmanager
+def recomputed(block, remat):
+    """``block`` itself, or with ``remat`` the class whose instances are
+    recomputed in the backward pass but for what they made under a name of
+    ``KEPT``. Leaving, says once what the blocks made inside keep: ``[remat]
+    block keeps <k> names: <names>; per slot <x> GB (reckoned from
+    shapes)``."""
+    if not remat:
+        yield block
+        return
+    _kept_bytes.clear()
+    yield nn.remat(
+        block, policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+    names = [name for names in _kept_bytes for name in names]
+    attention.say(
+        f"[remat] block keeps {len(names)} names: {', '.join(names)}; per "
+        f"slot {sum(_kept_bytes.values()) / 1e9:.3f} GB (reckoned from "
+        "shapes)")
 
 
 class RMSNorm(nn.Module):
@@ -174,13 +232,14 @@ class ShortConv(nn.Module):
     def __call__(self, u):
         hidden = u.shape[-1]
         b, c, x = jnp.split(
-            _dense(3 * hidden, self.dtype, "in_proj")(u), 3, axis=-1)
+            keep(_dense(3 * hidden, self.dtype, "in_proj")(u), "conv_in"),
+            3, axis=-1)
         taps = self.param("conv_kernel", _normal, (self.length, hidden))
         z = b * x
         padded = jnp.pad(z, ((0, 0), (self.length - 1, 0), (0, 0)))
         y = sum(taps[j].astype(self.dtype) * padded[:, j:j + z.shape[1]]
                 for j in range(self.length))
-        return _dense(hidden, self.dtype, "out_proj")(c * y)
+        return keep(_dense(hidden, self.dtype, "out_proj")(c * y), "conv_out")
 
 
 def einsum_attention(q, k, v, window=None):
@@ -237,9 +296,10 @@ class Attention(nn.Module):
         # it while it took a theta: the step keeps its operations' order.
         table = functools.partial(rope_table, hd, self.rope_theta, self.yarn)
         with scope(rest):
-            q = _dense(heads * hd, self.dtype, "q_proj")(u).reshape(
-                n, t, heads, hd)
-            k = _dense(kv * hd, self.dtype, "k_proj")(u).reshape(n, t, kv, hd)
+            q = keep(_dense(heads * hd, self.dtype, "q_proj")(u),
+                     "attention_q_proj").reshape(n, t, heads, hd)
+            k = keep(_dense(kv * hd, self.dtype, "k_proj")(u),
+                     "attention_k_proj").reshape(n, t, kv, hd)
             v = _dense(kv * hd, self.dtype, "v_proj")(u).reshape(n, t, kv, hd)
             q = rotary(RMSNorm(self.eps, jnp.float32, name="q_norm")(q),
                        *table()).astype(self.dtype)
@@ -247,10 +307,11 @@ class Attention(nn.Module):
                        *table()).astype(self.dtype)
         with scope(self.core_scope):
             mixed = attention.causal_gqa(
-                q, k, v, einsum_attention, window=self.window)
+                q, k, v, einsum_attention, window=self.window, kept=_count)
         with scope(rest):
-            return _dense(hidden, self.dtype, "o_proj")(
-                mixed.reshape(n, t, heads * hd))
+            return keep(
+                _dense(hidden, self.dtype, "o_proj")(
+                    mixed.reshape(n, t, heads * hd)), "attention_o_proj")
 
 
 class SwiGLU(nn.Module):
@@ -261,9 +322,9 @@ class SwiGLU(nn.Module):
 
     @nn.compact
     def __call__(self, u):
-        gate = nn.silu(_dense(self.width, self.dtype, "w1")(u))
+        gate = nn.silu(keep(_dense(self.width, self.dtype, "w1")(u), "mlp_w1"))
         return _dense(u.shape[-1], self.dtype, "w2")(
-            gate * _dense(self.width, self.dtype, "w3")(u))
+            gate * keep(_dense(self.width, self.dtype, "w3")(u), "mlp_w3"))
 
 
 @jax.custom_vjp
@@ -314,9 +375,9 @@ class ExpertLayer(nn.Module):
         with scope("moe_router"):
             kernel = self.param(
                 "router_kernel", _normal, (hidden, self.num_experts))
-            logits = jnp.matmul(
+            logits = keep(jnp.matmul(
                 x.astype(jnp.float32), kernel,
-                precision=jax.lax.Precision.HIGHEST)
+                precision=jax.lax.Precision.HIGHEST), "moe_logits")
             if self.score == "sigmoid":
                 bias = self.param(
                     "expert_bias", nn.initializers.zeros, (self.num_experts,))
@@ -328,6 +389,7 @@ class ExpertLayer(nn.Module):
                 _, chosen = jax.lax.top_k(scores, k)
             else:
                 raise ValueError(f"unknown router law {self.score!r}")
+            chosen = keep(chosen, "moe_chosen")
             picked = jnp.take_along_axis(scores, chosen, axis=-1)
             total = jnp.sum(picked, -1, keepdims=True)
             if self.score == "sigmoid":
@@ -339,15 +401,18 @@ class ExpertLayer(nn.Module):
             slot_of = jnp.full((self.num_experts,), held, jnp.int32).at[
                 jnp.asarray(self.experts_held)].set(jnp.arange(held))
             slots = slot_of[chosen].reshape(-1)
-            order = jnp.argsort(slots, stable=True)
-            inverse = jnp.argsort(order)
-            sizes = jnp.sum(
+            # Named before they enter `_permute`, whose rule hands them on
+            # as they come.
+            order = keep(jnp.argsort(slots, stable=True), "moe_order")
+            inverse = keep(jnp.argsort(order), "moe_inverse")
+            sizes = keep(jnp.sum(
                 slots[:, None] == jnp.arange(held)[None], axis=0,
-                dtype=jnp.int32)
+                dtype=jnp.int32), "moe_sizes")
             here = (jnp.arange(tokens * k) < jnp.sum(sizes))[:, None]
             rows = jnp.broadcast_to(
                 x[:, None], (tokens, k, hidden)).reshape(-1, hidden)
-            rows = jnp.where(here, _permute(rows, order, inverse), 0)
+            rows = keep(
+                jnp.where(here, _permute(rows, order, inverse), 0), "moe_rows")
         with scope("moe_experts"):
             w1 = self.param("w1", _stack_init, (held, hidden, self.width))
             w3 = self.param("w3", _stack_init, (held, hidden, self.width))
@@ -355,14 +420,18 @@ class ExpertLayer(nn.Module):
             dot = functools.partial(
                 jax.lax.ragged_dot, group_sizes=sizes,
                 preferred_element_type=self.dtype)
-            gate = nn.silu(dot(rows, w1.astype(self.dtype)))
-            out = dot(gate * dot(rows, w3.astype(self.dtype)),
-                      w2.astype(self.dtype))
+            gate = nn.silu(keep(dot(rows, w1.astype(self.dtype)), "moe_w1"))
+            out = dot(
+                gate * keep(dot(rows, w3.astype(self.dtype)), "moe_w3"),
+                w2.astype(self.dtype))
         with scope("moe_combine"):
             # Rows past the last group belong to absent experts: the grouped
             # matmul leaves them unvisited, so they are set to zero here (and
             # their cotangent above) and not multiplied by a zero weight.
-            out = _permute(jnp.where(here, out, 0), inverse, order)
+            # Kept in place: the weights' gradient reads these rows, and
+            # neither the grouped matmul nor the permutation runs again.
+            out = keep(
+                _permute(jnp.where(here, out, 0), inverse, order), "moe_out")
             out = jnp.sum(
                 out.reshape(tokens, k, hidden)
                 * weights[..., None].astype(self.dtype), axis=1)
@@ -440,7 +509,8 @@ class Lfm2Moe(nn.Module):
     final RMSNorm, logits over the ``vocab`` rows held (float32).
 
     ``num_classes`` is the vocabulary slice (``models.select_model`` passes
-    the dataset's). ``remat`` recomputes each block in the backward pass."""
+    the dataset's). ``remat`` recomputes each block in the backward pass but
+    for what it made under a name of ``KEPT`` (`recomputed`)."""
 
     num_classes: int = 16384
     dtype: Any = jnp.float32
@@ -477,10 +547,10 @@ class Lfm2Moe(nn.Module):
                 embedding_init=nn.initializers.normal(self.hidden ** -0.5),
                 name="embed")
             h = table(tokens)
-        block = nn.remat(Block) if self.remat else Block
-        for i, kind in enumerate(self.layer_types):
-            h = block(kind, self.sizes(), i >= self.num_dense_layers,
-                      self.dtype, name=f"layer_{i}")(h)
+        with recomputed(Block, self.remat) as block:
+            for i, kind in enumerate(self.layer_types):
+                h = block(kind, self.sizes(), i >= self.num_dense_layers,
+                          self.dtype, name=f"layer_{i}")(h)
         with scope("head_loss"):
             h = RMSNorm(self.eps, self.dtype, name="final_norm")(h)
             return table.attend(h).astype(jnp.float32)
@@ -491,9 +561,14 @@ def lfm2_8b_a1b_ep4(num_classes=16384, dtype=jnp.float32):
     expert parallelism: published layers 1 and 3-6 (one leading dense layer
     and the first whole period of expert layers), experts 0-7 of 32, every width as
     published; ``num_classes`` is the vocabulary slice (16,384 of 65,536).
-    Each block is recomputed in the backward pass: without that, 4 workers'
-    16,384 tokens a step beside a 4 x 508M gradient stack do not fit one
-    v5e (16.3 GiB of 15.75 at compile, PERF.md section 4)."""
+    ``remat``: without recomputation 4 workers' 16,384 tokens a step beside
+    a 4 x 508M gradient stack do not fit one v5e (16.3 GiB of 15.75 at
+    compile, PERF.md section 4), so each block is recomputed in the
+    backward pass, but for ``KEPT``: what its matmuls, kernels and sorts
+    made (1.48 GB a slot, reckoned from the shapes; the trainers' unroll
+    runs the slots one after another, so one slot's is held at a time).
+    What is computed again is elementwise: norms, rotary embedding, gates,
+    masks, casts."""
     return Lfm2Moe(num_classes=num_classes, dtype=dtype, remat=True)
 
 

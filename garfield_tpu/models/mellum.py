@@ -86,7 +86,8 @@ class Block(nn.Module):
 class Mellum(nn.Module):
     """The model: embedding, ``layer_types`` blocks, a final RMSNorm, logits
     over the ``num_classes`` vocabulary rows held (float32) through a head of
-    its own. ``remat`` recomputes each block in the backward pass."""
+    its own. ``remat`` recomputes each block in the backward pass but for
+    what it made under a name of `lfm2.KEPT` (`lfm2.recomputed`)."""
 
     num_classes: int = 24576
     dtype: Any = jnp.float32
@@ -124,9 +125,10 @@ class Mellum(nn.Module):
             h = nn.Embed(self.num_classes, self.hidden, dtype=self.dtype,
                          embedding_init=nn.initializers.normal(1.0),
                          name="embed")(tokens)
-        block = nn.remat(Block) if self.remat else Block
-        for i, kind in enumerate(self.layer_types):
-            h = block(kind, self.sizes(), self.dtype, name=f"layer_{i}")(h)
+        with lfm2.recomputed(Block, self.remat) as block:
+            for i, kind in enumerate(self.layer_types):
+                h = block(kind, self.sizes(), self.dtype,
+                          name=f"layer_{i}")(h)
         with lfm2.scope("head_loss"):
             h = lfm2.RMSNorm(self.eps, self.dtype, name="final_norm")(h)
             head = nn.Embed(
@@ -141,8 +143,12 @@ def mellum2_12b_a2p5b_ep4(num_classes=24576, dtype=jnp.float32):
     by expert parallelism: published layers 0-3 (the first whole period:
     three sliding layers and one full), experts 0-15 of 64, every width as
     published; ``num_classes`` is the vocabulary slice (24,576 of 98,304).
-    Each block is recomputed in the backward pass, as in `lfm2_8b_a1b_ep4`:
-    4 workers' 16,384 tokens a step beside a 4 x 595M gradient stack."""
+    ``remat`` as in `lfm2_8b_a1b_ep4` (4 workers' 16,384 tokens a step
+    beside a 4 x 595M gradient stack): each block is recomputed in the
+    backward pass but for `lfm2.KEPT`, here 2.21 GB a slot reckoned from
+    the shapes: the attention projections and the kernels' residuals of
+    four layers, and of four expert layers the sorts, the dispatched rows
+    (32,768 x 2,304) and the three grouped matmuls' results."""
     return Mellum(num_classes=num_classes, dtype=dtype, remat=True)
 
 

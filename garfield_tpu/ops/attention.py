@@ -52,12 +52,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import coordinate
 
-__all__ = ["causal_gqa", "blockwise", "misfit", "blocks_run"]
+__all__ = ["KEPT", "causal_gqa", "blockwise", "misfit", "blocks_run", "say"]
 
 LANES = 128
 # Rows of q a head and keys a block: the largest of these that divides t.
@@ -69,11 +70,19 @@ VMEM_LIMIT_BYTES = 64 << 20
 # exp(MASKED - max) is 0 and MASKED - MASKED is no NaN, which -inf's is.
 MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 
+# What the backward kernels read of the forward pass, under the names a
+# caller's checkpoint policy keeps them by (`jax.checkpoint_policies.
+# save_only_these_names`): q, k and v as the kernels take them, the output,
+# the rows' log-sum-exp. Without them a recomputed block runs the forward
+# kernel twice.
+KEPT = ("attention_q", "attention_k", "attention_v", "attention_out",
+        "attention_lse")
+
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _said = set()
 
 
-def _say(line):
+def say(line):
     """Each distinct line once a process: a step's trace passes here once a
     slot and again where a block is recomputed."""
     if line not in _said:
@@ -359,8 +368,14 @@ def _core(q, k, v, block, window, interpret):
 
 
 def _core_fwd(q, k, v, block, window, interpret):
-    o, lse = _forward(q, k, v, block, window, interpret)
-    return o, (q, k, v, o, lse)
+    # Named here, in the rule: a policy sees the residuals where they are
+    # made, and a name on `_core`'s result would mark another value. The
+    # output goes on under its name too, or what reads it downstream would
+    # ask for the kernel again.
+    _, _, _, o, _ = kept = tuple(
+        checkpoint_name(x, name) for x, name in zip(
+            (q, k, v, *_forward(q, k, v, block, window, interpret)), KEPT))
+    return o, kept
 
 
 def _core_bwd(block, window, interpret, kept, do):
@@ -390,11 +405,13 @@ def blockwise(q, k, v, *, window=None, block=None, interpret=False):
 
 
 def causal_gqa(q, k, v, fallback, *, window=None, block=None,
-               interpret=False):
+               interpret=False, kept=None):
     """Causal grouped-query attention of q (n, t, heads, head) over k, v
     (n, t, kv_heads, head), a query seeing the ``window`` keys up to its own
     (None: all of them), by the kernels where they apply (module docstring),
-    else ``fallback(q, k, v, window)``; says which once."""
+    else ``fallback(q, k, v, window)``; says which once. Where the kernels
+    run, ``kept(KEPT, bytes)`` is told what they keep for the backward pass,
+    reckoned from the shapes."""
     n, t, heads, head = q.shape
     kv, window = k.shape[2], _band(window, t)
     fallback = functools.partial(fallback, window=window)
@@ -402,18 +419,21 @@ def causal_gqa(q, k, v, fallback, *, window=None, block=None,
     if why is None and not interpret and not coordinate.use_pallas():
         why = "no TPU lowering"
     if why is not None:
-        _say(f"[attention] einsum: {why}")
+        say(f"[attention] einsum: {why}")
         return fallback(q, k, v)
     bq, bk = _blocks(t, block)
     run, above, below = blocks_run(t, bq, bk, window)
     of = run + above + below
-    _say(f"[attention] blockwise: (n, heads, kv_heads, t, head) = "
-         f"({n}, {heads}, {kv}, {t}, {head}) {jnp.dtype(q.dtype).name}, "
-         f"blocks ({bq}, {bk}), "
-         + (f"causal blocks skipped {above} of {of}" if window is None else
-            f"window {window}, blocks run {run} of {of} (skipped {above} "
-            f"above the diagonal, {below} below the band)")
-         + (", interpret mode" if interpret else ""))
+    say(f"[attention] blockwise: (n, heads, kv_heads, t, head) = "
+        f"({n}, {heads}, {kv}, {t}, {head}) {jnp.dtype(q.dtype).name}, "
+        f"blocks ({bq}, {bk}), "
+        + (f"causal blocks skipped {above} of {of}" if window is None else
+           f"window {window}, blocks run {run} of {of} (skipped {above} "
+           f"above the diagonal, {below} below the band)")
+        + (", interpret mode" if interpret else ""))
+    if kept is not None:
+        kept(KEPT, (2 * q.size + k.size + v.size) * q.dtype.itemsize
+             + 4 * n * heads * t)
     kernels = functools.partial(
         blockwise, window=window, block=block, interpret=interpret)
     if interpret:

@@ -655,9 +655,11 @@ def make_trainer(
         grads_local, (loss_local, ms_local) = core.per_slot_grads(
             grad_fn, params, ms, x_local, y_local, drop_keys,
             fused_fn=slot_fused_fn, force_unroll=force_unroll,
+            dtype=gar_dtype,
         )
         # Narrow the aggregation pipeline (see make_trainer docstring); the
-        # cast fuses into the backward's output writes. No-op when None.
+        # cast fuses into the backward's output writes. No-op when None,
+        # and where the unroll has cast slot by slot already.
         with core.phase("grads"):
             grads_local = core.cast_leaves(grads_local, gar_dtype)
 

@@ -426,7 +426,7 @@ def select_slot_path(module, loss_fn, slots, num_iter=None, log_tag=None,
 
 
 def per_slot_grads(grad_fn, params, ms, x, y, keys, fused_fn=None,
-                   force_unroll=False):
+                   force_unroll=False, dtype=None):
     """Per-slot gradients over a leading logical-slot axis, vmap-compatible.
 
     Returns exactly what ``jax.vmap(grad_fn, in_axes=(None, None, 0, 0, 0))``
@@ -438,9 +438,15 @@ def per_slot_grads(grad_fn, params, ms, x, y, keys, fused_fn=None,
          batch (fused forward + fused dx), and only the parameter-cotangent
          contractions are slot-resolved — the r5 hybrid (PERF.md).
       2. A Python unroll over the slots when their count is small: keeps
-         every subgraph 4-D and batch-minor; XLA schedules the independent
-         per-slot fwd+bwd graphs without relayouts (r2; 12.9 -> 9.1 ms for
-         the 8-worker ResNet-18 stack).
+         every subgraph 4-D and batch-minor, without relayouts (r2; 12.9 ->
+         9.1 ms for the 8-worker ResNet-18 stack). The slots run one after
+         another: a slot's batch passes one ``optimization_barrier`` with
+         the gradients of the slot before it, cast to ``dtype`` (the width
+         the caller keeps them at; None: as they are), so what a slot's
+         forward pass keeps for its backward pass is freed before the next
+         slot's is made. Left to itself XLA:TPU overlaps the independent
+         slots and holds three slots' kept activations at once (PR 33: 11.6
+         GB of temporaries against 7.5 in the 4-slot token step).
       3. vmap above UNROLL_MAX_SLOTS — compile time of the unroll grows
          linearly with slots; the 5-D relayout tax shrinks with n
          (~19% at n=64, PERF.md r4).
@@ -457,7 +463,13 @@ def per_slot_grads(grad_fn, params, ms, x, y, keys, fused_fn=None,
             return jax.vmap(grad_fn, in_axes=(None, None, 0, 0, 0))(
                 params, ms, x, y, keys
             )
-        outs = [grad_fn(params, ms, x[k], y[k], keys[k]) for k in range(n)]
+        outs, xk = [], x[0]
+        for k in range(n):
+            grads, aux = grad_fn(params, ms, xk, y[k], keys[k])
+            grads = cast_leaves(grads, dtype)
+            if k + 1 < n:
+                grads, xk = jax.lax.optimization_barrier((grads, x[k + 1]))
+            outs.append((grads, aux))
         return jax.tree.map(lambda *ls: jnp.stack(ls), *outs)
 
 

@@ -401,15 +401,18 @@ def test_the_model_scopes_stand_inside_the_gradient_phase(
         lfm2.scope("nonsense")
 
 
-def test_three_trainer_steps_equal_slot_by_slot_gradients(monkeypatch):
+@pytest.mark.parametrize("remat", [False, True])
+def test_three_trainer_steps_equal_slot_by_slot_gradients(monkeypatch, remat):
     """aggregathor (n = 4, f = 1, median under lie): the unroll over the 4
     slots against the same gradients taken one slot after another
-    (``lax.map``, what ``vmap`` computes), three steps; the step's metrics
-    carry the expert layers' counters. ``vmap`` itself cannot take this
-    family yet: jax 0.9.0 has no batching rule for a ragged dot whose group
-    sizes are batched, which is what more than ``UNROLL_MAX_SLOTS`` slots a
-    shard would ask for."""
-    module = _module(_model())
+    (``lax.map``, what ``vmap`` computes), three steps, without
+    recomputation and with the blocks recomputed but for `lfm2.KEPT`, as
+    the benchmark's preset runs; the step's metrics carry the expert
+    layers' counters. ``vmap`` itself cannot take this family yet: jax 0.9.0
+    has no batching rule for a ragged dot whose group sizes are batched,
+    which is what more than ``UNROLL_MAX_SLOTS`` slots a shard would ask
+    for."""
+    module = _module(_model()).clone(remat=remat)
     x, y = _worker_batches()
 
     def three_steps():
@@ -470,10 +473,11 @@ def _slot_gradients_of_one_step(monkeypatch, module):
 
 def test_the_two_attention_paths_give_the_trainer_the_same_gradients(
         monkeypatch, capsys):
-    """The 4-slot unroll with every block recomputed (``remat=True``, as
-    the benchmark's preset runs): forward, recomputed forward and backward
-    kernels against the einsum path, worker by worker and leaf by leaf;
-    each trace says its path once, not once a slot."""
+    """The 4-slot unroll with the blocks recomputed but for `lfm2.KEPT`
+    (``remat=True``, as the benchmark's preset runs): forward and backward
+    kernels, the forward's output and log-sum-exp kept, against the einsum
+    path, worker by worker and leaf by leaf; each trace says its path once,
+    not once a slot."""
     module = lfm2.lfm2_moe_tiny(num_classes=VOCAB, remat=True)
     attention._said.clear()
     want = _slot_gradients_of_one_step(monkeypatch, module)

@@ -324,7 +324,8 @@ def test_the_scopes_stand_inside_the_gradient_phase(
 def test_three_trainer_steps_equal_slot_by_slot_gradients(monkeypatch):
     """aggregathor (n = 4, f = 1, median under lie): the unroll over the 4
     slots against the same gradients taken one slot after another, three
-    steps, every block recomputed as the benchmark's preset runs; the
+    steps, the blocks recomputed but for `lfm2.KEPT` as the benchmark's
+    preset runs; the
     step's metrics carry the expert layers' counters, one entry a layer."""
     from garfield_tpu.parallel import core
 

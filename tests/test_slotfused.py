@@ -38,6 +38,8 @@ selects slot-minor (8 slots x 3: 8 fills the 32-/64-bit sublane tile, 3
 misses it) against the same unroll reference at the same tolerances.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -526,6 +528,33 @@ def test_per_slot_grads_routes_fused():
         ),
         g_f, g_u,
     )
+
+
+@pytest.mark.parametrize("dtype", [None, jnp.bfloat16])
+def test_per_slot_grads_unroll_runs_the_slots_one_after_another(dtype):
+    """The unroll chains its slots: slot k + 1's batch and slot k's
+    gradients, cast to ``dtype`` first, pass one ``optimization_barrier``,
+    so XLA cannot start a slot's forward pass before the last slot's
+    backward pass has ended (what a forward pass keeps is then held once,
+    not once a slot: PERF.md, PR 33). The values are the plain unroll's."""
+    module = select_model("cifarnet", "cifar10")
+    _, grad_fn, params, ms, x, y, keys = _setup(module, (32, 32, 3))
+    chained = functools.partial(core.per_slot_grads, grad_fn, dtype=dtype)
+    barriers = [
+        eqn for eqn in jax.make_jaxpr(chained)(params, ms, x, y, keys).eqns
+        if eqn.primitive.name == "optimization_barrier"]
+    leaves = len(jax.tree.leaves(params))
+    assert len(barriers) == N - 1
+    for eqn in barriers:
+        assert [v.aval.dtype for v in eqn.invars] == (
+            [dtype or jnp.float32] * leaves + [x.dtype])
+        assert eqn.invars[-1].aval.shape == x.shape[1:]
+    g_c, (loss_c, _) = chained(params, ms, x, y, keys)
+    g_u, loss_u, _ = _unroll(grad_fn, params, ms, x, y, keys)
+    np.testing.assert_array_equal(loss_c, loss_u)
+    jax.tree.map(
+        lambda a, b: np.testing.assert_array_equal(
+            a, b if dtype is None else b.astype(dtype)), g_c, g_u)
 
 
 @pytest.mark.parametrize("n,b,order,why", [
