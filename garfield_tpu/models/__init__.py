@@ -1,15 +1,16 @@
 """Model zoo: the CNNs of pytorch_impl/libs/garfieldpp/models/ and the
 torchvision entries in garfieldpp/tools.py:59-105, the small transformers
-(`transformer.py`) and the language models: LFM2-MoE (`lfm2.py`) and Mellum
-(`mellum.py`, built of `lfm2.py`'s attention and expert layer).
+(`transformer.py`) and the language models: LFM2-MoE (`lfm2.py`), Mellum
+(`mellum.py`) and Laguna (`laguna.py`), the last two built of `lfm2.py`'s
+attention and expert layer.
 
 All models are flax.linen modules with the signature
 ``model(x, train: bool)`` and constructor kwargs ``num_classes`` and
 ``dtype`` (compute dtype; pass jnp.bfloat16 to route convs/matmuls to the
 MXU in bf16 while parameters stay float32). ``x`` is an NHWC image batch
 for the CNNs and ``vit_tiny``, an int token batch (batch, time) for
-``gpt_tiny`` (one label per sequence) and for the ``lfm2_*`` and ``mellum2_*``
-presets, whose logits are (batch, time, vocabulary) and whose loss is ``next-token``
+``gpt_tiny`` (one label per sequence) and for the ``lfm2_*``, ``mellum2_*``
+and ``laguna_*`` presets, whose logits are (batch, time, vocabulary) and whose loss is ``next-token``
 (``utils.selectors.select_loss``); for them ``num_classes`` is the slice of
 the vocabulary held.
 
@@ -26,6 +27,7 @@ from .densenet import DenseNet121, DenseNet161, DenseNet169, DenseNet201, densen
 from .dpn import DPN26, DPN92
 from .efficientnet import EfficientNetB0
 from .googlenet import GoogLeNet
+from .laguna import laguna_tiny, laguna_xs2_ep16
 from .lenet import LeNet
 from .lfm2 import lfm2_8b_a1b_ep4, lfm2_moe_tiny
 from .mellum import mellum2_12b_a2p5b_ep4, mellum2_tiny
@@ -109,6 +111,12 @@ models = {
     # 28 layers, experts 0-15 of 64, published widths).
     "mellum2_12b_a2p5b_ep4": mellum2_12b_a2p5b_ep4,
     "mellum2_tiny": mellum2_tiny,
+    # Laguna language models (models/laguna.py): a head count and a rotary
+    # table by kind of layer, a per-head gate, a shared expert.
+    # laguna_xs2_ep16 is one chip's share of Laguna-XS.2 under 16-way expert
+    # parallelism (5 of 40 layers, experts 0-15 of 256, published widths).
+    "laguna_xs2_ep16": laguna_xs2_ep16,
+    "laguna_tiny": laguna_tiny,
 }
 
 # tools.py:89 (+ the synthetic sequence datasets of data/__init__.py:

@@ -66,11 +66,15 @@ __all__ = [
 # ``attention`` stands around a whole attention module (this family's
 # blocks); a block that splits the module instead (`Attention.core_scope`)
 # has ``attention_proj`` around the projections, q/k norm and rotary
-# embedding and ``window_attention`` or ``full_attention`` around the core.
+# embedding and ``window_attention`` or ``full_attention`` around the core;
+# ``attention_gate`` stands around a per-head output gate (`Attention.gate`:
+# its projection, sigmoid and multiply), ``shared_expert`` around the expert
+# every token passes beside the routed ones (`ExpertLayer.shared_width`).
 SCOPES = (
     "embed", "conv_mixer", "attention", "dense_mlp", "moe_router",
     "moe_dispatch", "moe_experts", "moe_combine", "head_loss",
     "attention_proj", "window_attention", "full_attention",
+    "attention_gate", "shared_expert",
 )
 # parallel.core's two collections for a model's counters, by name.
 COUNTER_SUMS, COUNTER_MAXES = "counters_sum", "counters_max"
@@ -85,7 +89,8 @@ WEIGHT_EPS = 1e-6
 # dense MLP's two inner products; of an expert layer the router's logits
 # and choice, the two sorts and the groups' sizes, the rows as dispatched,
 # the two inner grouped matmuls and the third as the combine reads it,
-# rows back in place; and `ops.attention`'s own. Norms, the rotary
+# rows back in place; a per-head gate's projection and a shared expert's
+# two inner products; and `ops.attention`'s own. Norms, the rotary
 # embedding, gates, masks, casts and the combine's sum are computed again.
 # One set for every family made of this file's modules: a family differs
 # in which of the sites it contains.
@@ -93,7 +98,7 @@ KEPT = (
     "conv_in", "conv_out", "attention_q_proj", "attention_k_proj",
     "attention_o_proj", "mlp_w1", "mlp_w3", "moe_logits", "moe_chosen",
     "moe_order", "moe_inverse", "moe_sizes", "moe_rows", "moe_w1", "moe_w3",
-    "moe_out",
+    "moe_out", "attention_gate_proj", "shared_w1", "shared_w3",
 ) + attention.KEPT
 
 _normal = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
@@ -181,7 +186,9 @@ def rope_table(head_dim, theta, yarn=None):
     ramp between the dimensions that turn ``beta_fast`` and ``beta_slow``
     times over the original positions, cos and sin times
     ``attention_factor``. The table does not depend on the sequence's
-    length."""
+    length. A table over part of a head (``partial_rotary_factor``) is the
+    table of that many dimensions: ``head_dim`` is then the rotated width,
+    and YaRN's ramp is reckoned over it, as Hugging Face does."""
     extrap = 1.0 / theta ** (
         jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     if yarn is None:
@@ -204,8 +211,13 @@ def rope_table(head_dim, theta, yarn=None):
 def rotary(x, inv, scale=1.0):
     """Rotary embedding of ``x`` (batch, time, heads, head_dim) in float32,
     half-rotation convention, by the table ``inv`` (inverse frequencies,
-    head_dim / 2) with cos and sin times ``scale`` (`rope_table`)."""
-    t, d = x.shape[1], x.shape[-1]
+    head_dim / 2) with cos and sin times ``scale`` (`rope_table`). A shorter
+    table rotates the first 2 x len(inv) dimensions of each head among
+    themselves and passes the others unchanged."""
+    t, d = x.shape[1], 2 * inv.shape[0]
+    if d < x.shape[-1]:
+        return jnp.concatenate([
+            rotary(x[..., :d], inv, scale), x[..., d:].astype(jnp.float32)], -1)
     angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None]
     cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[None, :, None]
     sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[None, :, None]
@@ -275,7 +287,13 @@ class Attention(nn.Module):
     it). ``yarn``: the rotary table's YaRN parameters (None: the plain
     table of ``rope_theta``). ``core_scope``: a name of ``SCOPES`` to stand
     around the core alone, with ``attention_proj`` around the rest of the
-    module (None: no scope of its own; the caller's stands around it all)."""
+    module (None: no scope of its own; the caller's stands around it all).
+    ``rotary_dim``: the leading dimensions of each head that the rotary
+    embedding turns, the table being that many wide (None: the whole head).
+    ``gate``: a per-head output gate, g = sigmoid(u W_g) with W_g (hidden,
+    heads), one number a head and position, times the core's output in
+    front of ``o_proj`` (arXiv:2505.06708's per-head form); its scope is
+    ``attention_gate``, beside the others and inside none of them."""
 
     heads: int
     kv_heads: int
@@ -286,6 +304,8 @@ class Attention(nn.Module):
     window: Optional[int] = None
     yarn: Optional[Yarn] = None
     core_scope: Optional[str] = None
+    rotary_dim: Optional[int] = None
+    gate: bool = False
 
     @nn.compact
     def __call__(self, u):
@@ -294,7 +314,8 @@ class Attention(nn.Module):
         rest = "attention_proj" if self.core_scope else None
         # Made where it is used, once for q and once for k, as `rotary` made
         # it while it took a theta: the step keeps its operations' order.
-        table = functools.partial(rope_table, hd, self.rope_theta, self.yarn)
+        table = functools.partial(
+            rope_table, self.rotary_dim or hd, self.rope_theta, self.yarn)
         with scope(rest):
             q = keep(_dense(heads * hd, self.dtype, "q_proj")(u),
                      "attention_q_proj").reshape(n, t, heads, hd)
@@ -308,6 +329,12 @@ class Attention(nn.Module):
         with scope(self.core_scope):
             mixed = attention.causal_gqa(
                 q, k, v, einsum_attention, window=self.window, kept=_count)
+        if self.gate:
+            with scope("attention_gate"):
+                g = jax.nn.sigmoid(keep(
+                    _dense(heads, self.dtype, "g_proj")(u),
+                    "attention_gate_proj").astype(jnp.float32))
+                mixed = mixed * g.astype(self.dtype)[..., None]
         with scope(rest):
             return keep(
                 _dense(hidden, self.dtype, "o_proj")(
@@ -315,16 +342,19 @@ class Attention(nn.Module):
 
 
 class SwiGLU(nn.Module):
-    """W_2 (silu(u W_1) * (u W_3)). No bias."""
+    """W_2 (silu(u W_1) * (u W_3)). No bias. ``kept``: the names of ``KEPT``
+    its two inner products go by."""
 
     width: int
     dtype: Any = jnp.float32
+    kept: Sequence[str] = ("mlp_w1", "mlp_w3")
 
     @nn.compact
     def __call__(self, u):
-        gate = nn.silu(keep(_dense(self.width, self.dtype, "w1")(u), "mlp_w1"))
+        w1, w3 = self.kept
+        gate = nn.silu(keep(_dense(self.width, self.dtype, "w1")(u), w1))
         return _dense(u.shape[-1], self.dtype, "w2")(
-            gate * keep(_dense(self.width, self.dtype, "w3")(u), "mlp_w3"))
+            gate * keep(_dense(self.width, self.dtype, "w3")(u), w3))
 
 
 @jax.custom_vjp
@@ -353,10 +383,13 @@ class ExpertLayer(nn.Module):
     ``num_experts``. ``sigmoid``: the top ``experts_per_token`` of score +
     bias are chosen (the bias is a constant leaf under ``stop_gradient``: it
     enters the selection only) and a token's weights are its chosen scores
-    over their sum + ``WEIGHT_EPS``. ``softmax``: the top of the softmax are
-    chosen and renormalised over their sum; no bias leaf, no epsilon. The
-    sums run over all the chosen, held here or not; weights times
-    ``scaling``."""
+    over their sum + ``WEIGHT_EPS``; without ``bias`` the top of the scores
+    themselves, over their sum: no leaf, no epsilon. ``softmax``: the top of
+    the softmax are chosen and renormalised over their sum; no bias leaf, no
+    epsilon. The sums run over all the chosen, held here or not; weights
+    times ``scaling``. ``shared_width``: beside the routed experts every
+    token passes one shared `SwiGLU` of that width, ungated and unscaled,
+    whole on every chip (0: none); its scope is ``shared_expert``."""
 
     num_experts: int
     experts_held: Sequence[int]
@@ -365,6 +398,8 @@ class ExpertLayer(nn.Module):
     scaling: float = 1.0
     dtype: Any = jnp.float32
     score: str = "sigmoid"
+    bias: bool = True
+    shared_width: int = 0
 
     @nn.compact
     def __call__(self, u):
@@ -378,12 +413,15 @@ class ExpertLayer(nn.Module):
             logits = keep(jnp.matmul(
                 x.astype(jnp.float32), kernel,
                 precision=jax.lax.Precision.HIGHEST), "moe_logits")
+            biased = self.score == "sigmoid" and self.bias
             if self.score == "sigmoid":
-                bias = self.param(
-                    "expert_bias", nn.initializers.zeros, (self.num_experts,))
-                scores = jax.nn.sigmoid(logits)
-                _, chosen = jax.lax.top_k(
-                    scores + jax.lax.stop_gradient(bias), k)
+                scores = ranked = jax.nn.sigmoid(logits)
+                if biased:
+                    bias = self.param(
+                        "expert_bias", nn.initializers.zeros,
+                        (self.num_experts,))
+                    ranked = scores + jax.lax.stop_gradient(bias)
+                _, chosen = jax.lax.top_k(ranked, k)
             elif self.score == "softmax":
                 scores = jax.nn.softmax(logits, axis=-1)
                 _, chosen = jax.lax.top_k(scores, k)
@@ -392,7 +430,7 @@ class ExpertLayer(nn.Module):
             chosen = keep(chosen, "moe_chosen")
             picked = jnp.take_along_axis(scores, chosen, axis=-1)
             total = jnp.sum(picked, -1, keepdims=True)
-            if self.score == "sigmoid":
+            if biased:
                 total = total + WEIGHT_EPS
             weights = picked / total * self.scaling
         with scope("moe_dispatch"):
@@ -435,6 +473,11 @@ class ExpertLayer(nn.Module):
             out = jnp.sum(
                 out.reshape(tokens, k, hidden)
                 * weights[..., None].astype(self.dtype), axis=1)
+        if self.shared_width:
+            with scope("shared_expert"):
+                out = out + SwiGLU(
+                    self.shared_width, self.dtype, ("shared_w1", "shared_w3"),
+                    name="shared")(x)
         for collection, name, value in (
                 (COUNTER_SUMS, "moe_pairs_held", jnp.sum(sizes)),
                 (COUNTER_SUMS, "moe_pairs_total", tokens * k),

@@ -21,9 +21,10 @@ import pytest
 from garfield_tpu.models.lfm2 import einsum_attention
 from garfield_tpu.ops import attention
 
-# (heads, kv_heads, t, block, head): groups of 1 and 4; t of one block and of
-# several (skipped, crossed and full blocks all occur at 4 x 4), square and
-# oblong blocks; head sizes 64 and 16.
+# (heads, kv_heads, t, block, head): groups of 1 and 4, and of 6 and 8 (the
+# two groups of one model whose head count is its layer's); t of one block
+# and of several (skipped, crossed and full blocks all occur at 4 x 4),
+# square and oblong blocks; head sizes 64 and 16.
 SHAPES = {
     "group1-one-block-head16": (2, 2, 16, 16, 16),
     "group4-one-block-head64": (8, 2, 16, 16, 64),
@@ -32,6 +33,8 @@ SHAPES = {
     "group4-several-head64": (4, 1, 32, 8, 64),
     "group4-wide-key-blocks": (4, 1, 32, (8, 16), 16),
     "group4-tall-q-blocks": (4, 1, 32, (16, 8), 16),
+    "group6-several-head16": (12, 2, 32, 8, 16),
+    "group8-several-head16": (8, 1, 32, 8, 16),
 }
 CASES = [(name, dtype) for name in SHAPES
          for dtype in (jnp.float32, jnp.bfloat16)]
@@ -160,6 +163,9 @@ def test_the_line_names_the_blocks_and_is_said_once(capsys):
     # The sliding layers of the window cell: 21 of 64 run, causal 36.
     assert attention.blocks_run(4096, 512, 512, 1024) == (21, 28, 15)
     assert attention.blocks_run(4096, 512, 512) == (36, 28, 0)
+    # A window equal to the block: the diagonal's blocks and the ones under
+    # them, 15 of 64, none of them whole.
+    assert attention.blocks_run(4096, 512, 512, 512) == (15, 28, 21)
 
 
 # Windows over 32 positions in blocks of 8 (or 8 x 16, 16 x 8): one that
@@ -172,6 +178,11 @@ BAND_CASES = [(name, window, dtype)
                            "group4-tall-q-blocks", "group1-several-head64")
               for window in WINDOWS for dtype in (jnp.float32, jnp.bfloat16)
               if dtype == jnp.float32 or window in (5, 16)]
+# A window equal to the block at groups of 6 and 8: every block that runs is
+# crossed by an edge, the diagonal's by both.
+BAND_CASES += [(name, 8, dtype)
+               for name in ("group6-several-head16", "group8-several-head16")
+               for dtype in (jnp.float32, jnp.bfloat16)]
 
 
 @pytest.mark.parametrize(
@@ -279,6 +290,10 @@ def test_the_line_names_the_window_and_the_blocks_skipped_on_each_side(
     ((1, 4096, 32, 128), 4, jnp.bfloat16, None),
     ((2, 2048, 32, 64), 8, jnp.float32, None),
     ((1, 384, 4, 128), 4, jnp.bfloat16, None),
+    # Groups of 8 and 6 at heads of 128: dq of a KV head 32 MiB (the limit
+    # itself) and 24 MiB.
+    ((1, 4096, 64, 128), 8, jnp.bfloat16, None),
+    ((1, 4096, 48, 128), 8, jnp.bfloat16, None),
     ((2, 2048, 32, 64), 5, jnp.bfloat16, "do not share"),
     ((2, 2000, 32, 64), 8, jnp.bfloat16, "not a multiple of the block"),
     ((2, 2048, 32, 64), 8, jnp.float16, "dtype float16"),

@@ -6,8 +6,8 @@ driver's command collects `tests/` alone. This file loads those test files
 by path and re-exports their test functions and fixtures, so they run under
 `pytest tests/` as cases of this file; nothing under `benchmark/` knows.
 Left by hand (minutes each): `test_reference.py`, `test_run_cpu.py`,
-`test_lfm2_cell.py`; of `test_mellum2_cell.py` the runs through
-``run.run_cell``, which it marks slow.
+`test_lfm2_cell.py`; of `test_mellum2_cell.py` and `test_laguna_cell.py` the
+runs through ``run.run_cell``, which they mark slow.
 """
 
 import importlib.util
@@ -17,7 +17,7 @@ from _pytest.fixtures import FixtureFunctionDefinition
 
 BENCH_TESTS = pathlib.Path(__file__).resolve().parents[1] / "benchmark" / "tests"
 FILES = ("test_arithmetic", "test_reduce_trace", "test_seeded_arrays",
-         "test_phase_map", "test_mellum2_cell")
+         "test_phase_map", "test_mellum2_cell", "test_laguna_cell")
 
 
 for _name in FILES:

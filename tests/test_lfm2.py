@@ -353,13 +353,13 @@ def _after_operator(made, i, h, model):
     return ref.rms_norm(mid, made[f"{p}/ffn_norm/scale"], eps)
 
 
-def _trainer(module, **kwargs):
+def _trainer(module, num_workers=4, f=1, **kwargs):
     return aggregathor.make_trainer(
         module, selectors.select_loss("next-token"),
         selectors.select_optimizer("sgd", lr=0.05, momentum=0.9,
                                    weight_decay=5e-4),
-        "median", num_workers=4, f=1, attack="lie",
-        # One device holds the 4 slots, as the chip does: the unroll.
+        "median", num_workers=num_workers, f=f, attack="lie",
+        # One device holds the slots, as the chip does: the unroll.
         mesh=make_mesh({"workers": 1}, devices=jax.devices()[:1]), **kwargs)
 
 
@@ -386,11 +386,14 @@ def test_the_model_scopes_stand_inside_the_gradient_phase(
     state = init_fn(jax.random.PRNGKey(0), x[0])
     text = step_fn.lower(state, x, y).compile().as_text()
     # This family's blocks put ``attention`` around the whole module; the
-    # three names that split a module are the other family's
-    # (tests/test_mellum.py).
-    split = {"attention_proj", "window_attention", "full_attention"}
+    # three names that split a module are the other families'
+    # (tests/test_mellum.py), the gate and the shared expert the third's
+    # (tests/test_laguna.py).
+    split = {"attention_proj", "window_attention", "full_attention",
+             "attention_gate", "shared_expert"}
     for name in lfm2.SCOPES:
-        assert (f"model.{name}" in text) == (name not in split), name
+        assert (f"model.{name}/" in text or f"model.{name}\"" in text) == (
+            name not in split), name
     # Wherever an instruction names a model scope, phase.grads stands
     # outside it.
     for op_name in re.findall(r'op_name="([^"]*model\.[^"]*)"', text):

@@ -95,12 +95,16 @@ def test_remap_kernel_on_device():
     (16, 3, (3, 3, 256, 256)),  # tap-major, chunks of one (16, 128) tile a row
     (16, 3, (2048, 100)),     # a last axis of 100 taken whole
     (5, 1, (20000,)),         # flat, its last block ragged mid-tile
+    # n = 5, f = 2 (PR 34): an odd n whose fake row is a number and stands
+    # twice, over a stack of 16 expert kernels read worker-major in place.
+    (5, 2, (16, 2048, 512)),
 ])
 def test_operands_in_place_on_device(n, f, tail):
     """The block shapes of PR 29 through real Mosaic lowering, in bf16: the
     stack read where it lies, upcast in VMEM, the fake row (all NaN, as
-    lie at f = 1 makes it) a second operand — against ``jnp.sort`` over
-    the written-out rows, bit for bit."""
+    lie at f = 1 makes it; finite at f > 1, where it stands f times) a
+    second operand — against ``jnp.sort`` over the written-out rows, bit
+    for bit."""
     rng = np.random.default_rng(n)
     g = jnp.asarray(rng.standard_normal((n,) + tail), jnp.bfloat16)
     e = (jnp.full(tail, jnp.nan, jnp.bfloat16) if f == 1 else
@@ -124,6 +128,10 @@ def test_operands_in_place_on_device(n, f, tail):
     (1, 32, 4, 4096, 128, jnp.bfloat16, 1024),  # a sliding layer of mellum2n4
     (1, 32, 4, 4096, 128, jnp.bfloat16, None),  # its full layer: dq at 32 MiB
     (1, 8, 2, 1024, 64, jnp.bfloat16, 200),     # a window that cuts blocks
+    # The two groups of one model (PR 34), two KV heads of its eight: a
+    # window equal to the block at a group of 8, causal at a group of 6.
+    (1, 16, 2, 4096, 128, jnp.bfloat16, 512),
+    (1, 12, 2, 4096, 128, jnp.bfloat16, None),
 ])
 def test_attention_kernels_on_device(n, heads, kv, t, head, dtype, window):
     """The blockwise attention kernels (ops/attention.py) through real
