@@ -18,12 +18,17 @@ runs without its exchange: nothing here stands in for the absent chips.
 
 **Dropless.** Every (token, expert) pair whose expert is held is computed,
 whatever the imbalance: the pairs are sorted by expert, those of absent
-experts last, and three ``jax.lax.ragged_dot`` run over the sorted rows with
-the held experts' counts as group sizes. XLA:TPU compiles a ragged dot to a
-grouped matmul that visits only the row tiles its groups cover, so the
-matmul work follows the pairs held (a quarter of 4 x tokens when 8 of 32
-experts are held), not the worst case; the sort, the two permutations and
-the buffers they fill are the static worst case of 4 x tokens rows.
+experts last, and three grouped matmuls run over the sorted rows with the
+held experts' counts as group sizes: `ops.grouped.grouped_matmul`, Pallas
+kernels of the repo's own (forward, the rows' gradient against the weights
+read transposed, the weights' gradient group by group) whose tiles follow
+the shapes, where the step is lowered for the TPU and the shapes fill the
+tiles; ``jax.lax.ragged_dot`` — the one
+copy, handed in as the fallback — elsewhere; it says which once. Either
+visits only the row tiles its groups cover, so the matmul work follows the
+pairs held (a quarter of 4 x tokens when 8 of 32 experts are held), not the
+worst case; the sort, the two permutations and the buffers they fill are
+the static worst case of 4 x tokens rows.
 
 **Precision.** Parameters are float32; ``dtype`` is the compute dtype of
 the matmuls and the residual stream. Norm statistics, the rotary embedding,
@@ -33,9 +38,13 @@ logits' consumer (the loss) are float32 whatever ``dtype`` is.
 **Scopes** (``jax.named_scope``, always on, metadata only; they nest inside
 ``phase.grads`` of `parallel.core`): the vocabulary is ``SCOPES`` below, one
 ``model.<name>`` each. **Counters**: an expert layer writes ``moe_pairs_held``
-(pairs computed here) and ``moe_pairs_total`` (experts_per_token x tokens)
-into the collection ``counters_sum`` and ``moe_max_expert_load`` (the
-fullest held expert's pairs) into ``counters_max`` — `parallel.core`'s
+(pairs computed here), ``moe_pairs_total`` (experts_per_token x tokens) and
+``moe_rows_visited`` (the rows of the row tiles the forward grouped-matmul
+kernel visits, a tile that holds rows of several experts once for each:
+over ``moe_pairs_held`` it is the padding the tiles pay; 0 where
+``ragged_dot`` runs) into the collection ``counters_sum`` and
+``moe_max_expert_load`` (the fullest held expert's pairs) into
+``counters_max`` — `parallel.core`'s
 convention for any model's counters, by name as BatchNorm writes
 ``batch_stats`` — so they ride in ``model_state``, and a trainer that calls
 ``core.step_counters`` (`aggregathor.make_trainer` does) has them in the
@@ -53,7 +62,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops import attention
+from ..ops import attention, grouped
 
 __all__ = [
     "SCOPES", "COUNTER_SUMS", "COUNTER_MAXES", "KEPT", "scope", "keep",
@@ -456,9 +465,11 @@ class ExpertLayer(nn.Module):
             w3 = self.param("w3", _stack_init, (held, hidden, self.width))
             w2 = self.param("w2", _stack_init, (held, self.width, hidden))
             dot = functools.partial(
-                jax.lax.ragged_dot, group_sizes=sizes,
-                preferred_element_type=self.dtype)
-            gate = nn.silu(keep(dot(rows, w1.astype(self.dtype)), "moe_w1"))
+                grouped.grouped_matmul, sizes=sizes,
+                fallback=functools.partial(
+                    jax.lax.ragged_dot, preferred_element_type=self.dtype))
+            w1 = w1.astype(self.dtype)
+            gate = nn.silu(keep(dot(rows, w1), "moe_w1"))
             out = dot(
                 gate * keep(dot(rows, w3.astype(self.dtype)), "moe_w3"),
                 w2.astype(self.dtype))
@@ -478,9 +489,13 @@ class ExpertLayer(nn.Module):
                 out = out + SwiGLU(
                     self.shared_width, self.dtype, ("shared_w1", "shared_w3"),
                     name="shared")(x)
+        # The three grouped matmuls take one row tile: the first stands for
+        # them.
+        visited = grouped.rows_visited(rows, w1, sizes)
         for collection, name, value in (
                 (COUNTER_SUMS, "moe_pairs_held", jnp.sum(sizes)),
                 (COUNTER_SUMS, "moe_pairs_total", tokens * k),
+                (COUNTER_SUMS, "moe_rows_visited", visited),
                 (COUNTER_MAXES, "moe_max_expert_load", jnp.max(sizes))):
             if self.is_mutable_collection(collection):
                 self.variable(

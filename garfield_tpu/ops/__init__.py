@@ -14,6 +14,10 @@ the selection step.
 `ops.attention` (imported by the model that uses it, `models/lfm2.py`, not
 from here) is the same idea for a token model's attention: blockwise causal
 grouped-query kernels that never write the (t, t) scores to HBM.
+`ops.grouped` (imported there too) is the held experts' grouped matmul:
+rows sorted by expert against each expert's weights, forward and both
+gradients, with tiles chosen from the shapes so that an expert's weights are
+fetched once; ``jax.lax.ragged_dot`` is its spec and its fallback.
 
 Public entry points dispatch by backend: the Pallas path on TPU (or when
 forced via ``interpret=True`` for CPU testing), a pure-jnp fallback elsewhere

@@ -134,7 +134,10 @@ def kernel_path(monkeypatch):
         attention.causal_gqa, block=4, interpret=True))
 
 
-@pytest.mark.parametrize("path", ["einsum", "kernels"])
+expert_kernels = shared.expert_kernels  # a fixture
+
+
+@pytest.mark.parametrize("path", ["einsum", "kernels", "expert_kernels"])
 @pytest.mark.parametrize("layers", list(LAYERS))
 def test_logits_loss_and_gradient_equal_the_reference(layers, path, request,
                                                       capsys):
@@ -142,8 +145,9 @@ def test_logits_loss_and_gradient_equal_the_reference(layers, path, request,
     between embedding and head) and the tiny model — both kinds of layer,
     two head counts, a dense layer, a shared expert —, by the einsum path
     and by the kernels: logits, loss and ``jax.grad`` of every leaf."""
-    if path == "kernels":
-        request.getfixturevalue("kernel_path")
+    if path != "einsum":
+        request.getfixturevalue(
+            "kernel_path" if path == "kernels" else path)
     model = _model(**LAYERS[layers])
     module, variables, made = _setup(model)
     x, y = shared._tokens()
@@ -177,6 +181,23 @@ def test_logits_loss_and_gradient_equal_the_reference(layers, path, request,
             assert {re.search(r"= \(\d+, (\d+), 2,", s).group(1)
                     for s in said} == {"4", "6"}
             assert any("window 4, blocks run 7 of 16" in s for s in said)
+    if path == "expert_kernels":
+        # The expert layer's grouped matmuls in the kernels (a dense layer
+        # alone has none); the shared expert is a plain `SwiGLU`.
+        said = shared._expert_lines(capsys)
+        assert len(said) == (0 if layers == "dense_full" else 2), said
+        assert all(line.startswith("[experts] grouped: ") and line.endswith(
+            "weights, interpret mode") for line in said)
+
+
+def test_the_ragged_dot_path_says_why_it_was_taken_once(capsys):
+    attention._said.clear()
+    module, variables, _ = _setup(_model())
+    _apply(module, variables, shared._tokens()[0])
+    said = shared._expert_lines(capsys)
+    assert len(said) == 1 and said[0].startswith(
+        "[experts] ragged_dot: m = ") and said[0].endswith(
+            "is not a multiple of the row tile 128")
 
 
 @pytest.mark.parametrize("kind", list(laguna.KINDS))

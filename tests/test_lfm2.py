@@ -17,10 +17,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import test_grouped
 from garfield_tpu import models
 from garfield_tpu.aggregators import dataplane
 from garfield_tpu.models import lfm2
-from garfield_tpu.ops import attention
+from garfield_tpu.ops import attention, grouped
 from garfield_tpu.parallel import aggregathor, core, make_mesh
 from garfield_tpu.utils import selectors
 
@@ -321,7 +322,59 @@ def test_the_einsum_path_says_why_it_was_taken(capsys):
         "[attention] einsum: t = 16 is not a multiple of the block 128"]
 
 
-def test_the_counters_equal_the_references_count():
+def _expert_lines(capsys):
+    return [line for line in capsys.readouterr().err.splitlines()
+            if "[experts]" in line]
+
+
+@pytest.fixture
+def expert_kernels(monkeypatch):
+    """`ExpertLayer` takes the grouped-matmul kernels, in interpret mode
+    with row tiles of 8 and the contraction and the columns in tiles of 16
+    (64 and 48 wide, either way round): the test steers the choice that
+    platform and shape make on the chip; the model has no argument for
+    it."""
+    attention._said.clear()
+    for name in ("grouped_matmul", "rows_visited"):
+        monkeypatch.setattr(grouped, name, functools.partial(
+            getattr(grouped, name), tiles=(8, 16, 16), interpret=True))
+
+
+@pytest.mark.parametrize("block", ["expert", "whole"])
+def test_the_expert_kernels_equal_the_reference_too(
+        block, expert_kernels, capsys):
+    """Logits and gradients with the three grouped matmuls, their rows'
+    and their weights' gradients in the kernels, under the ragged-dot
+    path's own tolerances."""
+    test_logits_and_gradient_equal_the_reference(block)
+    assert _expert_lines(capsys) == [
+        f"[experts] grouped: (m, k, n) = (96, {k}, {n}) g=2 float32, tiles "
+        "(8, 16, 16), gradients (8, 16, 16) rows, (8, 16, 16) weights, "
+        "interpret mode" for k, n in ((64, 48), (48, 64))]
+
+
+def test_the_expert_kernels_drop_no_token_either(expert_kernels):
+    test_no_token_is_dropped_when_every_token_chooses_one_held_expert()
+
+
+def test_the_expert_kernels_give_zero_when_every_choice_is_absent(
+        expert_kernels):
+    """No group has a row: the kernels visit nothing, and what they leave
+    in the result (NaN, in interpret mode) is masked forward and backward."""
+    test_the_expert_output_is_zero_when_every_choice_is_absent()
+
+
+def test_the_ragged_dot_path_says_why_it_was_taken(capsys):
+    attention._said.clear()
+    test_the_expert_output_is_zero_when_every_choice_is_absent()
+    assert _expert_lines(capsys) == [
+        "[experts] ragged_dot: m = 64 is not a multiple of the row tile 128"]
+
+
+@pytest.mark.parametrize("path", ["ragged_dot", "kernels"])
+def test_the_counters_equal_the_references_count(path, request):
+    if path == "kernels":
+        request.getfixturevalue("expert_kernels")
     model = _model()
     module, variables, made = _setup(model)
     x, _ = _tokens(seed=4)
@@ -340,6 +393,11 @@ def test_the_counters_equal_the_references_count():
                 assert float(sums["moe_pairs_held"]) == float(held.sum())
                 assert float(maxes["moe_max_expert_load"]) == float(held.max())
                 assert float(sums["moe_pairs_total"]) == x.size * 2
+                # The rows of the row tiles of 8 that hold a pair of a held
+                # expert, once for each expert in them.
+                assert float(sums["moe_rows_visited"]) == (
+                    8 * test_grouped.visits(held, x.size * 2, 8)
+                    if path == "kernels" else 0)
             h = ref.block(made, i, h, model, lambda t: t)
 
 

@@ -114,15 +114,20 @@ SAID = {
 }
 
 
-@pytest.mark.parametrize("path", ["einsum", "kernels"])
+expert_kernels = shared.expert_kernels  # a fixture
+
+
+@pytest.mark.parametrize("path", ["einsum", "kernels", "expert_kernels"])
 @pytest.mark.parametrize("layers", list(LAYERS))
 def test_logits_and_gradient_equal_the_reference(layers, path, request,
                                                  capsys):
     """Each kind of layer alone (one between embedding and head) and the
-    tiny model, by the einsum path and by the kernels: logits and
-    ``jax.grad`` of the next-token loss."""
-    if path == "kernels":
-        request.getfixturevalue("kernel_path")
+    tiny model, by the einsum path, by the attention kernels and by the
+    expert layer's grouped-matmul kernels: logits and ``jax.grad`` of the
+    next-token loss."""
+    if path != "einsum":
+        request.getfixturevalue(
+            "kernel_path" if path == "kernels" else path)
     model = _model(LAYERS[layers])
     module, variables, made = _setup(model)
     x, y = shared._tokens()
@@ -151,6 +156,22 @@ def test_logits_and_gradient_equal_the_reference(layers, path, request,
         assert _attention_lines(capsys) == (
             SAID["sliding"] + SAID["full"] if layers == "whole"
             else SAID[layers])
+    if path == "expert_kernels":
+        said = shared._expert_lines(capsys)
+        assert len(said) == 2 and all(
+            line.startswith("[experts] grouped: ") and line.endswith(
+                "weights, interpret mode") for line in said)
+
+
+def test_the_ragged_dot_path_says_why_it_was_taken_once(capsys):
+    """Two expert layers, three grouped matmuls each, one line."""
+    attention._said.clear()
+    module, variables, _ = _setup(_model())
+    _apply(module, variables, shared._tokens()[0])
+    said = shared._expert_lines(capsys)
+    assert len(said) == 1 and re.fullmatch(
+        r"\[experts\] ragged_dot: m = \d+ is not a multiple of the row tile "
+        "128", said[0])
 
 
 @pytest.mark.parametrize("path", ["einsum", "kernels"])

@@ -169,3 +169,59 @@ def test_attention_kernels_on_device(n, heads, kv, t, head, dtype, window):
         np.testing.assert_allclose(
             f32(a), f32(b), atol=2.0 ** -6 * float(np.abs(f32(b)).max()),
             err_msg=leaf)
+
+
+@pytest.mark.parametrize("m,k,n,g,held", [
+    # One slot's expert matmuls of lfm2n4, mellum2n4 and lagunaxs2n5, the
+    # inner pair's shape and the third's.
+    (16384, 2048, 1792, 8, 1 / 4), (16384, 1792, 2048, 8, 1 / 4),
+    (32768, 2304, 896, 16, 1 / 4), (32768, 896, 2304, 16, 1 / 4),
+    (32768, 2048, 512, 16, 1 / 16), (32768, 512, 2048, 16, 1 / 16),
+])
+def test_grouped_matmul_on_device(m, k, n, g, held):
+    """The grouped-matmul kernels (ops/grouped.py) through real Mosaic
+    lowering, taken by ``grouped_matmul`` itself with the rule's tiles,
+    against ``lax.ragged_dot``: the result and both gradients under the
+    layer's mask, uneven groups with an empty one and an absent tail."""
+    from garfield_tpu.ops import grouped
+
+    dtype = jnp.bfloat16
+    rng = np.random.default_rng(m + k)
+    share = rng.dirichlet(np.full(g, 2.0)) * held * m
+    sizes = np.floor(share).astype(np.int32)
+    sizes[g // 2] = 0
+    keys = jax.random.split(jax.random.PRNGKey(n), 3)
+    rows = jax.random.normal(keys[0], (m, k), dtype)
+    weights = jax.random.normal(keys[1], (g, k, n), dtype) * k ** -0.5
+    weight = jax.random.normal(keys[2], (m, n), jnp.float32)
+    here = (jnp.arange(m) < sizes.sum())[:, None]
+    sizes = jnp.asarray(sizes)
+    assert grouped.misfit((m, k, n), dtype, dtype) is None
+    assert grouped.plan(k, n, dtype)[0] == (128, k, n)  # what the cells run
+
+    def ragged(rows, weights, sizes):
+        return jax.lax.ragged_dot(
+            rows, weights, sizes, preferred_element_type=dtype)
+
+    def both(dot):
+        # Every array an argument: a constant of this size in the program
+        # costs minutes of compilation.
+        def loss(rows, weights, sizes, here, weight):
+            out = jnp.where(
+                here, dot(jnp.where(here, rows, 0), weights, sizes), 0)
+            return jnp.sum(out.astype(jnp.float32) * weight), out
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(
+                rows, weights, sizes, here, weight)
+
+    (_, got), got_grads = both(
+        lambda r, w, s: grouped.grouped_matmul(r, w, s, ragged))
+    (_, want), want_grads = both(ragged)
+    f32 = lambda x: np.asarray(x, np.float32)
+    np.testing.assert_allclose(
+        f32(got), f32(want), rtol=2.0 ** -7, atol=2.0 ** -7)
+    for leaf, a, b in zip(("rows", "weights"), got_grads, want_grads):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_allclose(
+            f32(a), f32(b), atol=2.0 ** -6 * float(np.abs(f32(b)).max()),
+            err_msg=leaf)
