@@ -37,18 +37,29 @@ logits' consumer (the loss) are float32 whatever ``dtype`` is.
 
 **Scopes** (``jax.named_scope``, always on, metadata only; they nest inside
 ``phase.grads`` of `parallel.core`): the vocabulary is ``SCOPES`` below, one
-``model.<name>`` each. **Counters**: an expert layer writes ``moe_pairs_held``
-(pairs computed here), ``moe_pairs_total`` (experts_per_token x tokens) and
-``moe_rows_visited`` (the rows of the row tiles the forward grouped-matmul
-kernel visits, a tile that holds rows of several experts once for each:
-over ``moe_pairs_held`` it is the padding the tiles pay; 0 where
-``ragged_dot`` runs) into the collection ``counters_sum`` and
+``model.<name>`` each, read by the benchmark's `harness/model_map.py`
+(``moe_route_ms`` and the other ``model blocks`` metrics). An expert layer's
+routing is split further by ``ROUTE_STEPS``, one ``route.<name>`` each,
+nested inside ``model.moe_dispatch`` and ``model.moe_combine`` so that every
+``model.*`` label reads what it read without them; `harness/route_map.py`
+reads them (``moe_sort_ms``: ``order`` and ``inverse``; ``moe_permute_ms``:
+``gather_rows`` and ``return_rows``, forward and backward — `_permute`'s
+backward gather carries its forward's scope). **Counters**: an expert layer
+writes ``moe_pairs_held`` (pairs computed here), ``moe_rows_routed`` (the
+rows of the dispatch permutation, taken where it is made: the rows the two
+sorts order and the two permutations move, experts_per_token x tokens
+today) and ``moe_rows_visited`` (the rows of the row tiles the forward
+grouped-matmul kernel visits, a tile that holds rows of several experts
+once for each: over ``moe_pairs_held`` it is the padding the tiles pay; 0
+where ``ragged_dot`` runs) into the collection ``counters_sum`` and
 ``moe_max_expert_load`` (the fullest held expert's pairs) into
 ``counters_max`` — `parallel.core`'s
 convention for any model's counters, by name as BatchNorm writes
 ``batch_stats`` — so they ride in ``model_state``, and a trainer that calls
 ``core.step_counters`` (`aggregathor.make_trainer` does) has them in the
-step's ``metrics`` under those names, one entry per expert layer.
+step's ``metrics`` under those names, one entry per expert layer. The
+benchmark's `harness/route_map.py` sums them over layers for
+``moe_route_fill`` (held ÷ routed) and ``moe_tile_fill`` (held ÷ visited).
 """
 
 import contextlib
@@ -65,7 +76,8 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops import attention, grouped
 
 __all__ = [
-    "SCOPES", "COUNTER_SUMS", "COUNTER_MAXES", "KEPT", "scope", "keep",
+    "SCOPES", "ROUTE_STEPS", "COUNTER_SUMS", "COUNTER_MAXES", "KEPT",
+    "scope", "route", "keep",
     "recomputed", "RMSNorm", "ShortConv",
     "Yarn", "rope_table", "rotary", "einsum_attention", "Attention", "SwiGLU",
     "ExpertLayer", "Sizes", "Block", "Lfm2Moe", "lfm2_8b_a1b_ep4",
@@ -84,6 +96,17 @@ SCOPES = (
     "moe_dispatch", "moe_experts", "moe_combine", "head_loss",
     "attention_proj", "window_attention", "full_attention",
     "attention_gate", "shared_expert",
+)
+# The steps of an expert layer's routing, one ``route.<name>`` scope each,
+# nested inside ``model.moe_dispatch`` (the first five) and
+# ``model.moe_combine`` (the last two): the slot lookup, the sort of the
+# slots, the sort that inverts it, the groups' sizes and the mask of held
+# rows, the rows gathered into the sorted order, the rows gathered back and
+# the weighted sum over a token's choices. A namespace of their own, since
+# a map labels an instruction by its outermost scope of a namespace.
+ROUTE_STEPS = (
+    "slots", "order", "inverse", "sizes", "gather_rows", "return_rows",
+    "weigh",
 )
 # parallel.core's two collections for a model's counters, by name.
 COUNTER_SUMS, COUNTER_MAXES = "counters_sum", "counters_max"
@@ -121,6 +144,14 @@ def scope(name):
     if name not in SCOPES:
         raise ValueError(f"unknown model scope {name!r}; have {SCOPES}")
     return jax.named_scope("model." + name)
+
+
+def route(name):
+    """``jax.named_scope("route.<name>")`` for a name of ``ROUTE_STEPS``."""
+    if name not in ROUTE_STEPS:
+        raise ValueError(
+            f"unknown routing step {name!r}; have {ROUTE_STEPS}")
+    return jax.named_scope("route." + name)
 
 
 # names -> bytes kept under them, of the model whose blocks are being traced.
@@ -445,21 +476,26 @@ class ExpertLayer(nn.Module):
         with scope("moe_dispatch"):
             # The slot of each chosen expert among those held, ``held`` for
             # an absent one; pairs sorted by slot, absent pairs last.
-            slot_of = jnp.full((self.num_experts,), held, jnp.int32).at[
-                jnp.asarray(self.experts_held)].set(jnp.arange(held))
-            slots = slot_of[chosen].reshape(-1)
+            with route("slots"):
+                slot_of = jnp.full((self.num_experts,), held, jnp.int32).at[
+                    jnp.asarray(self.experts_held)].set(jnp.arange(held))
+                slots = slot_of[chosen].reshape(-1)
             # Named before they enter `_permute`, whose rule hands them on
             # as they come.
-            order = keep(jnp.argsort(slots, stable=True), "moe_order")
-            inverse = keep(jnp.argsort(order), "moe_inverse")
-            sizes = keep(jnp.sum(
-                slots[:, None] == jnp.arange(held)[None], axis=0,
-                dtype=jnp.int32), "moe_sizes")
-            here = (jnp.arange(tokens * k) < jnp.sum(sizes))[:, None]
-            rows = jnp.broadcast_to(
-                x[:, None], (tokens, k, hidden)).reshape(-1, hidden)
-            rows = keep(
-                jnp.where(here, _permute(rows, order, inverse), 0), "moe_rows")
+            with route("order"):
+                order = keep(jnp.argsort(slots, stable=True), "moe_order")
+            with route("inverse"):
+                inverse = keep(jnp.argsort(order), "moe_inverse")
+            with route("sizes"):
+                sizes = keep(jnp.sum(
+                    slots[:, None] == jnp.arange(held)[None], axis=0,
+                    dtype=jnp.int32), "moe_sizes")
+                here = (jnp.arange(tokens * k) < jnp.sum(sizes))[:, None]
+            with route("gather_rows"):
+                rows = jnp.broadcast_to(
+                    x[:, None], (tokens, k, hidden)).reshape(-1, hidden)
+                rows = keep(jnp.where(
+                    here, _permute(rows, order, inverse), 0), "moe_rows")
         with scope("moe_experts"):
             w1 = self.param("w1", _stack_init, (held, hidden, self.width))
             w3 = self.param("w3", _stack_init, (held, hidden, self.width))
@@ -479,11 +515,13 @@ class ExpertLayer(nn.Module):
             # their cotangent above) and not multiplied by a zero weight.
             # Kept in place: the weights' gradient reads these rows, and
             # neither the grouped matmul nor the permutation runs again.
-            out = keep(
-                _permute(jnp.where(here, out, 0), inverse, order), "moe_out")
-            out = jnp.sum(
-                out.reshape(tokens, k, hidden)
-                * weights[..., None].astype(self.dtype), axis=1)
+            with route("return_rows"):
+                out = keep(_permute(
+                    jnp.where(here, out, 0), inverse, order), "moe_out")
+            with route("weigh"):
+                out = jnp.sum(
+                    out.reshape(tokens, k, hidden)
+                    * weights[..., None].astype(self.dtype), axis=1)
         if self.shared_width:
             with scope("shared_expert"):
                 out = out + SwiGLU(
@@ -494,7 +532,7 @@ class ExpertLayer(nn.Module):
         visited = grouped.rows_visited(rows, w1, sizes)
         for collection, name, value in (
                 (COUNTER_SUMS, "moe_pairs_held", jnp.sum(sizes)),
-                (COUNTER_SUMS, "moe_pairs_total", tokens * k),
+                (COUNTER_SUMS, "moe_rows_routed", rows.shape[0]),
                 (COUNTER_SUMS, "moe_rows_visited", visited),
                 (COUNTER_MAXES, "moe_max_expert_load", jnp.max(sizes))):
             if self.is_mutable_collection(collection):

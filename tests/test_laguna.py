@@ -376,7 +376,7 @@ def test_the_counters_equal_the_references_count():
                 assert float(sums["moe_pairs_held"]) == float(held.sum())
                 assert float(maxes["moe_max_expert_load"]) == float(
                     held.max())
-                assert float(sums["moe_pairs_total"]) == x.size * 2
+                assert float(sums["moe_rows_routed"]) == x.size * 2
             else:
                 assert p not in state[COUNTERS[0]]
             h = ref.block(made, i, h, model, identity)
@@ -457,11 +457,11 @@ def test_a_five_worker_step_under_two_liars_has_a_finite_fake_row():
         pulled += int(jnp.sum(want[p] != honest[p]))
     assert pulled > 0
     assert math.isfinite(float(metrics["loss"]))
-    assert metrics["moe_pairs_total"].tolist() == [5 * 2 * SEQ * 2] * 4
+    assert metrics["moe_rows_routed"].tolist() == [5 * 2 * SEQ * 2] * 4
     assert metrics["moe_pairs_held"].shape == (4,)
     assert metrics["moe_max_expert_load"].shape == (4,)
     assert bool(jnp.all(metrics["moe_pairs_held"]
-                        <= metrics["moe_pairs_total"]))
+                        <= metrics["moe_rows_routed"]))
 
 
 def test_the_presets_and_their_token_dataset_are_registered():

@@ -246,7 +246,7 @@ def test_no_token_is_dropped_when_every_token_chooses_one_held_expert():
     sums, maxes = (state[c] for c in COUNTERS)
     assert float(sums["moe_pairs_held"]) == 3 * SEQ
     assert float(maxes["moe_max_expert_load"]) == 3 * SEQ
-    assert float(sums["moe_pairs_total"]) == 2 * 3 * SEQ
+    assert float(sums["moe_rows_routed"]) == 2 * 3 * SEQ
     scores = jax.nn.sigmoid(u @ params["router_kernel"])
     weight = scores[..., 0] / (scores[..., 0] + scores[..., 7] + 1e-6)
     mlp = (jax.nn.silu(u @ params["w1"][0]) * (u @ params["w3"][0])
@@ -392,7 +392,7 @@ def test_the_counters_equal_the_references_count(path, request):
                     state[c][f"layer_{i}"]["moe"] for c in COUNTERS)
                 assert float(sums["moe_pairs_held"]) == float(held.sum())
                 assert float(maxes["moe_max_expert_load"]) == float(held.max())
-                assert float(sums["moe_pairs_total"]) == x.size * 2
+                assert float(sums["moe_rows_routed"]) == x.size * 2
                 # The rows of the row tiles of 8 that hold a pair of a held
                 # expert, once for each expert in them.
                 assert float(sums["moe_rows_visited"]) == (
@@ -462,6 +462,91 @@ def test_the_model_scopes_stand_inside_the_gradient_phase(
         lfm2.scope("nonsense")
 
 
+@pytest.fixture(scope="module", params=[False, True], ids=["whole", "remat"])
+def routed_text(request):
+    """The tiny preset's compiled step with its metadata, with and without
+    the benchmark presets' recomputed blocks; and the rows a slot routes."""
+    name = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, name)
+    jax.config.update(name, True)
+    try:
+        module = models.select_model(
+            "lfm2_moe_tiny", "synthtokens").clone(remat=request.param)
+        init_fn, step_fn, _ = _trainer(module)
+        x, y = _worker_batches()
+        state = init_fn(jax.random.PRNGKey(0), x[0])
+        text = step_fn.lower(state, x, y).compile().as_text()
+    finally:
+        jax.config.update(name, before)
+    return text, x[0].size * module.experts_per_token, module.hidden
+
+
+def _op_names(text, opcode, *marks):
+    """The ``op_name`` of every ``opcode`` instruction whose line holds each
+    of ``marks`` and whose op_name names a model scope."""
+    return [
+        re.search(r'op_name="([^"]*)"', line).group(1)
+        for line in text.splitlines()
+        if f" {opcode}(" in line and "model." in line
+        and all(mark in line for mark in marks)]
+
+
+def test_every_routing_step_appears_in_the_step_text(routed_text):
+    text, _, _ = routed_text
+    for name in lfm2.ROUTE_STEPS:
+        assert f"route.{name}/" in text, name
+
+
+def test_every_sort_of_the_model_is_a_routing_sort(routed_text):
+    """The expert layers' two sorts a layer and slot, each under its step;
+    the rule's sorts stand outside the model."""
+    text, _, _ = routed_text
+    sorts = _op_names(text, "sort")
+    assert sorts
+    assert all("route.order/" in s or "route.inverse/" in s for s in sorts)
+    for step in ("order", "inverse"):
+        assert any(f"route.{step}/" in s for s in sorts), step
+
+
+def test_the_row_gathers_hold_a_permutation_step_forward_and_backward(
+        routed_text):
+    """Every gather of whole rows (tokens x k of them) in the model is one
+    of the two permutations, and `_permute`'s backward gather carries its
+    forward's step."""
+    text, rows, hidden = routed_text
+    gathers = _op_names(
+        text, "gather", f"= f32[{rows},", f"slice_sizes={{1,{hidden}}}")
+    assert gathers
+    for op_name in gathers:
+        assert "route.gather_rows/" in op_name or (
+            "route.return_rows/" in op_name), op_name
+    for step in ("gather_rows", "return_rows"):
+        forward = [g for g in gathers
+                   if f"route.{step}/" in g and "transpose(" not in g]
+        backward = [g for g in gathers
+                    if f"route.{step}/" in g and "transpose(" in g]
+        assert forward and backward, step
+
+
+def test_every_routing_step_stands_inside_dispatch_or_combine(routed_text):
+    """So that every ``model.*`` label reads what it read without them."""
+    text, _, _ = routed_text
+    parts = [part for op_name in re.findall(r'op_name="([^"]*)"', text)
+             for part in op_name.split(";") if "route." in part]
+    assert parts
+    for part in parts:
+        scopes = re.findall(r"model\.(\w+)", part)
+        assert scopes and scopes[-1] in ("moe_dispatch", "moe_combine"), part
+        assert part.rindex("model.") < part.index("route."), part
+
+
+def test_an_unknown_routing_step_raises():
+    with pytest.raises(ValueError, match="nonsense"):
+        lfm2.route("nonsense")
+    with pytest.raises(ValueError):
+        lfm2.route(None)
+
+
 @pytest.mark.parametrize("remat", [False, True])
 def test_three_trainer_steps_equal_slot_by_slot_gradients(monkeypatch, remat):
     """aggregathor (n = 4, f = 1, median under lie): the unroll over the 4
@@ -499,9 +584,9 @@ def test_three_trainer_steps_equal_slot_by_slot_gradients(monkeypatch, remat):
         np.testing.assert_allclose(a, b, atol=1e-5)
     for m, mm in zip(metrics, metrics_m):
         np.testing.assert_allclose(m["loss"], mm["loss"], rtol=1e-5)
-        assert m["moe_pairs_total"].tolist() == [4 * 2 * SEQ * 2] * 2
+        assert m["moe_rows_routed"].tolist() == [4 * 2 * SEQ * 2] * 2
         assert m["moe_pairs_held"].shape == (2,)
-        assert bool(jnp.all(m["moe_pairs_held"] <= m["moe_pairs_total"]))
+        assert bool(jnp.all(m["moe_pairs_held"] <= m["moe_rows_routed"]))
         assert bool(jnp.all(m["moe_max_expert_load"] <= 2 * SEQ * 2))
         np.testing.assert_array_equal(
             m["moe_pairs_held"], mm["moe_pairs_held"])

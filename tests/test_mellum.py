@@ -315,7 +315,7 @@ def test_the_counters_equal_the_references_count():
             sums, maxes = (state[c][p]["moe"] for c in COUNTERS)
             assert float(sums["moe_pairs_held"]) == float(held.sum())
             assert float(maxes["moe_max_expert_load"]) == float(held.max())
-            assert float(sums["moe_pairs_total"]) == x.size * 2
+            assert float(sums["moe_rows_routed"]) == x.size * 2
             h = ref.block(made, i, h, model, lambda t: t)
 
 
@@ -377,8 +377,8 @@ def test_three_trainer_steps_equal_slot_by_slot_gradients(monkeypatch):
         np.testing.assert_allclose(a, b, atol=1e-5)
     for m, mm in zip(metrics, metrics_m):
         np.testing.assert_allclose(m["loss"], mm["loss"], rtol=1e-5)
-        assert m["moe_pairs_total"].tolist() == [4 * 2 * SEQ * 2] * 2
-        assert bool(jnp.all(m["moe_pairs_held"] <= m["moe_pairs_total"]))
+        assert m["moe_rows_routed"].tolist() == [4 * 2 * SEQ * 2] * 2
+        assert bool(jnp.all(m["moe_pairs_held"] <= m["moe_rows_routed"]))
         assert bool(jnp.all(m["moe_max_expert_load"] <= 2 * SEQ * 2))
         np.testing.assert_array_equal(
             m["moe_pairs_held"], mm["moe_pairs_held"])
