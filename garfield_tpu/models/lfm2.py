@@ -27,8 +27,14 @@ tiles; ``jax.lax.ragged_dot`` — the one
 copy, handed in as the fallback — elsewhere; it says which once. Either
 visits only the row tiles its groups cover, so the matmul work follows the
 pairs held (a quarter of 4 x tokens when 8 of 32 experts are held), not the
-worst case; the sort, the two permutations and the buffers they fill are
-the static worst case of 4 x tokens rows.
+worst case. The rows move the same way: `ops.route` gathers the held rows
+into the sorted order and sums them back per token with their weights,
+Pallas kernels whose grids end at the last held pair, where the step is
+lowered for the TPU and the shapes fit; elsewhere a token's row is
+broadcast to its choices and all tokens x k rows are permuted there and
+back (`_rows_permuted`, `_back_permuted`: the one copy, their spec). The
+sorts and the buffers they fill are the static worst case of k x tokens
+rows.
 
 **Precision.** Parameters are float32; ``dtype`` is the compute dtype of
 the matmuls and the residual stream. Norm statistics, the rotary embedding,
@@ -43,12 +49,13 @@ routing is split further by ``ROUTE_STEPS``, one ``route.<name>`` each,
 nested inside ``model.moe_dispatch`` and ``model.moe_combine`` so that every
 ``model.*`` label reads what it read without them; `harness/route_map.py`
 reads them (``moe_sort_ms``: ``order`` and ``inverse``; ``moe_permute_ms``:
-``gather_rows`` and ``return_rows``, forward and backward — `_permute`'s
-backward gather carries its forward's scope). **Counters**: an expert layer
-writes ``moe_pairs_held`` (pairs computed here), ``moe_rows_routed`` (the
-rows of the dispatch permutation, taken where it is made: the rows the two
-sorts order and the two permutations move, experts_per_token x tokens
-today) and ``moe_rows_visited`` (the rows of the row tiles the forward
+``gather_rows`` and ``return_rows``, forward and backward — a
+``custom_vjp``'s backward, `_permute`'s gather or `ops.route`'s kernels,
+carries its forward's scope). **Counters**: an expert layer writes
+``moe_pairs_held`` (pairs computed here), ``moe_rows_routed`` (the rows the
+dispatch moves, taken where it is made: the held pairs where `ops.route`'s
+kernels run, experts_per_token x tokens where the permutation does) and
+``moe_rows_visited`` (the rows of the row tiles the forward
 grouped-matmul kernel visits, a tile that holds rows of several experts
 once for each: over ``moe_pairs_held`` it is the padding the tiles pay; 0
 where ``ragged_dot`` runs) into the collection ``counters_sum`` and
@@ -74,6 +81,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import attention, grouped
+from ..ops import route as route_ops
 
 __all__ = [
     "SCOPES", "ROUTE_STEPS", "COUNTER_SUMS", "COUNTER_MAXES", "KEPT",
@@ -100,10 +108,12 @@ SCOPES = (
 # The steps of an expert layer's routing, one ``route.<name>`` scope each,
 # nested inside ``model.moe_dispatch`` (the first five) and
 # ``model.moe_combine`` (the last two): the slot lookup, the sort of the
-# slots, the sort that inverts it, the groups' sizes and the mask of held
-# rows, the rows gathered into the sorted order, the rows gathered back and
-# the weighted sum over a token's choices. A namespace of their own, since
-# a map labels an instruction by its outermost scope of a namespace.
+# slots, the sort that inverts it, the groups' sizes and their sum, the rows
+# gathered into the sorted order, the rows gathered back (with the kernels,
+# summed by token with their weights) and the weighted sum over a token's
+# choices (with the kernels, what is left of it: the weights' cast). A
+# namespace of their own, since a map labels an instruction by its
+# outermost scope of a namespace.
 ROUTE_STEPS = (
     "slots", "order", "inverse", "sizes", "gather_rows", "return_rows",
     "weigh",
@@ -120,10 +130,11 @@ WEIGHT_EPS = 1e-6
 # backward pass as the kernels' ``attention_v``, so it has no name); the
 # dense MLP's two inner products; of an expert layer the router's logits
 # and choice, the two sorts and the groups' sizes, the rows as dispatched,
-# the two inner grouped matmuls and the third as the combine reads it,
-# rows back in place; a per-head gate's projection and a shared expert's
+# the three grouped matmuls' results in sorted order, the third as the
+# combine reads it; a per-head gate's projection and a shared expert's
 # two inner products; and `ops.attention`'s own. Norms, the rotary
-# embedding, gates, masks, casts and the combine's sum are computed again.
+# embedding, gates, masks, casts and, where the permutation runs, its rows
+# gathered back are computed again.
 # One set for every family made of this file's modules: a family differs
 # in which of the sites it contains.
 KEPT = (
@@ -164,11 +175,34 @@ def _count(names, nbytes):
 
 def keep(x, name):
     """``x`` under ``name`` of ``KEPT``: the identity, and the mark by which
-    `recomputed`'s policy keeps ``x`` for the backward pass."""
+    `recomputed`'s policy keeps ``x`` for the backward pass. A float is kept
+    as its bits (`_kept_bits`)."""
     if name not in KEPT:
         raise ValueError(f"unknown name {name!r}; a block keeps {KEPT}")
     _count((name,), x.size * x.dtype.itemsize)
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        return _kept_bits(x, name)
     return checkpoint_name(x, name)
+
+
+def _as_bits(x, name):
+    bits = jnp.dtype(f"uint{8 * x.dtype.itemsize}")
+    return jax.lax.bitcast_convert_type(checkpoint_name(
+        jax.lax.bitcast_convert_type(x, bits), name), x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _kept_bits(x, name):
+    """``x``, named as its bits: the policy puts a ``reduce_precision`` on
+    every float it keeps, an identity that cannot fuse into a Pallas
+    kernel's result and so copies it whole (the dispatched rows and the
+    experts' output, two (tokens x k, hidden) arrays a layer and slot); an
+    integer is kept as it is. The cotangent passes unchanged."""
+    return _as_bits(x, name)
+
+
+_kept_bits.defvjp(lambda x, name: (_as_bits(x, name), None),
+                  lambda name, _, cotangent: (cotangent,))
 
 
 @contextlib.contextmanager
@@ -417,6 +451,52 @@ def _permute_bwd(res, ct):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
+def _rows_permuted(x, order, inverse, total):
+    """The dispatch by permutation: each token's row broadcast to its k
+    pairs, all tokens x k rows gathered into the sorted order, those past
+    ``total`` zeroed; with the rows it moved. The kernels' spec and their
+    fallback."""
+    tokens, hidden = x.shape
+    with route("gather_rows"):
+        rows = jnp.broadcast_to(
+            x[:, None], (tokens, order.shape[0] // tokens, hidden))
+        rows = _permute(rows.reshape(-1, hidden), order, inverse)
+        here = (jnp.arange(order.shape[0]) < total)[:, None]
+        return jnp.where(here, rows, 0), jnp.asarray(order.shape[0])
+
+
+def _rows_held(x, order, inverse, total):
+    """`_rows_permuted` by `ops.route.gather_held`: the held rows alone."""
+    del inverse
+    with route("gather_rows"):
+        k = order.shape[0] // x.shape[0]
+        return route_ops.gather_held(x, order // k, total), total
+
+
+def _back_permuted(out, weights, order, inverse, total, dtype):
+    """The combine by permutation: the sorted rows of ``out`` past
+    ``total`` zeroed (the grouped matmul leaves them unvisited), all tokens
+    x k rows gathered back in place, a token's k rows times their weights
+    summed. The kernels' spec and their fallback."""
+    tokens, k = weights.shape
+    with route("return_rows"):
+        here = (jnp.arange(order.shape[0]) < total)[:, None]
+        out = _permute(jnp.where(here, out, 0), inverse, order)
+    with route("weigh"):
+        return jnp.sum(
+            out.reshape(tokens, k, -1) * weights[..., None].astype(dtype),
+            axis=1)
+
+
+def _back_held(out, weights, order, inverse, total, dtype):
+    """`_back_permuted` by `ops.route.combine_held`, which reads the held
+    rows alone; what is left of the weighing is the weights' cast."""
+    with route("weigh"):
+        weights = weights.astype(dtype)
+    with route("return_rows"):
+        return route_ops.combine_held(out, weights, order, inverse, total)
+
+
 class ExpertLayer(nn.Module):
     """The part of a mixture-of-experts feed-forward that the experts held
     here give (module docstring). ``score`` is the router's law over all
@@ -480,8 +560,8 @@ class ExpertLayer(nn.Module):
                 slot_of = jnp.full((self.num_experts,), held, jnp.int32).at[
                     jnp.asarray(self.experts_held)].set(jnp.arange(held))
                 slots = slot_of[chosen].reshape(-1)
-            # Named before they enter `_permute`, whose rule hands them on
-            # as they come.
+            # Named before they enter the row movement, whose rules hand
+            # them on as they come.
             with route("order"):
                 order = keep(jnp.argsort(slots, stable=True), "moe_order")
             with route("inverse"):
@@ -490,12 +570,11 @@ class ExpertLayer(nn.Module):
                 sizes = keep(jnp.sum(
                     slots[:, None] == jnp.arange(held)[None], axis=0,
                     dtype=jnp.int32), "moe_sizes")
-                here = (jnp.arange(tokens * k) < jnp.sum(sizes))[:, None]
-            with route("gather_rows"):
-                rows = jnp.broadcast_to(
-                    x[:, None], (tokens, k, hidden)).reshape(-1, hidden)
-                rows = keep(jnp.where(
-                    here, _permute(rows, order, inverse), 0), "moe_rows")
+                pairs = jnp.sum(sizes)
+            why = route_ops.path((tokens, k, hidden), self.dtype)
+            rows, routed = route_ops.either(
+                _rows_held, _rows_permuted, x, order, inverse, pairs, why=why)
+            rows = keep(rows, "moe_rows")
         with scope("moe_experts"):
             w1 = self.param("w1", _stack_init, (held, hidden, self.width))
             w3 = self.param("w3", _stack_init, (held, hidden, self.width))
@@ -506,22 +585,16 @@ class ExpertLayer(nn.Module):
                     jax.lax.ragged_dot, preferred_element_type=self.dtype))
             w1 = w1.astype(self.dtype)
             gate = nn.silu(keep(dot(rows, w1), "moe_w1"))
-            out = dot(
+            # Kept in sorted order: the weights' gradient reads these rows,
+            # and the grouped matmul does not run again.
+            out = keep(dot(
                 gate * keep(dot(rows, w3.astype(self.dtype)), "moe_w3"),
-                w2.astype(self.dtype))
+                w2.astype(self.dtype)), "moe_out")
         with scope("moe_combine"):
-            # Rows past the last group belong to absent experts: the grouped
-            # matmul leaves them unvisited, so they are set to zero here (and
-            # their cotangent above) and not multiplied by a zero weight.
-            # Kept in place: the weights' gradient reads these rows, and
-            # neither the grouped matmul nor the permutation runs again.
-            with route("return_rows"):
-                out = keep(_permute(
-                    jnp.where(here, out, 0), inverse, order), "moe_out")
-            with route("weigh"):
-                out = jnp.sum(
-                    out.reshape(tokens, k, hidden)
-                    * weights[..., None].astype(self.dtype), axis=1)
+            out = route_ops.either(
+                functools.partial(_back_held, dtype=self.dtype),
+                functools.partial(_back_permuted, dtype=self.dtype),
+                out, weights, order, inverse, pairs, why=why)
         if self.shared_width:
             with scope("shared_expert"):
                 out = out + SwiGLU(
@@ -531,8 +604,8 @@ class ExpertLayer(nn.Module):
         # them.
         visited = grouped.rows_visited(rows, w1, sizes)
         for collection, name, value in (
-                (COUNTER_SUMS, "moe_pairs_held", jnp.sum(sizes)),
-                (COUNTER_SUMS, "moe_rows_routed", rows.shape[0]),
+                (COUNTER_SUMS, "moe_pairs_held", pairs),
+                (COUNTER_SUMS, "moe_rows_routed", routed),
                 (COUNTER_SUMS, "moe_rows_visited", visited),
                 (COUNTER_MAXES, "moe_max_expert_load", jnp.max(sizes))):
             if self.is_mutable_collection(collection):

@@ -18,6 +18,9 @@ grouped-query kernels that never write the (t, t) scores to HBM.
 rows sorted by expert against each expert's weights, forward and both
 gradients, with tiles chosen from the shapes so that an expert's weights are
 fetched once; ``jax.lax.ragged_dot`` is its spec and its fallback.
+`ops.route` (imported there too) moves that layer's held rows alone: a
+gather into the sorted order and a weighted sum back per token whose grids
+end at the last held pair, in place of permuting every (token, expert) row.
 
 Public entry points dispatch by backend: the Pallas path on TPU (or when
 forced via ``interpret=True`` for CPU testing), a pure-jnp fallback elsewhere

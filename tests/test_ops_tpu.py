@@ -225,3 +225,56 @@ def test_grouped_matmul_on_device(m, k, n, g, held):
         np.testing.assert_allclose(
             f32(a), f32(b), atol=2.0 ** -6 * float(np.abs(f32(b)).max()),
             err_msg=leaf)
+
+
+@pytest.mark.parametrize("tokens,k,hidden,held", [
+    # One slot's expert layer of lfm2n4, mellum2n4 and lagunaxs2n5.
+    (4096, 4, 2048, 1 / 4), (4096, 8, 2304, 1 / 4), (4096, 8, 2048, 1 / 16),
+])
+def test_held_rows_on_device(tokens, k, hidden, held):
+    """The held-row kernels (ops/route.py) through real Mosaic lowering,
+    taken by the expert layer's own branch functions, against the
+    permutation: the dispatched rows (equal), the combined value and the
+    cotangents of x, out and the weights, and zeros to the row tile."""
+    from garfield_tpu.models import lfm2
+    from garfield_tpu.ops import route
+
+    dtype, m = jnp.bfloat16, tokens * k
+    assert route.misfit((tokens, k, hidden), dtype) is None
+    rng = np.random.default_rng(m + hidden)
+    slots = np.where(rng.random(m) < held, rng.integers(0, 16, m), 16)
+    order = np.argsort(slots, kind="stable").astype(np.int32)
+    inverse = jnp.asarray(np.argsort(order), jnp.int32)
+    order, total = jnp.asarray(order), jnp.int32((slots < 16).sum())
+    keys = jax.random.split(jax.random.PRNGKey(tokens + hidden), 4)
+    x = jax.random.normal(keys[0], (tokens, hidden), dtype)
+    weights = jax.random.uniform(keys[1], (tokens, k)).astype(dtype)
+    d_rows = jax.random.normal(keys[2], (m, hidden), jnp.float32)
+    d_y = jax.random.normal(keys[3], (tokens, hidden), jnp.float32)
+    here = (jnp.arange(m) < total)[:, None]
+
+    def both(dispatch, back):
+        # Every array an argument: a constant of this size in the program
+        # costs minutes of compilation.
+        def run(x, weights, order, inverse, total, d_rows, d_y):
+            rows, pull = jax.vjp(
+                lambda x: dispatch(x, order, inverse, total)[0], x)
+            y, pull_back = jax.vjp(lambda o, w: back(
+                o, w, order, inverse, total, dtype), rows * 2, weights)
+            d_out, d_weights = pull_back(d_y.astype(dtype))
+            return (rows, pull(d_rows.astype(dtype))[0], y,
+                    jnp.where(here, d_out, 0), d_weights)
+        return jax.jit(run)(x, weights, order, inverse, total, d_rows, d_y)
+
+    got = both(lfm2._rows_held, lfm2._back_held)
+    want = both(lfm2._rows_permuted, lfm2._back_permuted)
+    f32 = lambda a: np.asarray(a, np.float32)
+    t, end = int(total), -(-int(total) // 128) * 128
+    np.testing.assert_array_equal(f32(got[0])[:t], f32(want[0])[:t])
+    assert not f32(got[0])[t:end].any()
+    for name, a, b in zip(("d_x", "y", "d_out", "d_weights"), got[1:],
+                          want[1:]):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_allclose(
+            f32(a), f32(b), atol=2.0 ** -7 * float(np.abs(f32(b)).max()),
+            err_msg=name)

@@ -233,8 +233,9 @@ def test_the_saved_residuals_are_the_kept_set(
 def test_a_name_outside_the_kept_set_is_refused_and_off_changes_nothing():
     """`keep` takes the names of ``KEPT`` alone, so the policy and the sites
     cannot drift apart; outside a recomputed block it is the identity: with
-    ``remat=False`` the gradient's jaxpr has no equation but the names'
-    more than the same model traced with `keep` taken out."""
+    ``remat=False`` the gradient's jaxpr has no equation more than the same
+    model traced with `keep` taken out but the names' and, a float being
+    named as its bits, two bitcasts a float name."""
     with pytest.raises(ValueError, match="unknown name 'moe_gate'"):
         lfm2.keep(jnp.zeros(3), "moe_gate")
     assert len(set(lfm2.KEPT)) == len(lfm2.KEPT) == 24
@@ -245,5 +246,8 @@ def test_a_name_outside_the_kept_set_is_refused_and_off_changes_nothing():
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(lfm2, "keep", lambda x, name: x)
         without = _count(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
-    assert with_names.pop("name") > 0 and "name" not in without
+    names = with_names.pop("name")
+    assert names > 0 and "name" not in without
+    bitcasts = with_names.pop("bitcast_convert_type")
+    assert 0 < bitcasts <= 2 * names and bitcasts % 2 == 0
     assert with_names == without
