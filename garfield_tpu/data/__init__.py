@@ -48,12 +48,12 @@ __all__ = [
 # tensorflow_impl/libs/dataset.py:41-87 accepts any tfds dataset) +
 # copytask (the synthetic token-sequence task the transformer family
 # trains on — no reference counterpart, synthetic BY CONSTRUCTION) +
-# synthtokens, synthtokens24k, synthtokens12k (seeded token sequences
-# labelled with their next token, for the language models of models/lfm2.py,
-# models/mellum.py and models/laguna.py: TOKEN_DATASETS).
+# synthtokens, synthtokens24k, synthtokens12k, synthtokens25k (seeded token
+# sequences labelled with their next token, for the language models of
+# models/lfm2.py, mellum.py, laguna.py and phi4flash.py: TOKEN_DATASETS).
 datasets_list = [
     "mnist", "cifar10", "cifar100", "pima", "copytask", "synthtokens",
-    "synthtokens24k", "synthtokens12k",
+    "synthtokens24k", "synthtokens12k", "synthtokens25k",
 ]
 
 # Reference normalization constants.
@@ -316,19 +316,21 @@ SYNTHTOKENS_VOCAB = 16384
 SYNTHTOKENS_SEQ = 2048
 # The token datasets: ``{name: (vocabulary slice, sequence length)}``, each
 # the slice and the length one language-model preset is benchmarked at
-# (``lfm2_8b_a1b_ep4``; ``mellum2_12b_a2p5b_ep4``; ``laguna_xs2_ep16``). The
-# slice is also ``models.num_classes_dict[name]``.
+# (``lfm2_8b_a1b_ep4``; ``mellum2_12b_a2p5b_ep4``; ``laguna_xs2_ep16``;
+# ``phi4_mini_flash_l15_19``). The slice is also
+# ``models.num_classes_dict[name]``.
 TOKEN_DATASETS = {
     "synthtokens": (SYNTHTOKENS_VOCAB, SYNTHTOKENS_SEQ),
     "synthtokens24k": (24576, 4096),
     "synthtokens12k": (12544, 4096),
+    "synthtokens25k": (25008, 4096),
 }
 
 
 def load_synthtokens(train_size=None, name="synthtokens"):
     """Seeded token sequences labelled with the next token at every
     position (the ``next-token`` loss; models/lfm2.py, models/mellum.py,
-    models/laguna.py).
+    models/laguna.py, models/phi4flash.py).
 
     x is (N, length) int32 below the vocabulary slice, both
     ``TOKEN_DATASETS[name]``'s; y is x moved one place on. The law is

@@ -2,15 +2,16 @@
 torchvision entries in garfieldpp/tools.py:59-105, the small transformers
 (`transformer.py`) and the language models: LFM2-MoE (`lfm2.py`), Mellum
 (`mellum.py`) and Laguna (`laguna.py`), the last two built of `lfm2.py`'s
-attention and expert layer.
+attention and expert layer, and SambaY (`phi4flash.py`: Mamba layers,
+differential attention, gated memory units).
 
 All models are flax.linen modules with the signature
 ``model(x, train: bool)`` and constructor kwargs ``num_classes`` and
 ``dtype`` (compute dtype; pass jnp.bfloat16 to route convs/matmuls to the
 MXU in bf16 while parameters stay float32). ``x`` is an NHWC image batch
 for the CNNs and ``vit_tiny``, an int token batch (batch, time) for
-``gpt_tiny`` (one label per sequence) and for the ``lfm2_*``, ``mellum2_*``
-and ``laguna_*`` presets, whose logits are (batch, time, vocabulary) and whose loss is ``next-token``
+``gpt_tiny`` (one label per sequence) and for the ``lfm2_*``, ``mellum2_*``,
+``laguna_*`` and ``phi4*`` presets, whose logits are (batch, time, vocabulary) and whose loss is ``next-token``
 (``utils.selectors.select_loss``); for them ``num_classes`` is the slice of
 the vocabulary held.
 
@@ -34,6 +35,7 @@ from .mellum import mellum2_12b_a2p5b_ep4, mellum2_tiny
 from .mobilenet import MobileNet
 from .mobilenetv2 import MobileNetV2
 from .nets import CNNet, Cifarnet, Net
+from .phi4flash import phi4_mini_flash_l15_19, phi4flash_tiny
 from .pimanet import PimaNet
 from .pnasnet import PNASNetA, PNASNetB
 from .preact_resnet import PreActResNet18
@@ -117,6 +119,12 @@ models = {
     # parallelism (5 of 40 layers, experts 0-15 of 256, published widths).
     "laguna_xs2_ep16": laguna_xs2_ep16,
     "laguna_tiny": laguna_tiny,
+    # SambaY language models (models/phi4flash.py): Mamba layers, window,
+    # full and cross differential attention, gated memory units, a tied
+    # embedding. phi4_mini_flash_l15_19 is one pipeline stage of
+    # Phi-4-mini-flash-reasoning (published layers 15-19, published widths).
+    "phi4_mini_flash_l15_19": phi4_mini_flash_l15_19,
+    "phi4flash_tiny": phi4flash_tiny,
 }
 
 # tools.py:89 (+ the synthetic sequence datasets of data/__init__.py:

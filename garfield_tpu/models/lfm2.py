@@ -93,17 +93,17 @@ __all__ = [
 ]
 
 # ``attention`` stands around a whole attention module (this family's
-# blocks); a block that splits the module instead (`Attention.core_scope`)
-# has ``attention_proj`` around the projections, q/k norm and rotary
-# embedding and ``window_attention`` or ``full_attention`` around the core;
-# ``attention_gate`` stands around a per-head output gate (`Attention.gate`:
-# its projection, sigmoid and multiply), ``shared_expert`` around the expert
-# every token passes beside the routed ones (`ExpertLayer.shared_width`).
+# blocks); a block that splits it (`Attention.core_scope`) has
+# ``attention_proj`` around the projections and ``window_attention``,
+# ``full_attention`` or ``cross_attention`` around the core;
+# ``attention_gate`` around a per-head gate, ``shared_expert`` around a
+# shared expert; ``ssm_*`` and ``gmu`` are `models/phi4flash.py`'s.
 SCOPES = (
     "embed", "conv_mixer", "attention", "dense_mlp", "moe_router",
     "moe_dispatch", "moe_experts", "moe_combine", "head_loss",
     "attention_proj", "window_attention", "full_attention",
-    "attention_gate", "shared_expert",
+    "attention_gate", "shared_expert", "ssm_proj", "ssm_conv", "ssm_scan",
+    "gmu", "cross_attention",
 )
 # The steps of an expert layer's routing, one ``route.<name>`` scope each,
 # nested inside ``model.moe_dispatch`` (the first five) and
@@ -124,24 +124,24 @@ COUNTER_SUMS, COUNTER_MAXES = "counters_sum", "counters_max"
 WEIGHT_EPS = 1e-6
 # What a recomputed block (``remat``) keeps of its forward pass, by the name
 # `keep` gives it where it is made: what a matmul, a kernel or a sort made,
-# as far as the backward pass reads it. The projections of the two
-# operators (the last of each, ``conv_out`` and ``attention_o_proj``, is
-# what the feed-forward's norm reads; ``v_proj``'s result reaches the
-# backward pass as the kernels' ``attention_v``, so it has no name); the
+# as far as the backward pass reads it. The operators' projections (the
+# last of each, ``conv_out``, ``attention_o_proj``, ``ssm_out_proj``,
+# ``gmu_out_proj``, is what the feed-forward's norm reads; ``v_proj``'s
+# result reaches the backward pass as the kernels' ``attention_v``); the
 # dense MLP's two inner products; of an expert layer the router's logits
-# and choice, the two sorts and the groups' sizes, the rows as dispatched,
-# the three grouped matmuls' results in sorted order, the third as the
-# combine reads it; a per-head gate's projection and a shared expert's
-# two inner products; and `ops.attention`'s own. Norms, the rotary
-# embedding, gates, masks, casts and, where the permutation runs, its rows
-# gathered back are computed again.
-# One set for every family made of this file's modules: a family differs
-# in which of the sites it contains.
+# and choice, the sorts and sizes, the dispatched rows, the three grouped
+# matmuls' results in sorted order; a gate's projection, a shared expert's
+# inner products, a Mamba layer's memory (y silu(z)); `ops.attention`'s
+# own. Norms, rotary embedding, gates, masks, casts, a convolution, the
+# scan and the permutation's rows gathered back are computed again. One set
+# for every family made of this file's modules.
 KEPT = (
     "conv_in", "conv_out", "attention_q_proj", "attention_k_proj",
     "attention_o_proj", "mlp_w1", "mlp_w3", "moe_logits", "moe_chosen",
     "moe_order", "moe_inverse", "moe_sizes", "moe_rows", "moe_w1", "moe_w3",
     "moe_out", "attention_gate_proj", "shared_w1", "shared_w3",
+    "ssm_in_proj", "ssm_x_proj", "ssm_dt_proj", "ssm_memory", "ssm_out_proj",
+    "gmu_gate", "gmu_out_proj",
 ) + attention.KEPT
 
 _normal = nn.initializers.variance_scaling(1.0, "fan_in", "normal")

@@ -387,7 +387,8 @@ def test_the_scopes_stand_inside_the_gradient_phase(
     """The family's scopes: the two kinds of core apart, the projections
     under a third, the gate and the shared expert each under its own and
     inside no other model scope; ``model.attention`` and ``conv_mixer`` are
-    the first family's. A recomputed block says what it keeps, the gate's
+    the first family's, ``ssm_*``, ``gmu`` and ``cross_attention`` the
+    SambaY family's (tests/test_phi4flash.py). A recomputed block says what it keeps, the gate's
     projection and the shared expert's inner products among it."""
     attention._said.clear()
     module = models.select_model("laguna_tiny", "synthtokens").clone(
@@ -396,7 +397,9 @@ def test_the_scopes_stand_inside_the_gradient_phase(
     x, y = shared._worker_batches()
     state = init_fn(jax.random.PRNGKey(0), x[0])
     text = step_fn.lower(state, x, y).compile().as_text()
-    ours = set(lfm2.SCOPES) - {"attention", "conv_mixer"}
+    ours = set(lfm2.SCOPES) - {
+        "attention", "conv_mixer", "ssm_proj", "ssm_conv", "ssm_scan", "gmu",
+        "cross_attention"}
     for name in lfm2.SCOPES:
         assert (f"model.{name}/" in text or f"model.{name}\"" in text) == (
             name in ours), name

@@ -446,9 +446,11 @@ def test_the_model_scopes_stand_inside_the_gradient_phase(
     # This family's blocks put ``attention`` around the whole module; the
     # three names that split a module are the other families'
     # (tests/test_mellum.py), the gate and the shared expert the third's
-    # (tests/test_laguna.py).
+    # (tests/test_laguna.py), the Mamba mixer's, the GMU's and the cross
+    # layer's the SambaY family's (tests/test_phi4flash.py).
     split = {"attention_proj", "window_attention", "full_attention",
-             "attention_gate", "shared_expert"}
+             "attention_gate", "shared_expert", "ssm_proj", "ssm_conv",
+             "ssm_scan", "gmu", "cross_attention"}
     for name in lfm2.SCOPES:
         assert (f"model.{name}/" in text or f"model.{name}\"" in text) == (
             name not in split), name

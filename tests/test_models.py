@@ -30,7 +30,7 @@ def test_num_classes_dict_parity():
     assert models.num_classes_dict == {
         "cifar10": 10, "cifar100": 100, "mnist": 10, "imagenet": 1000, "pima": 1,
         "copytask": 10, "synthtokens": 16384, "synthtokens24k": 24576,
-        "synthtokens12k": 12544,
+        "synthtokens12k": 12544, "synthtokens25k": 25008,
     }
 
 

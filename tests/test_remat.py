@@ -238,7 +238,7 @@ def test_a_name_outside_the_kept_set_is_refused_and_off_changes_nothing():
     named as its bits, two bitcasts a float name."""
     with pytest.raises(ValueError, match="unknown name 'moe_gate'"):
         lfm2.keep(jnp.zeros(3), "moe_gate")
-    assert len(set(lfm2.KEPT)) == len(lfm2.KEPT) == 24
+    assert len(set(lfm2.KEPT)) == len(lfm2.KEPT) == 31
     assert set(attention.KEPT) < set(lfm2.KEPT)
     loss, params = _block_loss(
         "lfm2", dict(layer_types=("conv", "full_attention")), remat=False)
