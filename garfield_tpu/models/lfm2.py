@@ -80,7 +80,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops import attention, grouped
+from ..ops import attention, grouped, scan
 from ..ops import route as route_ops
 
 __all__ = [
@@ -132,9 +132,9 @@ WEIGHT_EPS = 1e-6
 # and choice, the sorts and sizes, the dispatched rows, the three grouped
 # matmuls' results in sorted order; a gate's projection, a shared expert's
 # inner products, a Mamba layer's memory (y silu(z)); `ops.attention`'s
-# own. Norms, rotary embedding, gates, masks, casts, a convolution, the
-# scan and the permutation's rows gathered back are computed again. One set
-# for every family made of this file's modules.
+# and `ops.scan`'s own. Norms, rotary embedding, gates, masks, casts, a
+# convolution, the chunked scan and the permutation's rows gathered back are
+# computed again. One set for every family made of this file's modules.
 KEPT = (
     "conv_in", "conv_out", "attention_q_proj", "attention_k_proj",
     "attention_o_proj", "mlp_w1", "mlp_w3", "moe_logits", "moe_chosen",
@@ -142,7 +142,7 @@ KEPT = (
     "moe_out", "attention_gate_proj", "shared_w1", "shared_w3",
     "ssm_in_proj", "ssm_x_proj", "ssm_dt_proj", "ssm_memory", "ssm_out_proj",
     "gmu_gate", "gmu_out_proj",
-) + attention.KEPT
+) + attention.KEPT + scan.KEPT
 
 _normal = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
 

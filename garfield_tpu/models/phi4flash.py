@@ -154,7 +154,8 @@ class Mamba(nn.Module):
         with lfm2.scope("ssm_scan"):
             a_log = self.param("A_log", _a_log_init, (self.inner, self.state))
             skip = self.param("D", nn.initializers.ones, (self.inner,))
-            y = scan.selective_scan(x, delta, -jnp.exp(a_log), b, c, skip)
+            y = scan.selective_scan(x, delta, -jnp.exp(a_log), b, c, skip,
+                                    kept=lfm2._count)
         with lfm2.scope("ssm_proj"):
             memory = lfm2.keep(y * nn.silu(z), "ssm_memory")
             out = lfm2.keep(lfm2._dense(hidden, self.dtype, "out_proj")(

@@ -21,6 +21,10 @@ fetched once; ``jax.lax.ragged_dot`` is its spec and its fallback.
 `ops.route` (imported there too) moves that layer's held rows alone: a
 gather into the sorted order and a weighted sum back per token whose grids
 end at the last held pair, in place of permuting every (token, expert) row.
+`ops.scan` (imported by `models/phi4flash.py`; `models/lfm2.py` keeps the
+names its kernels give what they keep) is a Mamba layer's selective
+scan: a forward and a backward kernel that hold the state on chip across a
+sequence's positions, and the chunked loop where they do not apply.
 
 Public entry points dispatch by backend: the Pallas path on TPU (or when
 forced via ``interpret=True`` for CPU testing), a pure-jnp fallback elsewhere

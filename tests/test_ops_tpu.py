@@ -278,3 +278,53 @@ def test_held_rows_on_device(tokens, k, hidden, held):
         np.testing.assert_allclose(
             f32(a), f32(b), atol=2.0 ** -7 * float(np.abs(f32(b)).max()),
             err_msg=name)
+
+
+@pytest.mark.parametrize("n,t,channels,dtype", [
+    (1, 4096, 5120, jnp.bfloat16),  # one slot of phi4flashn4's Mamba layer
+    (2, 1000, 384, jnp.float32),    # padded positions, three channel tiles
+])
+def test_selective_scan_kernels_on_device(n, t, channels, dtype):
+    """The selective scan's kernels (ops/scan.py) through real Mosaic
+    lowering, taken by ``selective_scan`` itself under the recomputed
+    block's policy, against ``sequential_scan``: y and the cotangents of x,
+    delta, A, B, C and D. A bf16 leaf within two steps of bf16's grid at its
+    largest entry, a float32 one within 1e-4 of it (the sums run in
+    another order)."""
+    from garfield_tpu.ops import scan
+
+    state = 16
+    assert scan.misfit((n, t, channels, state), dtype) is None
+    keys = jax.random.split(jax.random.PRNGKey(t + channels), 7)
+    args = (jax.random.normal(keys[0], (n, t, channels), dtype),
+            jax.nn.softplus(jax.random.normal(keys[1], (n, t, channels))
+                            - 2.0),
+            -jnp.exp(jax.random.normal(keys[2], (channels, state))),
+            jax.random.normal(keys[3], (n, t, state), dtype),
+            jax.random.normal(keys[4], (n, t, state), dtype),
+            jax.random.normal(keys[5], (channels,)))
+    weight = jax.random.normal(keys[6], (n, t, channels), jnp.float32)
+    keep = jax.checkpoint_policies.save_only_these_names(*scan.KEPT)
+
+    def both(core):
+        def loss(*a):
+            y = jax.checkpoint(core, policy=keep)(*a)
+            return jnp.sum(y.astype(jnp.float32) * weight), y
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=range(6), has_aux=True))(*args)
+
+    (_, got), got_grads = both(scan.selective_scan)
+    (_, want), want_grads = both(scan.sequential_scan)
+    f32 = lambda x: np.asarray(x, np.float32)
+
+    def close(a, b, name):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        room = 2.0 ** -7 if a.dtype == jnp.bfloat16 else 1e-4
+        np.testing.assert_allclose(
+            f32(a), f32(b), atol=room * float(np.abs(f32(b)).max()),
+            err_msg=name)
+
+    close(got, want, "y")
+    for name, a, b in zip(("dx", "ddelta", "dA", "dB", "dC", "dD"),
+                          got_grads, want_grads):
+        close(a, b, name)

@@ -84,10 +84,14 @@ def _apply(module, variables, x):
 def kernel_path(monkeypatch):
     """`ops.attention.causal_gqa` takes the blockwise kernels, in interpret
     mode with blocks of 4 over the 16 positions: a window layer's band is
-    one block wide."""
+    one block wide; `ops.scan.selective_scan` takes its kernels, in
+    interpret mode (the tiny preset's state of 4 fills no sublanes, which
+    only the chip asks for)."""
     attention._said.clear()
     monkeypatch.setattr(attention, "causal_gqa", functools.partial(
         attention.causal_gqa, block=4, interpret=True))
+    monkeypatch.setattr(scan, "selective_scan", functools.partial(
+        scan.selective_scan, interpret=True))
 
 
 @pytest.mark.parametrize("path", ["einsum", "kernels"])
@@ -95,9 +99,10 @@ def kernel_path(monkeypatch):
 def test_logits_loss_and_gradient_equal_the_reference(layers, path, request,
                                                       capsys):
     """The tiny model (8 layers) and a stage of its last five (published
-    indices 3-7, so λ_init follows them), by the einsum path and by the
-    kernels: logits, loss and ``jax.grad`` of every leaf. Float32 both:
-    the program sums in another order (the scan by chunks, the attention
+    indices 3-7, so λ_init follows them), by the einsum path and the
+    chunked scan and by the kernels (the attention's and the scan's):
+    logits, loss and ``jax.grad`` of every leaf. Float32 both: the
+    program sums in another order (the scan by chunks, the attention
     block by block, the combine after the two calls), so each number is
     held to a few float32 ulps of its largest entry, 2e-5 as the other
     families' tests hold theirs."""
@@ -130,11 +135,15 @@ def test_logits_loss_and_gradient_equal_the_reference(layers, path, request,
                 jnp.linalg.norm(want[leaf]))), err_msg=leaf)
         assert float(jnp.linalg.norm(want[leaf])) > 0, leaf
     if path == "kernels":
-        said = [line for line in capsys.readouterr().err.splitlines()
-                if "[attention]" in line]
+        err = capsys.readouterr().err.splitlines()
+        said = [line for line in err if "[attention]" in line]
         assert said and all("blockwise" in line for line in said)
         # Both softmaxes' heads in one call: 8 over 4 KV heads.
         assert all("= (3, 8, 4, 16, 8)" in line for line in said), said
+        assert [line for line in err if "[ssm]" in line] == [
+            "[ssm] kernels: (n, t, channels, state) = (3, 16, 128, 4), "
+            "channel tile 128, chunks of 128, state float32; kept y + chunk "
+            "states 0.0676 MB a sequence, interpret mode"]
 
 
 def _scan_inputs(n=2, t=37, channels=8, state=4, seed=0):
@@ -201,8 +210,9 @@ def test_the_chunk_length_follows_the_shapes(capsys):
         scan.selective_scan(*args)
     said = [line for line in capsys.readouterr().err.splitlines()
             if line.startswith("[ssm]")]
-    assert said == ["[ssm] chunked: (n, t, channels, state) = (1, 48, 8, 4), "
-                    "chunks of 32, loop over positions, state float32"]
+    assert said == ["[ssm] chunked: channels = 8 is not a multiple of 128; "
+                    "(n, t, channels, state) = (1, 48, 8, 4), chunks of 32, "
+                    "loop over positions, state float32"]
 
 
 def _einsum_diff(q, k, v, lam, window):
@@ -367,8 +377,9 @@ def test_the_compiled_step_names_the_five_new_scopes(
     assert "moe_" not in remat[-1]
     assert re.search(r"keeps 12 names: .*; per slot [0-9.]+ GB", remat[-1])
     ssm = [line for line in err if line.startswith("[ssm]")]
-    assert ssm == ["[ssm] chunked: (n, t, channels, state) = (2, 16, 128, "
-                   "4), chunks of 16, loop over positions, state float32"]
+    assert ssm == ["[ssm] chunked: state = 4 is not a multiple of 8 "
+                   "sublanes; (n, t, channels, state) = (2, 16, 128, 4), "
+                   "chunks of 16, loop over positions, state float32"]
 
 
 def test_the_preset_and_its_token_dataset_are_registered():

@@ -22,7 +22,7 @@ import pytest
 
 import test_lfm2 as shared
 from garfield_tpu.models import lfm2, mellum
-from garfield_tpu.ops import attention
+from garfield_tpu.ops import attention, scan
 
 VOCAB = shared.VOCAB
 FAMILIES = {"lfm2": lfm2.lfm2_moe_tiny, "mellum": mellum.mellum2_tiny}
@@ -238,8 +238,9 @@ def test_a_name_outside_the_kept_set_is_refused_and_off_changes_nothing():
     named as its bits, two bitcasts a float name."""
     with pytest.raises(ValueError, match="unknown name 'moe_gate'"):
         lfm2.keep(jnp.zeros(3), "moe_gate")
-    assert len(set(lfm2.KEPT)) == len(lfm2.KEPT) == 31
+    assert len(set(lfm2.KEPT)) == len(lfm2.KEPT) == 33
     assert set(attention.KEPT) < set(lfm2.KEPT)
+    assert set(scan.KEPT) < set(lfm2.KEPT)
     loss, params = _block_loss(
         "lfm2", dict(layer_types=("conv", "full_attention")), remat=False)
     with_names = _count(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
